@@ -607,6 +607,8 @@ def run(smoke: bool = True, kind: str = "gb", requests: int = 320,
                 f"replica sweep: {rsweep['replica_speedup']}x over the "
                 "single-replica plane < the 3x target (rig has "
                 f"{rsweep['cpu_count']} cores)")
+        from learningorchestra_tpu.parallel import distributed
+
         doc = {
             "metric": "online predict: micro-batched vs serialized "
                       f"per-request dispatch ({kind}, {requests} reqs)",
@@ -614,6 +616,7 @@ def run(smoke: bool = True, kind: str = "gb", requests: int = 320,
             "unit": "x speedup",
             "model": name,
             "smoke": smoke,
+            "device": distributed.device_info(),
             "serialized": serial,
             "closed_loop": closed,
             "closed_loop_http": http,

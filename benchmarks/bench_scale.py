@@ -1,4 +1,4 @@
-"""At-scale engine benchmarks on real hardware (BASELINE.md configs).
+"""At-scale engine benchmarks on the TPU (BASELINE.md configs).
 
 Synthetic datasets shaped like the baseline workloads (no egress in the
 bench environment):
@@ -180,22 +180,22 @@ def bench_analytics(runtime, n=50_000_000):
 
 
 def main():
-    import jax
-
     from learningorchestra_tpu.config import Settings
+    from learningorchestra_tpu.parallel import distributed
     from learningorchestra_tpu.parallel.mesh import MeshRuntime
 
-    try:  # persistent compile cache: steady-state numbers, like bench.py
-        jax.config.update("jax_compilation_cache_dir", "/tmp/lo_jit_cache")
-    except Exception:
-        pass
+    # Device benchmarks: they run on the chip or not at all, and every
+    # run names the device its numbers belong to.
+    device = distributed.device_info()
+    if device["platform"] != "tpu":
+        raise SystemExit(f"bench_scale.py measures the TPU; found {device}")
+    distributed.place_compile_cache()  # steady-state numbers, like bench.py
 
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     cfg = Settings()
     cfg.persist = False
     runtime = MeshRuntime(cfg)
-    print(json.dumps({"devices": [str(d) for d in jax.devices()]}),
-          flush=True)
+    print(json.dumps({"device": device}), flush=True)
     if which in ("higgs", "all"):
         bench_higgs(runtime)
     if which in ("tsne", "all"):

@@ -709,8 +709,8 @@ def process_id() -> Optional[int]:
 
 def peak_flops() -> float:
     """Override for the per-chip peak dense-matmul FLOP/s used as the
-    MFU denominator (``LO_TPU_PEAK_FLOPS``; models/flops.py defaults to
-    the v5e bf16 figure). 0.0 = unset."""
+    MFU denominator (``LO_TPU_PEAK_FLOPS``; unset, models/flops.py looks
+    the device up in its table of published peaks). 0.0 = unset."""
     try:
         return float(os.environ.get("LO_TPU_PEAK_FLOPS", "") or 0.0)
     except ValueError:

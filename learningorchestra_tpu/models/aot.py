@@ -32,10 +32,10 @@ Design points:
   bit-identical by the row-wise-evaluation argument below. The default
   (1) preserves the single-device topology byte-for-byte; 0 means all
   local devices.
-- **Donated inputs**: the batch buffer is donated to the executable
-  where the backend supports it (TPU/GPU), so dispatch writes the
-  output into the input's HBM pages instead of allocating per request.
-  CPU has no donation — gated to keep the test rig warning-free.
+- **No donated inputs**: the (b, d) batch buffer can never hold the
+  (b, classes) output, so donating it bought nothing — on the TPU every
+  bucket compile only warned "Some donated buffers were not usable"
+  (first chip run, PR 22) — and the TPU-only branch is gone.
 - **Versioned cache**: programs are keyed (model name, version, bucket)
   where version is the manifest file's (mtime_ns, size). Re-saving a
   model under the same name (incremental refit, ROADMAP item 4) or
@@ -302,9 +302,6 @@ class AotModel:
                           for d in self._devices]
         self._device = self._devices[0]
         self._params = self._params_r[0]
-        # Donation rewrites the batch buffer in place on backends that
-        # support it; the CPU test rig would only log a warning per call.
-        donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
         fn = model.predict_proba_fn
 
         def rowwise(p, x):
@@ -323,7 +320,7 @@ class AotModel:
             # dispatch) is untouched.
             return jax.lax.map(lambda r: fn(p, r[None, :])[0], x)
 
-        jitted = jax.jit(rowwise, donate_argnums=donate)
+        jitted = jax.jit(rowwise)
         x_specs = {
             b: jax.ShapeDtypeStruct((b, self.n_features), jnp.float32)
             for b in self.buckets}

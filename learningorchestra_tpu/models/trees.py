@@ -36,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.models.base import TrainedModel, as_design
 from learningorchestra_tpu.ops import pallas_kernels
@@ -48,18 +48,19 @@ NEG = -1e30
 
 def _use_tree_kernel(runtime: Optional[MeshRuntime] = None) -> bool:
     """Whether tree fits route their hot loops through the fused Pallas
-    kernels (ops/pallas_kernels.py). ``LO_TPU_TREE_KERNEL=0`` selects
-    the pure-XLA contraction path — kept as the bit-parity oracle
-    (docs/performance.md); the master ``LO_TPU_USE_PALLAS`` switch
-    disables every Pallas kernel at once. Off-TPU the kernels run in
-    interpreter mode, so the default exercises the same code path on
-    the CPU mesh."""
+    kernels (ops/pallas_kernels.py): the two config flags and nothing
+    else. ``LO_TPU_TREE_KERNEL=0`` selects the pure-XLA contraction path
+    — kept as the bit-parity oracle (docs/performance.md); the master
+    ``LO_TPU_USE_PALLAS`` switch disables every Pallas kernel at once.
+    With the flags on, a kernel the backend's compiler refuses raises
+    out of the fit and fails the job — there is no fallback to the
+    oracle. Off-TPU the kernels run in interpreter mode, so the default
+    exercises the same code path on the CPU mesh."""
     if runtime is not None:
         cfg = runtime.cfg
     else:
         from learningorchestra_tpu.config import settings as cfg
-    return bool(cfg.use_pallas and cfg.tree_kernel
-                and pallas_kernels.tree_kernels_supported())
+    return bool(cfg.use_pallas and cfg.tree_kernel)
 
 
 def _hist_dtype():
@@ -348,6 +349,9 @@ def _build_tree(B, stats_T, feat_gain_mask, *, max_depth, n_bins,
         B = jnp.pad(B, ((0, n_pad - n), (0, 0)))
         stats_T = jnp.pad(stats_T, ((0, 0), (0, n_pad - n)))
     hdt = _hist_dtype()
+    # The kernels stream every per-row operand with rows in lanes — the
+    # bin matrix too, transposed once per tree outside the level loop.
+    BT = B.T if use_kernel else None
 
     #: Fixed per-level node width: the deepest processed level has
     #: 2^(max_depth-1) nodes, and every level runs at that width so the
@@ -371,7 +375,7 @@ def _build_tree(B, stats_T, feat_gain_mask, *, max_depth, n_bins,
 
         if use_kernel:
             hist = pallas_kernels.tree_histogram(
-                B, stats_T, rel, active, n_nodes=NL, n_bins=n_bins,
+                BT, stats_T, rel, active, n_nodes=NL, n_bins=n_bins,
                 tile=blk, operand_dtype=hdt)
         else:
             hist = _hist_level_xla(B, stats_T, rel, active, n_nodes=NL,
@@ -404,7 +408,7 @@ def _build_tree(B, stats_T, feat_gain_mask, *, max_depth, n_bins,
 
         if use_kernel:
             asg = pallas_kernels.tree_route_level(
-                B, rel, active, assign, best_f, best_t, split, tile=blk)
+                BT, rel, active, assign, best_f, best_t, split, tile=blk)
         else:
             asg = _route_level_xla(B, rel, active, assign, best_f,
                                    best_t, split, blk=blk)
@@ -439,7 +443,7 @@ def _descend(B, feat, thr, is_internal, max_depth, use_kernel=False):
     on the oracle, where tile padding would dominate."""
     n, d = B.shape
     if use_kernel and n >= pallas_kernels.TREE_ROUTE_TILE:
-        return pallas_kernels.tree_descend(B, feat, thr, is_internal,
+        return pallas_kernels.tree_descend(B.T, feat, thr, is_internal,
                                            max_depth=max_depth)
     blk, nbk, n_pad = _block_shape(n)
     if n_pad != n:
@@ -461,6 +465,40 @@ def _descend(B, feat, thr, is_internal, max_depth, use_kernel=False):
     a, _ = jax.lax.scan(desc_block, jnp.zeros((n_pad,), jnp.int32),
                         jnp.arange(nbk))
     return a[:n]
+
+
+def _predict_program(body):
+    """The family's ``(params, X, *, max_depth) -> probs`` predict
+    function from its row-local ``body``: one jitted program, run under
+    ``shard_map`` over the design's own mesh when the concrete ``X``
+    handed in is row-sharded across several devices. XLA partitions a
+    plain jitted predict by itself, but it cannot partition a Mosaic
+    kernel ("Mosaic kernels cannot be automatically partitioned" — the
+    first four-chip run, PR 22; interpret mode on the CPU mesh never
+    shows it). Every op in ``body`` is row-local and the tree params are
+    replicated, so per-shard evaluation is the same arithmetic row for
+    row. A traced ``X`` (the AOT row-wise serving programs) or a
+    one-device design takes the plain program."""
+
+    @partial(jax.jit, static_argnames=("max_depth", "mesh"))
+    def program(params, X, *, max_depth, mesh):
+        fn = partial(body, max_depth=max_depth)
+        if mesh is None:
+            return fn(params, X)
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=(P(), P(DATA_AXIS)),
+            out_specs=P(DATA_AXIS), check_vma=False)(params, X)
+
+    def predict(params, X, *, max_depth):
+        sharding = None if isinstance(X, jax.core.Tracer) else getattr(
+            X, "sharding", None)
+        spans = (isinstance(sharding, NamedSharding)
+                 and len(sharding.device_set) > 1)
+        return program(params, X, max_depth=max_depth,
+                       mesh=sharding.mesh if spans else None)
+
+    predict.program = program    # tests compile it for a described mesh
+    return predict
 
 
 # ---------------------------------------------------------------------------
@@ -954,7 +992,7 @@ def _fit_cls_trees(kind, runtime, X, y, num_classes, seed, *, n_trees,
                  "n_bins": n_bins})
 
 
-@partial(jax.jit, static_argnames=("max_depth",))
+@_predict_program
 def _forest_proba_static(params, X, *, max_depth):
     B = bin_features(X, params["edges"])
     # Trace-time kernel selection is safe here: descent is integer
@@ -1109,7 +1147,7 @@ def _gbt_replay_margin(B, feat, thr, internal, leaf_val, step_size, *,
     )(B, feat, thr, internal, leaf_val, step_size)
 
 
-@partial(jax.jit, static_argnames=("max_depth",))
+@_predict_program
 def _gbt_proba_static(params, X, *, max_depth):
     B = bin_features(X, params["edges"])
     use_kernel = _use_tree_kernel()
@@ -1125,7 +1163,7 @@ def _gbt_proba_static(params, X, *, max_depth):
     return jnp.stack([1 - p1, p1], axis=1)
 
 
-@partial(jax.jit, static_argnames=("max_depth",))
+@_predict_program
 def _gbt_ovr_proba_static(params, X, *, max_depth):
     """Multiclass gb probabilities: per-class booster margins (leading
     class axis on every tree param), class scores p_k = σ(margin_k),

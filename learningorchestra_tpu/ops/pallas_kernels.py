@@ -175,6 +175,9 @@ _TREE_ACC_BYTES = 2 << 20
 #: (below it, padding overhead beats the fusion win — e.g. the online
 #: serving tier's row-wise AOT programs stay on the XLA oracle).
 TREE_ROUTE_TILE = 512
+#: Lane width of a TPU vector register: the histogram kernel builds its
+#: one-hot and accumulates one such column group at a time.
+_LANES = 128
 
 
 def tree_tile(d: int, n_bins: int) -> int:
@@ -198,100 +201,131 @@ def _tree_node_groups(n_nodes: int, n_stats: int, d: int,
     return ng
 
 
-def _pad_rows(arr: jax.Array, n_pad: int) -> jax.Array:
-    n = arr.shape[0]
+def _pad_lanes(arr: jax.Array, n_pad: int, value=0) -> jax.Array:
+    """Pad the trailing (row) axis out to ``n_pad``."""
+    n = arr.shape[-1]
     if n == n_pad:
         return arr
-    return jnp.pad(arr, ((0, n_pad - n),) + ((0, 0),) * (arr.ndim - 1))
+    return jnp.pad(arr, ((0, 0),) * (arr.ndim - 1) + ((0, n_pad - n),),
+                   constant_values=value)
 
 
-def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, act_ref, out_ref,
-                      *, operand_dtype):
+def _row_vec(v: jax.Array, n_pad: int, value=0) -> jax.Array:
+    """A per-row (n,) vector as the (1, n_pad) int32 row the kernels
+    stream: rows sit in lanes, so the array is dense in HBM — an (n, 1)
+    column is lane-padded 128× (measured from the v5e compile's
+    memory_analysis: 11 GB of temporaries for a 1.1M-row rf batch)."""
+    return _pad_lanes(v.astype(jnp.int32).reshape(1, -1), n_pad, value)
+
+
+def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
+                      operand_dtype):
     """One (node-group g, row-tile t) cell of the histogram grid.
 
     Scatter-adds the row tile's sufficient statistics into the
     VMEM-resident (NG·S, d·n_bins) accumulator block: the node-masked
-    stats operand and the (tile, d·n_bins) bin one-hot are built in VMEM
-    and consumed by one MXU contraction — never written to HBM. The
-    accumulator block is indexed by g only, so it stays resident while
-    the row tiles stream past (t is the innermost grid dimension).
+    stats operand and the bin one-hot are built in VMEM and consumed by
+    the MXU — never written to HBM. The accumulator block is indexed by
+    g only, so it stays resident while the row tiles stream past (t is
+    the innermost grid dimension).
+
+    Every value is 2-D with the tile's rows in lanes, as the operands
+    arrive — Mosaic refuses the rank-3 broadcasts/reshapes the XLA
+    oracle's formulation uses. The stats operand's row ``node·S + s`` is
+    selected from the (1, tile) node-id row; the one-hot is built
+    transposed, one 128-column group of the histogram at a time:
+    ``col`` is each (feature, row)'s global histogram column
+    ``f·n_bins + code``, so a group's one-hot is the OR, over the few
+    features whose columns fall in it, of a sublane-broadcast compare.
     Operands mirror the XLA oracle's dtype (bf16 on TPU, f32 elsewhere);
-    {0,1} one-hot products are exact and the dot accumulates in f32.
+    {0,1} one-hot products are exact and every dot accumulates in f32.
     """
     g = pl.program_id(0)
     t = pl.program_id(1)
-    tile, d = codes_ref.shape
+    d, tile = codes_ref.shape
     S = stats_ref.shape[0]
-    NG = out_ref.shape[0] // S
-    nb = out_ref.shape[1] // d
+    NGS, Wp = out_ref.shape
 
-    codes = codes_ref[:].astype(jnp.int32)                    # (tile, d)
-    node_ids = (g * NG
-                + jax.lax.broadcasted_iota(jnp.int32, (tile, NG), 1))
-    node_oh = (rel_ref[:] == node_ids) & (act_ref[:] != 0)    # (tile, NG)
-    A = (node_oh[:, :, None].astype(operand_dtype)
-         * stats_ref[:].T.astype(operand_dtype)[:, None, :])  # (tile,NG,S)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (tile, d, nb), 2)
-    oh = (codes[:, :, None] == bins).astype(operand_dtype)
-    contrib = jax.lax.dot(A.reshape(tile, NG * S).T,
-                          oh.reshape(tile, d * nb),
-                          preferred_element_type=jnp.float32)
+    # Inactive/padded rows carry rel = -1 and rows of other node groups
+    # fall outside [0, NGS): neither matches any accumulator row.
+    first = (rel_ref[:] - g * (NGS // S)) * S                 # (1, tile)
+    row = jax.lax.broadcasted_iota(jnp.int32, (NGS, tile), 0)
+    At = jnp.zeros((NGS, tile), jnp.float32)
+    for s in range(S):
+        At = jnp.where(row == first + s, stats_ref[s:s + 1, :], At)
+    At = At.astype(operand_dtype)
+
+    codes = codes_ref[:].astype(jnp.int32)                    # (d, tile)
+    col = jnp.where(
+        codes < n_bins,
+        codes + n_bins * jax.lax.broadcasted_iota(jnp.int32, (d, tile), 0),
+        -1)
+    group = jax.lax.broadcasted_iota(jnp.int32, (_LANES, tile), 0)
 
     @pl.when(t == 0)
     def _init():
-        out_ref[:] = contrib
+        out_ref[:] = jnp.zeros_like(out_ref)
 
-    @pl.when(t != 0)
-    def _acc():
-        out_ref[:] += contrib
+    for lo in range(0, Wp, _LANES):
+        cols = group + lo                     # this group's column ids
+        hit = None
+        for f in range(lo // n_bins,
+                       min(d - 1, (lo + _LANES - 1) // n_bins) + 1):
+            m = col[f:f + 1, :] == cols
+            hit = m if hit is None else hit | m
+        ohT = jnp.where(hit, 1.0, 0.0).astype(operand_dtype)  # (128, tile)
+        out_ref[:, lo:lo + _LANES] += jax.lax.dot_general(
+            At, ohT, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
-def _hist_call(codes, stats_T, rel, active, *, n_nodes, n_bins, tile,
+def _hist_call(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
                operand_dtype):
     """Shared pallas_call for tree_histogram / tree_leaf_stats. Returns
     the flat (n_nodes·S, d·n_bins) f32 histogram."""
-    n, d = codes.shape
+    d, n = codes_T.shape
     S = stats_T.shape[0]
     n_pad = -(-n // tile) * tile
-    codes = _pad_rows(codes, n_pad)
-    stats_T = _pad_rows(stats_T.T, n_pad).T
-    # Padded rows carry zero stats (callers pad stats with zeros), so
-    # their contribution is an exact 0 regardless of rel/active padding.
-    rel = _pad_rows(rel.reshape(-1, 1), n_pad)
-    act = _pad_rows(active.reshape(-1, 1).astype(jnp.int32), n_pad)
+    # Padded rows carry zero stats (callers pad stats with zeros) and an
+    # inactive node id, so their contribution is an exact 0.
+    rel = _row_vec(jnp.where(active, rel, -1), n_pad, -1)
     NG = _tree_node_groups(n_nodes, S, d, n_bins)
     G = n_nodes // NG
+    # The accumulator's lane width rounds up to whole 128-lane groups so
+    # every in-kernel store is aligned; the group axis leads so a block
+    # always spans the array's full trailing dims, whatever NG·S is.
+    Wp = -(-d * n_bins // _LANES) * _LANES
     out = pl.pallas_call(
-        partial(_tree_hist_kernel, operand_dtype=operand_dtype),
+        partial(_tree_hist_kernel, n_bins=n_bins,
+                operand_dtype=operand_dtype),
         grid=(G, n_pad // tile),
         in_specs=[
-            pl.BlockSpec((tile, d), lambda g, t: (t, 0)),
+            pl.BlockSpec((d, tile), lambda g, t: (0, t)),
             pl.BlockSpec((S, tile), lambda g, t: (0, t)),
-            pl.BlockSpec((tile, 1), lambda g, t: (t, 0)),
-            pl.BlockSpec((tile, 1), lambda g, t: (t, 0)),
+            pl.BlockSpec((1, tile), lambda g, t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((NG * S, d * n_bins), lambda g, t: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_nodes * S, d * n_bins),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((None, NG * S, Wp), lambda g, t: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, NG * S, Wp), jnp.float32),
         interpret=_interpret(),
-    )(codes, stats_T, rel, act)
-    return out
+    )(_pad_lanes(codes_T, n_pad), _pad_lanes(stats_T, n_pad), rel)
+    return out.reshape(n_nodes * S, Wp)[:, :d * n_bins]
 
 
-def tree_histogram(codes, stats_T, rel, active, *, n_nodes, n_bins,
+def tree_histogram(codes_T, stats_T, rel, active, *, n_nodes, n_bins,
                    tile, operand_dtype=jnp.float32):
     """Per-level (node, feature, bin, stat) histogram over the local
     shard rows — the fused replacement for models/trees.py's
     ``hist_block`` contraction scan.
 
-    codes: (n, d) uint8 bin codes; stats_T: (S, n) f32 per-row stats;
-    rel: (n,) int32 node id relative to the level offset (clamped to 0
-    for inactive rows); active: (n,) bool. Returns (n_nodes, d, n_bins,
-    S) f32 — exactly the oracle's reshape/transpose of the contraction.
+    codes_T: (d, n) uint8 bin codes, TRANSPOSED so rows sit in lanes
+    like every other per-row operand; stats_T: (S, n) f32 per-row
+    stats; rel: (n,) int32 node id relative to the level offset;
+    active: (n,) bool. Returns (n_nodes, d, n_bins, S) f32 — exactly the
+    oracle's reshape/transpose of the contraction.
     """
     S = stats_T.shape[0]
-    d = codes.shape[1]
-    out = _hist_call(codes, stats_T, rel, active, n_nodes=n_nodes,
+    d = codes_T.shape[0]
+    out = _hist_call(codes_T, stats_T, rel, active, n_nodes=n_nodes,
                      n_bins=n_bins, tile=tile, operand_dtype=operand_dtype)
     return out.reshape(n_nodes, S, d, n_bins).transpose(0, 2, 3, 1)
 
@@ -303,190 +337,112 @@ def tree_leaf_stats(assign, stats_T, *, n_nodes, tile,
     the row's node assignment and a single always-active node group.
     Returns (S, n_nodes) f32 (callers transpose + psum)."""
     n = assign.shape[0]
-    ones = jnp.ones((n,), jnp.int32)
-    out = _hist_call(assign.reshape(n, 1).astype(jnp.int32), stats_T,
-                     jnp.zeros((n,), jnp.int32), ones, n_nodes=1,
-                     n_bins=n_nodes, tile=tile,
-                     operand_dtype=operand_dtype)
-    return out                                        # (S, n_nodes)
+    return _hist_call(assign.astype(jnp.int32).reshape(1, n), stats_T,
+                      jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool),
+                      n_nodes=1, n_bins=n_nodes, tile=tile,
+                      operand_dtype=operand_dtype)    # (S, n_nodes)
 
 
-def _sel_small(table_row, oh, out_dtype=jnp.int32):
-    """In-VMEM ``table[idx]`` via the one-hot mask ``oh`` (tile, M) —
-    the kernel-side analogue of models/trees.py `_sel_table`."""
-    return jnp.sum(jnp.where(oh, table_row.astype(out_dtype), 0), axis=1,
-                   keepdims=True)
+def _sel_small(table_col, oh):
+    """In-VMEM ``table[idx]`` via the one-hot mask ``oh`` (M, tile) over
+    the (M, 1) int32 table column — the kernel-side analogue of
+    models/trees.py `_sel_table`. Returns the (1, tile) looked-up row."""
+    return jnp.sum(jnp.where(oh, table_col, 0), axis=0, keepdims=True)
 
 
-def _tree_route_kernel(codes_ref, rel_ref, act_ref, asg_ref, tbl_ref,
-                       out_ref):
+def _node_tables(*cols) -> jax.Array:
+    """Per-node arrays packed as the (M, len(cols)) int32 block the
+    routing/descent kernels keep resident in VMEM."""
+    return jnp.stack([c.astype(jnp.int32) for c in cols], axis=1)
+
+
+def _tree_route_kernel(codes_ref, rel_ref, asg_ref, tbl_ref, out_ref):
     """One row tile of the per-level routing pass: node-table lookups
     (feature, threshold, did-split) and the child-assignment update,
-    fused into a single VPU pass. tbl packs [best_f; best_t; split] as a
-    (3, NL) int32 block resident in VMEM."""
-    tile, d = codes_ref.shape
-    NL = tbl_ref.shape[1]
+    fused into a single VPU pass over lane-dense (·, tile) values. tbl
+    packs [best_f, best_t, split] as (NL, 3) int32; inactive rows carry
+    rel = -1, match no node and so keep their assignment."""
+    d, tile = codes_ref.shape
+    NL = tbl_ref.shape[0]
     node_oh = rel_ref[:] == jax.lax.broadcasted_iota(
-        jnp.int32, (tile, NL), 1)                          # (tile, NL)
-    rf = _sel_small(tbl_ref[0:1, :], node_oh)              # (tile, 1)
-    rt = _sel_small(tbl_ref[1:2, :], node_oh)
-    rs = (_sel_small(tbl_ref[2:3, :], node_oh) != 0) & (act_ref[:] != 0)
-    feat_oh = rf == jax.lax.broadcasted_iota(jnp.int32, (tile, d), 1)
+        jnp.int32, (NL, tile), 0)                          # (NL, tile)
+    rf = _sel_small(tbl_ref[:, 0:1], node_oh)              # (1, tile)
+    rt = _sel_small(tbl_ref[:, 1:2], node_oh)
+    rs = _sel_small(tbl_ref[:, 2:3], node_oh) != 0
+    feat_oh = rf == jax.lax.broadcasted_iota(jnp.int32, (d, tile), 0)
     val = jnp.sum(jnp.where(feat_oh, codes_ref[:].astype(jnp.int32), 0),
-                  axis=1, keepdims=True)
+                  axis=0, keepdims=True)
     go_right = (val > rt).astype(jnp.int32)
     asg = asg_ref[:]
     out_ref[:] = jnp.where(rs, 2 * asg + 1 + go_right, asg)
 
 
-def tree_route_level(codes, rel, active, assign, best_f, best_t, split,
+def tree_route_level(codes_T, rel, active, assign, best_f, best_t, split,
                      *, tile):
     """Route split-node rows to their children for one level — the fused
     replacement for ``route_block``. Returns the new (n,) int32 node
     assignment (leaf rows keep theirs)."""
-    n, d = codes.shape
+    d, n = codes_T.shape
     NL = best_f.shape[0]
     n_pad = -(-n // tile) * tile
-    tbl = jnp.stack([best_f.astype(jnp.int32), best_t.astype(jnp.int32),
-                     split.astype(jnp.int32)])
+    row_spec = pl.BlockSpec((1, tile), lambda t: (0, t))
     out = pl.pallas_call(
         _tree_route_kernel,
         grid=(n_pad // tile,),
         in_specs=[
-            pl.BlockSpec((tile, d), lambda t: (t, 0)),
-            pl.BlockSpec((tile, 1), lambda t: (t, 0)),
-            pl.BlockSpec((tile, 1), lambda t: (t, 0)),
-            pl.BlockSpec((tile, 1), lambda t: (t, 0)),
-            pl.BlockSpec((3, NL), lambda t: (0, 0)),
+            pl.BlockSpec((d, tile), lambda t: (0, t)),
+            row_spec,
+            row_spec,
+            pl.BlockSpec((NL, 3), lambda t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile, 1), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=_interpret(),
-    )(_pad_rows(codes, n_pad),
-      _pad_rows(rel.reshape(-1, 1), n_pad),
-      _pad_rows(active.reshape(-1, 1).astype(jnp.int32), n_pad),
-      _pad_rows(assign.reshape(-1, 1), n_pad), tbl)
-    return out[:n, 0]
+    )(_pad_lanes(codes_T, n_pad),
+      _row_vec(jnp.where(active, rel, -1), n_pad, -1),
+      _row_vec(assign, n_pad), _node_tables(best_f, best_t, split))
+    return out[0, :n]
 
 
 def _tree_descend_kernel(codes_ref, tbl_ref, out_ref, *, max_depth):
     """One row tile of full-tree descent: all ``max_depth`` levels of
     node-table lookups run over the VMEM-resident tile in one pass. tbl
-    packs [feat; thr; internal] as a (3, M) int32 block."""
-    tile, d = codes_ref.shape
-    M = tbl_ref.shape[1]
+    packs [feat, thr, internal] as (M, 3) int32."""
+    d, tile = codes_ref.shape
+    M = tbl_ref.shape[0]
     codes = codes_ref[:].astype(jnp.int32)
-    feat_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 1)
-    node_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, M), 1)
-    a = jnp.zeros((tile, 1), jnp.int32)
+    feat_iota = jax.lax.broadcasted_iota(jnp.int32, (d, tile), 0)
+    node_iota = jax.lax.broadcasted_iota(jnp.int32, (M, tile), 0)
+    a = jnp.zeros((1, tile), jnp.int32)
     for _ in range(max_depth):
         node_oh = a == node_iota
-        f = _sel_small(tbl_ref[0:1, :], node_oh)
-        t = _sel_small(tbl_ref[1:2, :], node_oh)
-        internal = _sel_small(tbl_ref[2:3, :], node_oh) != 0
-        val = jnp.sum(jnp.where(f == feat_iota, codes, 0), axis=1,
+        f = _sel_small(tbl_ref[:, 0:1], node_oh)
+        t = _sel_small(tbl_ref[:, 1:2], node_oh)
+        internal = _sel_small(tbl_ref[:, 2:3], node_oh) != 0
+        val = jnp.sum(jnp.where(f == feat_iota, codes, 0), axis=0,
                       keepdims=True)
         a = jnp.where(internal, 2 * a + 1 + (val > t).astype(jnp.int32), a)
     out_ref[:] = a
 
 
-_TREE_KERNELS_OK: dict = {}
-
-
-def tree_kernels_supported() -> bool:
-    """One-time probe that the tree kernels actually lower on this
-    backend (tiny jitted hist + route + descend calls, plus a vmapped
-    hist for the rf batched-tree path). Compiled Mosaic support can lag
-    interpret mode, and a kernel that fails at trace time deep inside a
-    sharded fit would take the whole build down — a failed probe instead
-    falls back to the XLA oracle path with a warning (models/trees.py
-    `_use_tree_kernel`). Cached per backend."""
-    backend = jax.default_backend()
-    if backend in _TREE_KERNELS_OK:
-        return _TREE_KERNELS_OK[backend]
-    try:
-        # Probe at the bench-representative shape (depth-5 defaults on a
-        # HIGGS-wide design), not a toy one, and at the TILE the fits
-        # actually select for it (tree_tile — probing a tile production
-        # never runs would let layout/shape-specific Mosaic failures
-        # through). Mosaic lowering failures tend to be
-        # layout/shape-specific.
-        n, d, nb, NL = 512, 28, 32, 16
-        tile = tree_tile(d, nb)
-        hdt = jnp.bfloat16 if backend == "tpu" else jnp.float32
-        codes = jnp.zeros((n, d), jnp.uint8)
-        stats = jnp.ones((2, n), jnp.float32)
-        rel = jnp.zeros((n,), jnp.int32)
-        act = jnp.ones((n,), bool)
-        tbl = jnp.zeros((NL,), jnp.int32)
-        M = 2 ** 6 - 1
-        mtbl = jnp.zeros((M,), jnp.int32)
-        h = jax.jit(partial(tree_histogram, n_nodes=NL, n_bins=nb,
-                            tile=tile, operand_dtype=hdt))(
-            codes, stats, rel, act)
-        # Every kernel is probed both plain and under vmap, at the batch
-        # positions the fit/predict programs actually use: rf's batched
-        # tree build vmaps stats + tables over a shared bin matrix, and
-        # the forest predict vmaps descent tables per tree. The leaf
-        # kernel has the most layout-hostile shapes of the four (one
-        # synthetic feature, non-lane-aligned n_bins=M) — probe it too.
-        jax.vmap(lambda s: tree_histogram(
-            codes, s, rel, act, n_nodes=NL, n_bins=nb, tile=tile,
-            operand_dtype=hdt))(jnp.stack([stats, stats]))
-        jax.jit(partial(tree_leaf_stats, n_nodes=M, tile=tile,
-                        operand_dtype=hdt))(rel, stats)
-        jax.vmap(lambda s: tree_leaf_stats(
-            rel, s, n_nodes=M, tile=tile, operand_dtype=hdt))(
-            jnp.stack([stats, stats]))
-        jax.jit(partial(tree_route_level, tile=tile))(
-            codes, rel, act, rel, tbl, tbl, tbl.astype(bool))
-        jax.vmap(lambda f: tree_route_level(
-            codes, rel, act, rel, f, tbl, tbl.astype(bool), tile=tile))(
-            jnp.stack([tbl, tbl]))
-        jax.jit(partial(tree_descend, max_depth=5))(
-            codes, mtbl, mtbl, mtbl.astype(bool))
-        jax.vmap(lambda f: tree_descend(
-            codes, f, mtbl, mtbl.astype(bool), max_depth=5))(
-            jnp.stack([mtbl, mtbl]))
-        # The uint8 extreme selects a different (smaller) tile whose
-        # accumulator block is lane-wider — probe that layout too.
-        nb256 = 256
-        jax.jit(partial(tree_histogram, n_nodes=NL, n_bins=nb256,
-                        tile=tree_tile(d, nb256), operand_dtype=hdt))(
-            codes, stats, rel, act).block_until_ready()
-        h.block_until_ready()
-        ok = True
-    except Exception as e:  # pragma: no cover - backend-specific
-        from learningorchestra_tpu.utils.structlog import get_logger
-
-        get_logger("pallas").warning(
-            "tree Pallas kernels unavailable on backend %r (%s); "
-            "falling back to the XLA contraction path", backend, e)
-        ok = False
-    _TREE_KERNELS_OK[backend] = ok
-    return ok
-
-
-def tree_descend(codes, feat, thr, internal, *, max_depth,
+def tree_descend(codes_T, feat, thr, internal, *, max_depth,
                  tile=TREE_ROUTE_TILE):
     """Leaf assignment for binned rows — the fused replacement for
     models/trees.py ``_descend``'s blocked per-level select loops.
     Returns (n,) int32 leaf node ids (bit-identical to the oracle: all
     arithmetic is integer)."""
-    n, d = codes.shape
+    d, n = codes_T.shape
     M = feat.shape[0]
     n_pad = -(-n // tile) * tile
-    tbl = jnp.stack([feat.astype(jnp.int32), thr.astype(jnp.int32),
-                     internal.astype(jnp.int32)])
     out = pl.pallas_call(
         partial(_tree_descend_kernel, max_depth=max_depth),
         grid=(n_pad // tile,),
         in_specs=[
-            pl.BlockSpec((tile, d), lambda t: (t, 0)),
-            pl.BlockSpec((3, M), lambda t: (0, 0)),
+            pl.BlockSpec((d, tile), lambda t: (0, t)),
+            pl.BlockSpec((M, 3), lambda t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile, 1), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, tile), lambda t: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=_interpret(),
-    )(_pad_rows(codes, n_pad), tbl)
-    return out[:n, 0]
+    )(_pad_lanes(codes_T, n_pad), _node_tables(feat, thr, internal))
+    return out[0, :n]

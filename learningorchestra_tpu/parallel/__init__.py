@@ -1,8 +1,2 @@
-# Version shims (jax.shard_map on older runtimes) must load before any
-# kernel or mesh op runs; every compute module imports through this
-# package, while jax-free entry points (supervisor, client SDK) never
-# pay the jax import.
-from learningorchestra_tpu.utils import compat as _compat  # noqa: F401
-
 from learningorchestra_tpu.parallel.mesh import (  # noqa: F401
     MeshRuntime, get_runtime, local_mesh, pad_rows, replicate, shard_rows)

@@ -54,15 +54,6 @@ def initialize(coordinator_address: Optional[str] = None,
         process_id = config.process_id()
     if coordinator_address is None and num_processes is None:
         return  # single-host
-    if "cpu" in (os.environ.get("JAX_PLATFORMS") or ""):
-        # Cross-process collectives on the CPU backend need an explicit
-        # implementation on older jax (0.4.x defaults to none, and every
-        # multi-process psum fails to compile). Best-effort: the option
-        # name may not exist on other versions.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — version-dependent option
-            pass
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -80,3 +71,33 @@ def process_info() -> dict:
         "devices": [str(d) for d in jax.devices()],
         "platform": jax.default_backend(),
     }
+
+
+def device_info() -> dict:
+    """The device every benchmark and smoke result names, as JAX reports
+    it: ``platform``, ``kind`` and ``count`` of ``jax.devices()``."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+#: The checkout this package runs from — the fixed place its compile
+#: cache lives (the path is part of the cache key, so it never moves).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_compile_cache() -> Optional[str]:
+    """Point JAX's persistent compilation cache at a place that survives
+    the process, before the first compile: where
+    ``JAX_COMPILATION_CACHE_DIR`` says if it is set (JAX reads it
+    itself — nothing is set in code), else ``<checkout>/.jax_cache``.
+    Returns the directory set in code, None when the environment
+    decides. Called by every long-lived entry point (the server, the
+    benches, chip_smoke.py), so a restart re-reads its fit programs
+    instead of recompiling them."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -305,9 +305,8 @@ class MeshRuntime:
     built once and shared by every job in the server process.
 
     ``shard_rows`` memoizes host→device transfers per host array: a
-    5-classifier build shards the same design matrix five times (and PCIe —
-    or worse, a tunneled TPU link — makes each gigabyte-scale transfer the
-    dominant cost), so the sharded device array is cached keyed by the host
+    5-classifier build shards the same design matrix five times (and the
+    host→device link makes each gigabyte-scale transfer a dominant cost), so the sharded device array is cached keyed by the host
     array's identity and dropped when the host array is garbage-collected.
     Callers must treat arrays handed to ``shard_rows`` as immutable; the
     cache *enforces* this by marking cached owner-arrays read-only (a later

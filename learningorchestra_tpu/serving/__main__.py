@@ -83,6 +83,7 @@ def main() -> None:
     settings.store_root = args.store_root
 
     distributed.initialize()
+    distributed.place_compile_cache()
     import jax
 
     if jax.process_count() > 1 and jax.process_index() != 0:

@@ -1,0 +1,177 @@
+"""The chip's compiler, asked without the chip.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+PR 7's histogram and leaf kernels passed all of it and were refused by
+the TPU compiler (rank-3 reshapes in VMEM). The TPU compiler is
+installed here and compiles for a *described* v5e that is not attached,
+so each Pallas kernel of the main path is compiled once at its published
+width: HIGGS's 28 features, the Spark-parity depth-5 / 32-bin defaults,
+rf's vmapped batch of 5 stat sets, the 256-bin uint8 extreme at its own
+tile, and the t-SNE repulsion at the 8,192-row plot size.
+
+Nothing runs — a pass here says nothing about results or times, and is
+never reported as a chip run. This is the one file that describes a
+topology: only one process may hold the TPU library, and the xdist
+worker that is handed this file keeps it until it exits.
+"""
+
+from functools import partial
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from learningorchestra_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+#: Published widths (module docstring).
+N, D, N_BINS, DEPTH, S = 1 << 20, 28, 32, 5, 2
+NL = 2 ** (DEPTH - 1)                 # per-level node width (16)
+M = 2 ** (DEPTH + 1) - 1              # nodes of a depth-5 tree (63)
+HDT = jnp.bfloat16                    # trees._hist_dtype() on the chip
+N_TSNE = 8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def chip_compiler(monkeypatch):
+    """Steer the kernels off interpret mode (the backend here is still
+    the CPU) and keep the persistent compile cache out of it: a compile
+    for a described device is written there but cannot be read back
+    without a chip, and the next one would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _hist(n_bins):
+    return (partial(pk.tree_histogram, n_nodes=NL, n_bins=n_bins,
+                    tile=pk.tree_tile(D, n_bins), operand_dtype=HDT),
+            [((D, N), jnp.uint8), ((S, N), jnp.float32),
+             ((N,), jnp.int32), ((N,), jnp.bool_)])
+
+
+def _hist_vmapped():
+    fn, (codes, stats, rel, act) = _hist(N_BINS)
+    # rf's batched build (trees._forest_batch_shape(20) → batches of 5):
+    # stats and node ids carry the tree axis, the bin matrix is shared.
+    return (jax.vmap(fn, in_axes=(None, 0, 0, 0)),
+            [codes, ((5,) + stats[0], stats[1]), ((5,) + rel[0], rel[1]),
+             ((5,) + act[0], act[1])])
+
+
+def _leaf():
+    # One synthetic feature, non-lane-aligned bin count (63).
+    return (partial(pk.tree_leaf_stats, n_nodes=M,
+                    tile=pk.tree_tile(D, N_BINS), operand_dtype=HDT),
+            [((N,), jnp.int32), ((S, N), jnp.float32)])
+
+
+def _route():
+    node = ((NL,), jnp.int32)
+    return (partial(pk.tree_route_level, tile=pk.tree_tile(D, N_BINS)),
+            [((D, N), jnp.uint8), ((N,), jnp.int32), ((N,), jnp.bool_),
+             ((N,), jnp.int32), node, node, ((NL,), jnp.bool_)])
+
+
+def _descend(trees=None):
+    lead = () if trees is None else (trees,)
+    tbl = (lead + (M,), jnp.int32)
+    fn = partial(pk.tree_descend, max_depth=DEPTH)
+    if trees is not None:        # the forest predict: tables per tree
+        fn = jax.vmap(fn, in_axes=(None, 0, 0, 0))
+    return fn, [((D, N), jnp.uint8), tbl, tbl, (lead + (M,), jnp.bool_)]
+
+
+def _tsne():
+    return (pk.tsne_repulsion,
+            [((N_TSNE, 2), jnp.float32), ((N_TSNE,), jnp.float32)])
+
+
+def _tsne_rows():
+    # One shard of the row-sharded descent on a four-chip host.
+    nq = N_TSNE // 4
+    return (pk.tsne_repulsion_rows,
+            [((nq, 2), jnp.float32), ((nq,), jnp.float32),
+             ((N_TSNE, 2), jnp.float32), ((N_TSNE,), jnp.float32),
+             ((), jnp.int32)])
+
+
+CASES = {
+    "tree_histogram-32bins": lambda: _hist(32),
+    "tree_histogram-256bins": lambda: _hist(256),
+    "tree_histogram-vmap5": _hist_vmapped,
+    "tree_leaf_stats": _leaf,
+    "tree_route_level": _route,
+    "tree_descend": _descend,
+    "tree_descend-vmap20": lambda: _descend(20),
+    "tsne_repulsion": _tsne,
+    "tsne_repulsion_rows": _tsne_rows,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("family", ["forest", "gbt"])
+def test_tree_predict_compiles_on_four_chips(family, topo, chip_compiler):
+    """The batch predict of a row-sharded design on the 2x2 host: XLA
+    cannot partition a Mosaic kernel by itself (the first four-chip run
+    failed on exactly that), so the predict programs run the descent
+    kernel under shard_map over the design's mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from learningorchestra_tpu.config import Settings
+    from learningorchestra_tpu.models import trees
+    from learningorchestra_tpu.parallel.mesh import DATA_AXIS, local_mesh
+
+    mesh = local_mesh(Settings(), devices=topo.devices)
+    rep = NamedSharding(mesh, P())
+
+    def shape(dims, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    trees_n = 20
+    params = {"edges": shape((D, N_BINS - 1), jnp.float32),
+              "feat": shape((trees_n, M), jnp.int32),
+              "thr": shape((trees_n, M), jnp.int32),
+              "internal": shape((trees_n, M), jnp.bool_)}
+    if family == "forest":
+        fn = trees._forest_proba_static
+        params["leaf"] = shape((trees_n, M, 2), jnp.float32)
+    else:
+        fn = trees._gbt_proba_static
+        params["leaf_val"] = shape((trees_n, M), jnp.float32)
+        params["step_size"] = shape((), jnp.float32)
+    X = shape((100_000, D), jnp.float32,
+              NamedSharding(mesh, P(DATA_AXIS, None)))
+    compiled = fn.program.lower(params, X, max_depth=DEPTH,
+                                mesh=mesh).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -92,7 +92,7 @@ def test_descend_kernel_parity():
     feat = jnp.asarray(rng.integers(0, d, M).astype(np.int32))
     thr = jnp.asarray(rng.integers(0, 32, M).astype(np.int32))
     internal = jnp.asarray(rng.random(M) < 0.7)
-    a_k = np.asarray(pk.tree_descend(B, feat, thr, internal,
+    a_k = np.asarray(pk.tree_descend(B.T, feat, thr, internal,
                                      max_depth=max_depth))
     a_o = np.asarray(trees._descend(B, feat, thr, internal, max_depth,
                                     use_kernel=False))
@@ -108,6 +108,28 @@ def test_tree_kernel_disabled_via_use_pallas():
     cfg.use_pallas = False
     cfg.tree_kernel = True
     assert trees._use_tree_kernel(MeshRuntime(cfg)) is False
+
+
+def test_refused_kernel_fails_the_fit(monkeypatch):
+    """No quiet fallback: with the flags on (the default), a kernel the
+    compiler refuses raises out of the fit — no oracle-path model comes
+    back in its place."""
+    class Refused(Exception):
+        pass
+
+    def refuse(*_a, **_k):
+        raise Refused("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pk, "tree_histogram", refuse)
+    rt = _runtime(True)
+    assert trees._use_tree_kernel(rt) is True
+    X, y = _blobs(257, d=5, seed=11)       # a shape no other test jits
+    for kind in ("dt", "gb"):
+        with pytest.raises(Refused):
+            get_trainer(kind)(rt, X, y, 2, max_depth=2)
+    # The explicit oracle path is untouched by the broken kernel.
+    assert get_trainer("dt")(_runtime(False), X, y, 2,
+                             max_depth=2).params["feat"].shape == (1, 7)
 
 
 def test_n_bins_validator_shared():
@@ -128,8 +150,6 @@ def test_per_level_psum_parity_multi_shard():
     path: one level-0 histogram computed inside shard_map on the
     8-device mesh, reduced with the same single psum, is bit-identical
     kernel-vs-oracle (integer stats — exact under any tiling)."""
-    import learningorchestra_tpu.parallel  # noqa: F401 (compat shim)
-
     n, d, nb, NL, S = 2048, 5, 16, 4, 3
     rng = np.random.default_rng(0)
     B = rng.integers(0, nb, (n, d)).astype(np.uint8)
@@ -141,7 +161,7 @@ def test_per_level_psum_parity_multi_shard():
     def run(kernel):
         def fn(B, sT, rel, act):
             if kernel:
-                h = pk.tree_histogram(B, sT, rel, act, n_nodes=NL,
+                h = pk.tree_histogram(B.T, sT, rel, act, n_nodes=NL,
                                       n_bins=nb, tile=pk.tree_tile(d, nb))
             else:
                 blk, _, n_pad = trees._block_shape(B.shape[0], d * nb)

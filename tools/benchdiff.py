@@ -1,6 +1,6 @@
 """benchdiff — normalize BENCH_*.json schemas and gate on regressions.
 
-The bench trajectory (BENCH_r0N.json, BENCH_serving.json,
+The bench trajectory (bench.py output, BENCH_serving.json,
 MULTICHIP_r0N.json) has grown three shapes over the PRs: driver wrappers
 (``{n, cmd, rc, tail, parsed}``), bare metric documents, and lists of
 metric documents. Nothing machine-checked it — a perf regression only
