@@ -465,11 +465,6 @@ class Settings:
     )
 
     # --- observability -----------------------------------------------------
-    #: When set, compute jobs run under jax.profiler.trace writing
-    #: TensorBoard-loadable device traces here.
-    profile_dir: str = field(
-        default_factory=lambda: _env("LO_TPU_PROFILE_DIR", "")
-    )
     #: Capacity (spans) of the in-process trace ring buffer
     #: (utils/tracing.py). Old spans evict FIFO past this, so a long-lived
     #: server holds a bounded window of recent traces. 0 disables span

@@ -34,7 +34,12 @@ executables. The sweep is PIPELINED on both execution paths:
 
 Each fit records ``device_s`` — dispatch through blocked completion of
 its device programs — next to wall-clock, the split that separates
-host jitter from device compute. Output
+host jitter from device compute. Under a sampled trace the build is one
+span tree, each span opened around its work (so it also lies on a
+running device profile's timeline, utils/tracing.py): ``build`` >
+``design.build``, ``fit.<c>`` > ``host_prep`` / ``gate_wait`` /
+``dispatch`` / ``device`` / ``finish`` > ``score`` / ``model`` /
+``rows`` / ``store`` (docs/observability.md has the table). Output
 contract is preserved: dataset ``<name>_<classifier>`` per classifier,
 metrics in its metadata.
 """
@@ -60,7 +65,7 @@ from learningorchestra_tpu.parallel import spmd
 from learningorchestra_tpu.parallel.mesh import MeshRuntime
 from learningorchestra_tpu.utils import fitckpt, resources, tracing
 from learningorchestra_tpu.utils.profiling import (
-    device_span, device_trace, op_timer, timed)
+    device_span, op_timer, timed)
 
 
 class ModelBuilder:
@@ -115,7 +120,24 @@ class ModelBuilder:
         ``existing=True`` means the caller already created the prediction
         datasets (the async route does, metadata-first, so pollers can see
         them — and their failure flags — from the moment of submission).
+
+        One ``build`` span covers the whole of it, on the sync route and
+        in a job alike: what a request spends outside it is the REST
+        layer's own (``rest_overhead_s.sweep``).
         """
+        span_attrs = {"train": train, "test": test,
+                      "classifiers": list(classifiers)}
+        with tracing.span("build", span_attrs):
+            return self._build(span_attrs, train, test, prediction_name,
+                               classifiers, label, steps, preprocessor_code,
+                               hparams, existing)
+
+    def _build(self, span_attrs: Dict[str, Any], train: str, test: str,
+               prediction_name: str, classifiers: Sequence[str], label: str,
+               steps: Sequence[Dict[str, Any]],
+               preprocessor_code: Optional[str],
+               hparams: Optional[Dict[str, Dict[str, Any]]],
+               existing: bool) -> List[FitReport]:
         train_ds = self.store.get(train)
         test_ds = self.store.get(test)
         hparams = hparams or {}
@@ -208,6 +230,7 @@ class ModelBuilder:
             "design.build", time.monotonic() - design_t0,
             attrs={"train": train, "test": test, "streamed": streamed,
                    "rows": int(len(X_train))})
+        span_attrs["rows"] = int(len(X_train))
         if y_train is None:
             raise ValueError(f"label field {label!r} not in {train!r}")
         num_classes = int(max(int(y_train.max()) + 1,
@@ -251,7 +274,7 @@ class ModelBuilder:
             prep_s)."""
             trainer = get_trainer(c)
             hp = hparams.get(c, {})
-            with Timer() as tp:
+            with tracing.span(f"fit.{c}.host_prep"), Timer() as tp:
                 prep = getattr(trainer, "host_prep", None)
                 extra = prep(X_train, **hp) if prep is not None else {}
             return extra, tp.elapsed
@@ -300,35 +323,39 @@ class ModelBuilder:
             batched round, the family's prep-to-probabilities wall span
             (spans overlap across families, so build wall-clock below
             their sum is the overlap evidence)."""
-            preds = np.argmax(probs, axis=1)
-            report = FitReport(kind=c, fit_time=fit_time)
-            if y_test is not None and (y_test >= 0).all():
-                report.metrics = classification_metrics(
-                    y_test, preds, num_classes)
-            report.metrics["device_s"] = round(device_s, 6)
-            if self.cfg.persist_models:
-                # Best-effort: a persistence failure must not discard an
-                # otherwise successful fit's predictions; surface it in the
-                # persisted metrics instead.
-                try:
-                    self.registry.save(f"{prediction_name}_{c}", model,
-                                       metrics=report.metrics,
-                                       preprocess=pp_meta)
-                except Exception as exc:  # noqa: BLE001 — isolation boundary
-                    report.metrics["persist_error"] = (
-                        f"{type(exc).__name__}: {exc}")
-            self._save_predictions(f"{prediction_name}_{c}", test_ds,
-                                   preds, probs, report)
-            # The family reached its terminal outputs: its mid-fit
-            # checkpoint stream is superseded (a retry of THIS family
-            # can no longer happen — the retry machinery refits only
-            # families whose datasets failed), so reclaim the disk.
-            if c in ckpt_ctxs:
-                ckpt_ctxs[c].clear()
-            from learningorchestra_tpu import jobs
+            with tracing.span(f"fit.{c}.finish"):
+                with tracing.span(f"fit.{c}.finish.score"):
+                    preds = np.argmax(probs, axis=1)
+                    report = FitReport(kind=c, fit_time=fit_time)
+                    if y_test is not None and (y_test >= 0).all():
+                        report.metrics = classification_metrics(
+                            y_test, preds, num_classes)
+                report.metrics["device_s"] = round(device_s, 6)
+                if self.cfg.persist_models:
+                    # Best-effort: a persistence failure must not discard
+                    # an otherwise successful fit's predictions; surface it
+                    # in the persisted metrics instead.
+                    try:
+                        with tracing.span(f"fit.{c}.finish.model"):
+                            self.registry.save(
+                                f"{prediction_name}_{c}", model,
+                                metrics=report.metrics, preprocess=pp_meta)
+                    except Exception as exc:  # noqa: BLE001 — isolation
+                        report.metrics["persist_error"] = (
+                            f"{type(exc).__name__}: {exc}")
+                self._save_predictions(f"{prediction_name}_{c}", test_ds,
+                                       preds, probs, report,
+                                       phase=f"fit.{c}.finish")
+                # The family reached its terminal outputs: its mid-fit
+                # checkpoint stream is superseded (a retry of THIS family
+                # can no longer happen — the retry machinery refits only
+                # families whose datasets failed), so reclaim the disk.
+                if c in ckpt_ctxs:
+                    ckpt_ctxs[c].clear()
+                from learningorchestra_tpu import jobs
 
-            jobs.heartbeat()
-            return report
+                jobs.heartbeat()
+                return report
 
         def fail_report(c: str, exc: Exception) -> FitReport:
             self.store.fail(f"{prediction_name}_{c}",
@@ -377,9 +404,7 @@ class ModelBuilder:
         Every family gets a thread; a semaphore — not the pool size —
         caps how many sit in their device phase, so host prep and host
         finishing of other families overlap device compute while the
-        device working set stays bounded. One device trace spans the
-        whole build (JAX allows a single active trace per process, so
-        per-fit tracing would collide).
+        device working set stays bounded.
 
         On a MULTI-DEVICE mesh the device phase serializes outright
         (gate of 1) regardless of ``max_concurrent_fits``: every fit and
@@ -418,8 +443,9 @@ class ModelBuilder:
                     # never disagree about whether a family succeeded.
                     with tracing.span(f"fit.{c}", family=c):
                         extra, prep_s = prep_fit(c)   # outside the gate
-                        tracing.record_span(f"fit.{c}.host_prep", prep_s)
-                        with gate:                    # device phase
+                        with tracing.span(f"fit.{c}.gate_wait"):
+                            gate.acquire()            # device phase
+                        try:
                             # family_phase attributes the fit program's
                             # compile seconds to this family; the
                             # probability pass's compiles land via
@@ -430,24 +456,24 @@ class ModelBuilder:
                             # (a gate >1 admits concurrent families) —
                             # overlapped windows record peaks only,
                             # never a double-counted compile_s.
-                            with Timer() as td, resources.family_phase(c):
+                            with Timer() as td, \
+                                    resources.family_phase(c) as phase, \
+                                    tracing.span(f"fit.{c}.dispatch", phase):
                                 model = dispatch_fit(c, extra)
                             pre_s = prep_s + td.elapsed
                             probs, device_s = collect_fit(c, model, pre_s)
+                        finally:
+                            gate.release()
                         # fit_time = prep + dispatch + device spans, no
                         # scheduler waits: the per-family sum estimates
                         # the serialized sweep, and the gap to build
                         # wall-clock IS the overlap won.
-                        with Timer() as tf:
-                            report = finish_host(c, model, probs,
-                                                 pre_s + device_s,
-                                                 device_s)
-                        tracing.record_span(f"fit.{c}.finish", tf.elapsed)
-                        return report
+                        return finish_host(c, model, probs,
+                                           pre_s + device_s, device_s)
                 except Exception as exc:  # noqa: BLE001 — per-model bound
                     return fail_report(c, exc)
 
-        with device_trace(self.cfg), ThreadPoolExecutor(
+        with ThreadPoolExecutor(
                 max_workers=max(len(classifiers), 1)) as pool:
             futures = {c: pool.submit(fit_guarded, c) for c in classifiers}
             return [fut.result() for fut in futures.values()]
@@ -479,7 +505,7 @@ class ModelBuilder:
         numerically different (or wider) matrices than process 0's."""
         fitted: Dict[str, Any] = {}
         results: Dict[str, Any] = {}
-        with device_trace(self.cfg), spmd.dispatch_job(
+        with spmd.dispatch_job(
                 self.store, (train, test), {
                     "op": "build", "train": train, "test": test,
                     "label": label, "steps": list(steps),
@@ -496,13 +522,13 @@ class ModelBuilder:
                 t0 = time.time()
                 try:
                     extra, prep_s = prep_fit(c)
-                    tracing.record_span(f"fit.{c}.host_prep", prep_s)
                     # Same compile-attribution split as the pipelined
                     # path: fit-program compiles here, the probability
                     # pass's via collect_fit's device_span. This loop is
                     # sequential, so these windows never overlap and
                     # always attribute.
-                    with resources.family_phase(c):
+                    with resources.family_phase(c) as phase, \
+                            tracing.span(f"fit.{c}.dispatch", phase):
                         model = dispatch_fit(c, extra)
                         # No-op on TPU (stream order keeps back-to-back
                         # programs aligned); fences the CPU test rig,
@@ -536,9 +562,7 @@ class ModelBuilder:
                 reports.append(fail_report(c, res))
                 continue
             try:
-                with Timer() as tf:
-                    reports.append(finish_host(c, *res))
-                tracing.record_span(f"fit.{c}.finish", tf.elapsed)
+                reports.append(finish_host(c, *res))
             except Exception as exc:  # noqa: BLE001 — per-model boundary
                 reports.append(fail_report(c, exc))
         return reports
@@ -564,7 +588,7 @@ class ModelBuilder:
             self.store.create(out_name, parent=dataset,
                               extra={"model": model_name, "kind": man["kind"]})
         streamed = ds.over_budget or self.cfg.stream_design
-        with timed("model_predict"), device_trace(self.cfg):
+        with timed("model_predict"):
             if streamed:
                 X, _, _, _ = preprocess.design_matrix_streamed(
                     ds, pp["label"], pp["steps"], state=pp["state"],
@@ -582,7 +606,8 @@ class ModelBuilder:
                 probs = model.predict_proba(self.runtime, X)
         preds = np.argmax(probs, axis=1)
         self._save_predictions(out_name, ds, preds, probs,
-                               FitReport(kind=man["kind"], fit_time=0.0))
+                               FitReport(kind=man["kind"], fit_time=0.0),
+                               phase="predict.save")
 
     # -- device-resident hyperparameter search (models/tune.py) --------------
 
@@ -643,7 +668,7 @@ class ModelBuilder:
                         "mesh": dict(self.runtime.mesh.shape)},
                 snapshot=f"rows={int(len(X_train))}")
         try:
-            with device_trace(self.cfg), timed("tune"), \
+            with timed("tune"), \
                     tracing.span("tune.sweep", family=classifier,
                                  configs=len(configs)):
                 board = tune_mod.sweep(
@@ -685,11 +710,15 @@ class ModelBuilder:
         return board
 
     def _save_predictions(self, name: str, test_ds, preds: np.ndarray,
-                          probs: np.ndarray, report: FitReport) -> None:
+                          probs: np.ndarray, report: FitReport,
+                          phase: str) -> None:
         """Write the prediction dataset: original test rows + prediction +
         probability list; metrics into metadata (reference
         model_builder.py:191-248 drops 'features'/'rawPrediction' and
-        converts the probability vector to a plain list)."""
+        converts the probability vector to a plain list). Two spans
+        under the caller's ``phase``: ``<phase>.rows`` gathers the
+        columns (the probability lists are a Python loop over every
+        row), ``<phase>.store`` appends and commits them."""
         ds = self.store.get(name)
         n = len(preds)
 
@@ -701,6 +730,10 @@ class ModelBuilder:
                 out[i] = [float(x) for x in p]
             return out
 
+        def commit() -> None:
+            self.store.finish(name, fit_time=report.fit_time,
+                              **report.metrics)
+
         if test_ds.over_budget or self.cfg.stream_design:
             # Out-of-core test set (or forced streaming): write the
             # prediction dataset in row blocks instead of consolidating
@@ -710,16 +743,20 @@ class ModelBuilder:
             block = 1 << 18
             for off in range(0, n, block):
                 stop = min(off + block, n)
-                cols = test_ds.read_rows(None, off, stop)
-                cols["prediction"] = preds[off:stop].astype(np.int64)
-                cols["probability"] = prob_objcol(probs[off:stop])
-                ds.append_columns(cols)
+                with tracing.span(f"{phase}.rows"):
+                    cols = test_ds.read_rows(None, off, stop)
+                    cols["prediction"] = preds[off:stop].astype(np.int64)
+                    cols["probability"] = prob_objcol(probs[off:stop])
+                with tracing.span(f"{phase}.store"):
+                    ds.append_columns(cols)
+            with tracing.span(f"{phase}.store"):
+                commit()
         else:
-            cols = {f: test_ds.columns[f] for f in test_ds.metadata.fields}
-            cols["prediction"] = preds.astype(np.int64)
-            cols["probability"] = prob_objcol(probs)
-            ds.append_columns(cols)
-        self.store.finish(
-            name,
-            fit_time=report.fit_time,
-            **{k: v for k, v in report.metrics.items()})
+            with tracing.span(f"{phase}.rows"):
+                cols = {f: test_ds.columns[f]
+                        for f in test_ds.metadata.fields}
+                cols["prediction"] = preds.astype(np.int64)
+                cols["probability"] = prob_objcol(probs)
+            with tracing.span(f"{phase}.store"):
+                ds.append_columns(cols)
+                commit()

@@ -26,6 +26,13 @@ ops XLA alone schedules sub-optimally. Residents:
 On non-TPU backends every `pallas_call` runs in interpreter mode, so the
 same code path is unit-tested on the CPU mesh (tests/conftest.py) and
 cross-checked against the pure-XLA reference implementation.
+
+Each `pallas_call` carries a `name=` (`tsne_repulsion`, `tree_hist`,
+`tree_route`, `tree_descend`): it becomes the innermost name scope, XLA
+names the custom-call instruction after it (`%tree_hist.3 = ...
+custom_call_target="tpu_custom_call"`), and that instruction text is the
+event's name on a device profile's `XLA Ops` line — which is all the
+benchmark's per-kernel metrics read (perfbench/layer_metrics).
 """
 
 from __future__ import annotations
@@ -143,6 +150,7 @@ def tsne_repulsion_rows(Yq: jax.Array, validq: jax.Array, Y: jax.Array,
             jax.ShapeDtypeStruct((nq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="tsne_repulsion",
     )(off, xr, yr, vr, xc, yc, vc)
     return z[0, 0], jnp.concatenate([fx, fy], axis=1)
 
@@ -307,6 +315,7 @@ def _hist_call(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
         out_specs=pl.BlockSpec((None, NG * S, Wp), lambda g, t: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, NG * S, Wp), jnp.float32),
         interpret=_interpret(),
+        name="tree_hist",
     )(_pad_lanes(codes_T, n_pad), _pad_lanes(stats_T, n_pad), rel)
     return out.reshape(n_nodes * S, Wp)[:, :d * n_bins]
 
@@ -398,6 +407,7 @@ def tree_route_level(codes_T, rel, active, assign, best_f, best_t, split,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=_interpret(),
+        name="tree_route",
     )(_pad_lanes(codes_T, n_pad),
       _row_vec(jnp.where(active, rel, -1), n_pad, -1),
       _row_vec(assign, n_pad), _node_tables(best_f, best_t, split))
@@ -444,5 +454,6 @@ def tree_descend(codes_T, feat, thr, internal, *, max_depth,
         out_specs=pl.BlockSpec((1, tile), lambda t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=_interpret(),
+        name="tree_descend",
     )(_pad_lanes(codes_T, n_pad), _node_tables(feat, thr, internal))
     return out[0, :n]

@@ -851,7 +851,6 @@ class App:
                "compile": resources.compile_snapshot(),
                "pod": {"error": pod_error,
                        "degraded": pod_error is not None},
-               "profile_dir": self.cfg.profile_dir or None,
                # Cross-host replication plane: per-dataset lag against
                # each peer's acked watermark, push/fetch/repair
                # counters, and the under-replicated list the

@@ -13,13 +13,13 @@ UIs. Here:
   derive from (a rolling sample window keeps only recent shape; the
   histogram is exact over the op's whole life at O(#buckets) memory);
 - ``timed``/``device_span`` are span-emitting: under an ambient trace
-  (utils/tracing.py) each timed region also records a span with the
+  (utils/tracing.py) each timed region is also a span pinned to the
   exact measured duration, so per-request traces and aggregate metrics
   can never disagree about the same measurement;
-- setting ``LO_TPU_PROFILE_DIR`` wraps compute jobs in
-  ``jax.profiler.trace`` so every XLA op, transfer, and collective lands
-  in a TensorBoard-loadable trace — the device-level view Spark's stage UI
-  approximated.
+- the device-level view Spark's stage UI approximated is ``POST
+  /debug/profile`` (``resources.capture_profile``): every XLA op,
+  transfer and collective in a TensorBoard-loadable trace, with the
+  program's spans beside them on the same clock.
 """
 
 from __future__ import annotations
@@ -30,7 +30,6 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence
 
-from learningorchestra_tpu.config import Settings
 from learningorchestra_tpu.utils import tracing
 
 #: Log-spaced histogram bucket upper bounds, seconds (Prometheus-style
@@ -127,17 +126,25 @@ op_timer = OpTimer()
 @contextmanager
 def timed(name: str, timer: Optional[OpTimer] = None):
     """Time a region into the op timer AND, under an ambient trace,
-    record a span of the same name with the identical duration."""
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        dur = time.time() - t0
-        (timer or op_timer).record(name, dur)
-        tracing.record_span(name, dur)
+    open a span of the same name pinned to the identical duration."""
+    with tracing.span(name) as sp:
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            dur = time.time() - t0
+            (timer or op_timer).record(name, dur)
+            _pin(sp, dur)
 
 
-def device_span(fn, name: Optional[str] = None):
+def _pin(sp: Optional[tracing.TraceContext], duration_s: float) -> None:
+    """Make the span ``sp`` was yielded for record ``duration_s`` (no
+    ambient trace, or an unsampled one: there is no span to pin)."""
+    if sp is not None and sp.sampled:
+        sp.duration_s = duration_s
+
+
+def device_span(fn, name: str):
     """Run ``fn`` (a thunk whose result is a pytree of jax arrays or a
     value derived from them) and return ``(result, seconds)`` where the
     span covers program dispatch *through blocked completion* — JAX
@@ -151,10 +158,11 @@ def device_span(fn, name: Optional[str] = None):
     device compute in the bench. Under overlapped dispatch it includes
     queue waits behind other programs and is reported as such.
 
-    ``name`` additionally records a trace span (ambient context) with
-    the exact same measured duration — the builder passes
+    ``name`` opens a trace span (ambient context) around the work,
+    pinned to the exact same measured duration — the builder passes
     ``fit.<family>.device`` so a job's trace and its ``fit_device_s``
-    profile figure agree to the digit.
+    profile figure agree to the digit, and a device profile shows the
+    wait on its own timeline.
 
     Every device phase is also a resource sample point
     (``resources.device_phase``): the compile-seconds delta across the
@@ -162,42 +170,23 @@ def device_span(fn, name: Optional[str] = None):
     the counter is process-global) and a device-bytes reading at its
     end merge into the current job's watermarks (``peak_hbm_bytes``)
     and — for ``fit.<family>.device`` names — the per-family table
-    bench.py and the job profile's ``fit_resources`` read. Best-effort:
-    a sampling failure degrades to an unprofiled span, never a failed
-    fit.
+    bench.py and the job profile's ``fit_resources`` read; the span
+    carries the phase's ``compiles`` / ``compile_s`` as attributes.
+    Best-effort: a sampling failure degrades to an unprofiled span,
+    never a failed fit.
     """
     import jax
 
     from learningorchestra_tpu.utils import resources
 
-    with resources.device_phase(name):
-        # Timed INSIDE the sampling window so the measured duration
-        # stays the pure dispatch-to-completion figure (the sampling
-        # reads at window exit never inflate device_s).
-        t0 = time.time()
-        out = jax.block_until_ready(fn())
-        dur = time.time() - t0
-    if name is not None:
-        tracing.record_span(name, dur)
+    with resources.device_phase(name) as phase:
+        # The span sits INSIDE the sampling window so its extent stays
+        # the pure dispatch-to-completion figure (the sampling reads at
+        # window exit never inflate device_s); the phase's figures land
+        # in its attributes after it closed (recorded by reference).
+        with tracing.span(name, phase) as sp:
+            t0 = time.time()
+            out = jax.block_until_ready(fn())
+            dur = time.time() - t0
+            _pin(sp, dur)
     return out, dur
-
-
-#: JAX allows one active profiler trace per process; concurrent jobs that
-#: both request tracing serialize on this lock instead of crashing.
-_trace_lock = threading.Lock()
-
-
-@contextmanager
-def device_trace(cfg: Settings):
-    """jax.profiler trace around a compute job when profile_dir is set.
-
-    Wrap whole jobs (a full multi-classifier build, one predict call) —
-    not per-thread work items — so a trace covers a meaningful span.
-    """
-    if not cfg.profile_dir:
-        yield
-        return
-    import jax
-
-    with _trace_lock, jax.profiler.trace(cfg.profile_dir):
-        yield

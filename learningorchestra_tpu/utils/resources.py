@@ -431,7 +431,12 @@ def device_phase(name: Optional[str]):
     window overlapped another phase — attribution would double-count)
     and a current-device-bytes sample at exit, merged via
     :func:`observe_device_phase`. Exception-transparent — a failing
-    phase still records what it consumed before dying."""
+    phase still records what it consumed before dying.
+
+    Yields a dict that gains ``compiles`` and ``compile_s`` at exit
+    (both None for an overlapped window): the builder hands it to the
+    phase's span as its attributes, which are recorded by reference, so
+    a trace of a warm-up sweep says which step compiled."""
     ensure_listener()
     token = object()
     with _lock:
@@ -439,15 +444,19 @@ def device_phase(name: Optional[str]):
             _overlapped_phases.update(_open_phases)
             _overlapped_phases.add(token)
         _open_phases.add(token)
-    c0 = compile_seconds()
+        n0, c0 = _compile["compiles"], _compile["compile_s"]
+    phase: Dict[str, Any] = {}
     try:
-        yield
+        yield phase
     finally:
-        delta = compile_seconds() - c0
         with _lock:
+            count = _compile["compiles"] - n0
+            delta = _compile["compile_s"] - c0
             _open_phases.discard(token)
             overlapped = token in _overlapped_phases
             _overlapped_phases.discard(token)
+        phase["compiles"] = None if overlapped else count
+        phase["compile_s"] = None if overlapped else round(delta, 6)
         try:
             observe_device_phase(name, None if overlapped else delta,
                                  hbm_bytes_in_use())
@@ -489,17 +498,21 @@ def job_phase():
 PROFILE_MAX_SECONDS = 60.0
 
 
+#: JAX allows one active profiler trace per process; concurrent
+#: captures serialize here instead of crashing.
+_profile_lock = threading.Lock()
+
+
 def capture_profile(out_dir: str, seconds: float) -> str:
     """Capture a ``jax.profiler`` trace of this process for ``seconds``
-    into ``out_dir`` (TensorBoard-loadable). Serializes on the same lock
-    as ``device_trace`` — JAX allows one active trace per process."""
+    into ``out_dir`` (TensorBoard-loadable): the device's ops and, on
+    the ``/host:CPU`` plane and the same clock, every span the program
+    opened meanwhile (``tracing.span`` enters a ``TraceAnnotation``)."""
     import jax
-
-    from learningorchestra_tpu.utils import profiling
 
     seconds = min(max(0.0, float(seconds)), PROFILE_MAX_SECONDS)
     os.makedirs(out_dir, exist_ok=True)
-    with profiling._trace_lock:
+    with _profile_lock:
         jax.profiler.start_trace(out_dir)
         try:
             time.sleep(seconds)

@@ -21,6 +21,13 @@ Design (stdlib-only, like lolint):
 - **spans** record name, parent link, monotonic-clock duration, wall
   start, attributes (dataset, model, rows, ...), status, and the
   recording process;
+- a span opened as a context (``trace``/``span``) also enters a
+  ``jax.profiler.TraceAnnotation`` of its name for its life, so a
+  running device profile (the benchmark's, or an operator's ``POST
+  /debug/profile``) holds the program's spans on its ``/host:CPU``
+  plane, on the device events' clock. With no profile running that is
+  one inactive TraceMe; a process that never imported JAX skips it.
+  ``record_span`` (durations measured elsewhere) emits none;
 - spans land in a bounded **ring buffer** (``LO_TPU_TRACE_BUFFER_SPANS``,
   FIFO eviction — a long-lived server holds a recent window, never
   leaks); ``GET /traces`` lists recent root spans, ``GET /trace/{id}``
@@ -34,14 +41,16 @@ Design (stdlib-only, like lolint):
 Recording is cheap by construction: one ``os.urandom`` id + a dict and
 a deque-append under a short lock per span, no I/O, no serialization
 until a ``/traces`` read. The serving hot path adds ~4 spans per traced
-request; see bench.py's ``tracing_overhead`` section for the measured
-cost.
+request, a four-family sweep 47; PERF.md section 6 (PR 27) has
+the cost measured on the chip: nanoseconds per span with and without a
+running profile, and the traced sweep against the untraced one.
 """
 
 from __future__ import annotations
 
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -61,14 +70,18 @@ __all__ = [
 class TraceContext:
     """The ambient trace position of the current logical operation:
     which trace, which span is the would-be parent, and whether this
-    trace records at all."""
+    trace records at all. A block that times its own work sets
+    ``duration_s`` on the context its ``span`` yielded, and the span
+    records that figure instead of its own clock's (``device_span``:
+    the report's ``device_s`` and the span stay one measurement)."""
 
-    __slots__ = ("trace_id", "span_id", "sampled")
+    __slots__ = ("trace_id", "span_id", "sampled", "duration_s")
 
     def __init__(self, trace_id: str, span_id: str, sampled: bool):
         self.trace_id = trace_id
         self.span_id = span_id
         self.sampled = sampled
+        self.duration_s: Optional[float] = None
 
 
 class Span:
@@ -195,7 +208,10 @@ def current() -> Optional[TraceContext]:
 
 #: Span names aggregated into the per-model/per-phase histogram table,
 #: mapped to the attribute carrying their label. ``fit.<family>.<sub>``
-#: names are handled structurally (phase ``fit.<sub>``, label family).
+#: names are handled structurally (phase ``fit.<sub>``, label family;
+#: ``fit.gb.finish.rows`` is phase ``fit.finish.rows`` of ``gb``). The
+#: builder's taxonomy is 10 phases a family, 50 entries for the five
+#: families: a tenth of the cap below.
 _ATTR_PHASES = {"queue.wait": "model", "dispatch.device": "model",
                 "design.build": "model", "batch.coalesce": "model",
                 "http.handle": "route"}
@@ -225,8 +241,8 @@ def _attrib_key(name: str,
         return (name, "-") if name == "http.handle" else None
     if name.startswith("fit."):
         parts = name.split(".")
-        if len(parts) == 3:                 # fit.<family>.<sub-phase>
-            return (f"fit.{parts[2]}", parts[1])
+        if len(parts) in (3, 4):            # fit.<family>.<sub>[.<part>]
+            return ("fit." + ".".join(parts[2:]), parts[1])
         if len(parts) == 2:                 # fit.<family>
             return ("fit", parts[1])
     return None
@@ -285,6 +301,27 @@ def attribution_snapshot() -> Dict[str, Dict[str, Any]]:
     return out
 
 
+#: ``jax.profiler.TraceAnnotation``, resolved at the first sampled span
+#: of a process that has imported JAX (False: resolved, unavailable).
+_annotation_cls: Any = None
+
+
+def _annotation(name: str):
+    """An unentered profiler annotation for one span, or None. Never
+    imports JAX itself: a process without it (the front-end workers)
+    has no profiler session a span could land in."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name) if _annotation_cls else None
+
+
 def _record(span_obj: Span, ingested: bool = False) -> None:
     with _lock:
         cap = _capacity()
@@ -317,6 +354,9 @@ def trace(name: str, trace_id: Optional[str] = None,
         if not sampled:
             _counters["traces_unsampled"] += 1
     token = _ctx.set(ctx)
+    note = _annotation(name) if sampled else None
+    if note is not None:
+        note.__enter__()
     t0 = time.monotonic()
     t_wall = time.time()
     status, err = "ok", None
@@ -326,10 +366,13 @@ def trace(name: str, trace_id: Optional[str] = None,
         status, err = "error", f"{type(exc).__name__}: {exc}"
         raise
     finally:
+        dur = time.monotonic() - t0
+        if note is not None:
+            note.__exit__(None, None, None)
         _ctx.reset(token)
         if sampled:
             _record(Span(ctx.trace_id, ctx.span_id, None, name, t_wall,
-                         time.monotonic() - t0, attrs, status, err))
+                         dur, attrs, status, err))
 
 
 @contextmanager
@@ -338,7 +381,8 @@ def span(name: str, attrs: Optional[Dict[str, Any]] = None,
     """Open a child span under the ambient trace. No ambient trace (or
     an unsampled one) ⇒ near-zero-cost no-op — instrumented code needs
     no guards. ``attrs``/keyword attrs merge; the dict is recorded by
-    reference so the block may keep filling it in."""
+    reference so the block may keep filling it in. The span lies on a
+    running device profile's host plane under its name."""
     parent = _ctx.get()
     if parent is None or not parent.sampled:
         yield parent
@@ -347,6 +391,9 @@ def span(name: str, attrs: Optional[Dict[str, Any]] = None,
         attrs = {**(attrs or {}), **kw}
     ctx = TraceContext(parent.trace_id, new_id(), True)
     token = _ctx.set(ctx)
+    note = _annotation(name)
+    if note is not None:
+        note.__enter__()
     t0 = time.monotonic()
     t_wall = time.time()
     status, err = "ok", None
@@ -356,9 +403,13 @@ def span(name: str, attrs: Optional[Dict[str, Any]] = None,
         status, err = "error", f"{type(exc).__name__}: {exc}"
         raise
     finally:
+        dur = time.monotonic() - t0
+        if note is not None:
+            note.__exit__(None, None, None)
         _ctx.reset(token)
         _record(Span(ctx.trace_id, ctx.span_id, parent.span_id, name,
-                     t_wall, time.monotonic() - t0, attrs, status, err))
+                     t_wall, dur if ctx.duration_s is None
+                     else ctx.duration_s, attrs, status, err))
 
 
 @contextmanager
@@ -403,10 +454,11 @@ def record_span(name: str, duration_s: float, *,
                 status: str = "ok",
                 error: Optional[str] = None) -> Optional[str]:
     """Record a span with an EXACT externally measured duration — how
-    instrumentation points that already time themselves (``device_span``,
-    the batcher's queue-wait bookkeeping) emit spans that agree with
-    their metrics to the digit. Returns the span id, or None when the
-    (explicit or ambient) context is absent/unsampled.
+    instrumentation points that cannot wrap their work (the batcher's
+    queue-wait bookkeeping, a generator's scan) emit spans that agree
+    with their metrics to the digit. Such a span is not on a device
+    profile: it has ended before it is known. Returns the span id, or
+    None when the (explicit or ambient) context is absent/unsampled.
 
     ``parent_id=""`` records a ROOT span (parent None) — how the
     front-end worker's event loop emits its ``http.handle`` root after

@@ -140,6 +140,53 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: The name each case's kernel carries on a device profile's ``XLA Ops``
+#: line (``tree_leaf_stats`` shares the histogram's ``pallas_call``).
+KERNEL_NAMES = {
+    "tree_histogram-32bins": "tree_hist",
+    "tree_histogram-vmap5": "tree_hist",
+    "tree_leaf_stats": "tree_hist",
+    "tree_route_level": "tree_route",
+    "tree_descend": "tree_descend",
+    "tree_descend-vmap20": "tree_descend",
+    "tsne_repulsion": "tsne_repulsion",
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_NAMES))
+def test_kernel_is_named_in_the_compiled_module(case, one_chip,
+                                                chip_compiler):
+    """The profiler names an ``XLA Ops`` event by the instruction's text
+    up to its frontend attributes (no ``metadata=``, no backend
+    config), so the kernel's name has to be the custom call's own
+    instruction name, vmapped or not: what ``hist_kernel_s.sweep`` and
+    ``route_kernel_s.sweep`` match, in one expression with the call
+    target so a fusion that inherits the scope is never counted."""
+    import re
+
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    # vmap wraps the scope: ``%vmap_tree_hist_.1``.
+    named = re.compile(r"%[^ ]*" + KERNEL_NAMES[case] + r"[^ ]* = .*"
+                       r'custom_call_target="tpu_custom_call"')
+    assert calls and all(named.search(ln) for ln in calls), \
+        [ln[:80] for ln in calls]
+    metric = {"tree_hist": "hist_kernel_s.sweep",
+              "tree_route": "route_kernel_s.sweep"}.get(KERNEL_NAMES[case])
+    if metric:           # the benchmark's own expression finds them too
+        import json
+        import os
+
+        with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                               "perfbench", "layer_metrics",
+                               metric + ".json")) as fh:
+            (pattern,) = json.load(fh)["ops"]
+        assert all(re.search(pattern, ln) for ln in calls)
+
+
 @pytest.mark.parametrize("family", ["forest", "gbt"])
 def test_tree_predict_compiles_on_four_chips(family, topo, chip_compiler):
     """The batch predict of a row-sharded design on the 2x2 host: XLA

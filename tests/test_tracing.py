@@ -342,11 +342,14 @@ def test_sweep_job_trace_reconciles_with_profile(served):
     # The async job joins the submitting POST's trace.
     assert by_name["http.handle"][0]["attrs"]["route"] == "/models"
     (job_span,) = by_name["job.model_builder"]
+    # One ``build`` span under the job holds the whole of the build.
+    (build,) = by_name["build"]
+    assert build["parent_id"] == job_span["span_id"]
     (design,) = by_name["design.build"]
-    assert design["parent_id"] == job_span["span_id"]
+    assert design["parent_id"] == build["span_id"]
     for fam in ("lr", "nb"):
         (fit,) = by_name[f"fit.{fam}"]
-        assert fit["parent_id"] == job_span["span_id"]
+        assert fit["parent_id"] == build["span_id"]
         for phase in ("host_prep", "device", "finish"):
             (ps,) = by_name[f"fit.{fam}.{phase}"]
             assert ps["parent_id"] == fit["span_id"], (fam, phase)
@@ -373,6 +376,161 @@ def test_failed_family_fit_span_records_error(served):
     spans = {s["name"]: s for s in tracing.spans_for(ctx.trace_id)}
     assert spans["fit.lr"]["status"] == "error"
     assert "bogus_knob" in spans["fit.lr"]["error"]
+
+
+SWEEP = ["dt", "rf", "gb", "nb"]
+PHASES = ("host_prep", "gate_wait", "dispatch", "device", "finish")
+FINISH_PARTS = ("score", "model", "rows", "store")
+
+
+def _sync_sweep(ctx, prefix):
+    resp = requests.post(ctx.url("/models"), json={
+        "training_filename": "tr_train", "test_filename": "tr_test",
+        "prediction_filename": prefix, "classificators_list": SWEEP,
+        "label": "label"})
+    assert resp.status_code == 201, resp.text
+    return resp
+
+
+def test_sync_sweep_span_tree(served):
+    """The span tree of a four-family sync ``POST /models`` (ISSUE 27):
+    one ``build`` under the request, every family's five phases under
+    its ``fit.<c>`` and covering it, the finish sub-phases inside their
+    ``finish``, the report's ``device_s`` the device span's duration,
+    and the sub-phases in the attribution table."""
+    ctx, _app = served
+    resp = _sync_sweep(ctx, "tr_tree")
+    device_s = {r["classifier"]: r["device_s"] for r in resp.json()["result"]}
+    spans = requests.get(ctx.url(
+        f"/trace/{resp.headers['X-Request-Id']}")).json()["spans"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+
+    def end(s):
+        return s["start"] + s["duration_ms"] / 1e3
+
+    (root,) = by_name["http.handle"]
+    (build,) = by_name["build"]
+    assert build["parent_id"] == root["span_id"]
+    assert build["attrs"] == {"train": "tr_train", "test": "tr_test",
+                              "classifiers": SWEEP, "rows": 400}
+    assert by_name["design.build"][0]["parent_id"] == build["span_id"]
+    for c in SWEEP:
+        (fit,) = by_name[f"fit.{c}"]
+        assert fit["parent_id"] == build["span_id"]
+        covered = 0.0
+        for phase in PHASES:
+            (ps,) = by_name[f"fit.{c}.{phase}"]
+            assert ps["parent_id"] == fit["span_id"], (c, phase)
+            assert ps["status"] == "ok"
+            covered += ps["duration_ms"]
+        # Nothing of a family's time is outside a phase (the gate wait
+        # and the dispatch used to be fit.<c> minus its children).
+        assert covered == pytest.approx(fit["duration_ms"], rel=0.05), c
+        (finish,) = by_name[f"fit.{c}.finish"]
+        for part in FINISH_PARTS:
+            (ps,) = by_name[f"fit.{c}.finish.{part}"]
+            assert ps["parent_id"] == finish["span_id"], (c, part)
+            assert finish["start"] - 1e-3 <= ps["start"]
+            assert end(ps) <= end(finish) + 1e-3
+        # journal.commit nests under the store phase.
+        (store,) = by_name[f"fit.{c}.finish.store"]
+        assert any(s["parent_id"] == store["span_id"]
+                   for s in by_name["journal.commit"])
+        # One measurement: the report's device_s IS the span's duration.
+        (dev,) = by_name[f"fit.{c}.device"]
+        assert dev["duration_ms"] / 1e3 == pytest.approx(device_s[c],
+                                                         abs=1.5e-6)
+        # What each step compiled (None where the windows overlapped).
+        for phase in ("dispatch", "device"):
+            attrs = by_name[f"fit.{c}.{phase}"][0]["attrs"]
+            assert set(attrs) == {"compiles", "compile_s"}
+            assert attrs["compiles"] is None or attrs["compiles"] >= 0
+
+    table = requests.get(ctx.url("/metrics")).json()["latency_attribution"]
+    for phase in PHASES + tuple(f"finish.{p}" for p in FINISH_PARTS):
+        assert set(table[f"fit.{phase}"]) >= set(SWEEP), phase
+    # Ten phases a family: the 512-entry cap is nowhere near.
+    assert sum(len(v) for k, v in table.items()
+               if k.startswith("fit")) == 10 * len(SWEEP)
+    assert "attribution_dropped" not in tracing.counters_snapshot()
+
+
+def test_unsampled_sweep_records_no_span(served):
+    ctx, _app = served
+    tracing.set_sample(0.0)
+    resp = _sync_sweep(ctx, "tr_unsampled")
+    assert re.fullmatch(r"[0-9a-f]{16}", resp.headers["X-Request-Id"])
+    counters = tracing.counters_snapshot()
+    assert counters["spans_recorded"] == 0 and counters["buffer_spans"] == 0
+    assert counters["traces_unsampled"] >= 1
+    assert tracing.attribution_snapshot() == {}
+
+
+def test_span_raised_through_keeps_error_and_pinned_duration():
+    with tracing.trace("root") as root:
+        with pytest.raises(KeyError):
+            with tracing.span("fit.gb.finish.rows") as sp:
+                raise KeyError("gone")
+        assert tracing.current() is root       # the context was restored
+        with tracing.span("fit.gb.device") as sp:
+            sp.duration_s = 1.25               # what device_span measured
+    spans = {s["name"]: s for s in tracing.spans_for(root.trace_id)}
+    assert spans["fit.gb.finish.rows"]["status"] == "error"
+    assert "gone" in spans["fit.gb.finish.rows"]["error"]
+    assert spans["fit.gb.device"]["duration_ms"] == 1250.0
+    assert spans["root"]["status"] == "ok"
+
+
+@pytest.mark.parametrize("name,key", [
+    ("fit.gb", ("fit", "gb")),
+    ("fit.gb.finish", ("fit.finish", "gb")),
+    ("fit.gb.finish.rows", ("fit.finish.rows", "gb")),
+    ("fit.nb.gate_wait", ("fit.gate_wait", "nb")),
+    ("fit.gb.finish.rows.more", None),
+    ("build", None),
+])
+def test_attribution_key_folds_fit_sub_phases_by_family(name, key):
+    assert tracing._attrib_key(name, None) == key
+
+
+def test_spans_lie_on_a_running_profile_under_their_names(tmp_path):
+    """A span opened as a context is also a profiler annotation: a
+    capture taken meanwhile (``POST /debug/profile``) holds it on the
+    host plane; ``record_span`` durations, known only afterwards, are
+    not there."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracing.trace("http.handle"):
+            with tracing.span("build"), tracing.span("fit.gb.finish.rows"):
+                pass
+            tracing.record_span("queue.wait", 0.001)
+        with tracing.trace("unsampled.root", sampled=False):
+            with tracing.span("unsampled.child"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = {ev.name: (ev.start_ns, ev.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events}
+    assert {"http.handle", "build", "fit.gb.finish.rows"} <= set(events)
+    assert not {"queue.wait", "unsampled.root", "unsampled.child"} \
+        & set(events)
+    outer, inner = events["build"], events["fit.gb.finish.rows"]
+    assert outer[0] <= inner[0] and \
+        inner[0] + inner[1] <= outer[0] + outer[1]
 
 
 #: Exposition-format line shapes (version 0.0.4): comments, and samples
