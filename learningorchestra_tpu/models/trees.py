@@ -606,10 +606,14 @@ def _fit_forest(B, y, valid, key, *, num_classes, max_depth, n_bins,
             min_child_weight=min_child_weight, use_kernel=use_kernel)
         # Trees build in vmapped batches: a batch's (NL·S, blk) histogram
         # operands stack into one (tb·NL·S, blk) @ (blk, d·n_bins) MXU
-        # contraction per row block — ~2× over tree-at-a-time lax.map on
-        # rf fits — while the outer sequential map bounds live per-tree
-        # row state (stats/weights/assign are O(tb·n), not O(n_trees·n),
-        # so n_trees=100 still fits HBM).
+        # contraction per row block. On the oracle path that is what
+        # vmap makes of the contraction; on the kernel path the
+        # histogram call's own batching rule (ops/pallas_kernels.py
+        # `_hist_call`) does it, because the batch shares its bin matrix:
+        # one one-hot per row tile for the tb trees, 4.3× over a pass
+        # per tree at 11M rows (PERF.md, PR 28). The outer sequential
+        # map bounds live per-tree row state (stats/weights/assign are
+        # O(tb·n), not O(n_trees·n), so n_trees=100 still fits HBM).
         tb, nb = _forest_batch_shape(n_trees)
         keys = jax.random.split(key, nb * tb)
         outs = jax.lax.map(jax.vmap(one_tree),
@@ -663,8 +667,10 @@ def _fit_forest_batch(B, y, valid, keys_b, *, num_classes, max_depth,
 # row weights carry validity × k-fold membership (and drop to zero when
 # successive halving kills the member), so folds are index masks over
 # the ONE resident design — never data copies. The Pallas kernel path
-# stays off here: the kernels are shaped per single tree, and the
-# oracle contraction is the documented bit-parity reference.
+# stays off here (the oracle contraction is the documented bit-parity
+# reference); were it switched on, the histogram call's batching rule
+# would stack a member's trees and put the members, each with a bin
+# matrix of its own, on a grid axis.
 # ---------------------------------------------------------------------------
 
 @jax.jit

@@ -22,16 +22,25 @@ ops XLA alone schedules sub-optimally. Residents:
   lookups (the compare-sum gather emulations) and child-assignment
   update into one VPU pass per row tile. The XLA contraction path is
   kept as the bit-parity oracle (docs/performance.md).
+  A pass of the histogram kernel costs what its one-hot costs — built
+  on the VPU and loaded into the MXU per row tile — however many stats
+  rows then stream past it, so a batch of trees that shares its bin
+  matrix (rf's `vmap(one_tree)`) is ONE pass: the call's batching rule
+  (`_hist_call`, a `jax.custom_batching.custom_vmap`) reads which
+  operands carry the batch axis and stacks the trees on the matmul's
+  rows (`tree_hist_stacked`, M = T·NG·S) where the codes are shared, and
+  leaves the batch a grid axis of the one-tree kernel where every
+  member has codes of its own (the leaf statistics, a population).
 
 On non-TPU backends every `pallas_call` runs in interpreter mode, so the
 same code path is unit-tested on the CPU mesh (tests/conftest.py) and
 cross-checked against the pure-XLA reference implementation.
 
 Each `pallas_call` carries a `name=` (`tsne_repulsion`, `tree_hist`,
-`tree_route`, `tree_descend`): it becomes the innermost name scope, XLA
-names the custom-call instruction after it (`%tree_hist.3 = ...
-custom_call_target="tpu_custom_call"`), and that instruction text is the
-event's name on a device profile's `XLA Ops` line — which is all the
+`tree_hist_stacked`, `tree_route`, `tree_descend`): it becomes the
+innermost name scope, XLA names the custom-call instruction after it
+(`%tree_hist.3 = ... custom_call_target="tpu_custom_call"`), and that
+instruction text is the event's name on a device profile's `XLA Ops` line — which is all the
 benchmark's per-kernel metrics read (perfbench/layer_metrics).
 """
 
@@ -220,18 +229,21 @@ def _pad_lanes(arr: jax.Array, n_pad: int, value=0) -> jax.Array:
 
 def _row_vec(v: jax.Array, n_pad: int, value=0) -> jax.Array:
     """A per-row (n,) vector as the (1, n_pad) int32 row the kernels
-    stream: rows sit in lanes, so the array is dense in HBM — an (n, 1)
-    column is lane-padded 128× (measured from the v5e compile's
-    memory_analysis: 11 GB of temporaries for a 1.1M-row rf batch)."""
-    return _pad_lanes(v.astype(jnp.int32).reshape(1, -1), n_pad, value)
+    stream — a batch's (T, n) as (T, n_pad): rows sit in lanes, so the
+    array is dense in HBM — an (n, 1) column is lane-padded 128×
+    (measured from the v5e compile's memory_analysis: 11 GB of
+    temporaries for a 1.1M-row rf batch)."""
+    return _pad_lanes(v.astype(jnp.int32).reshape(-1, v.shape[-1]), n_pad,
+                      value)
 
 
 def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
-                      operand_dtype):
-    """One (node-group g, row-tile t) cell of the histogram grid.
+                      n_trees, operand_dtype):
+    """One (node-group g, row-tile t) cell of the histogram grid, for the
+    ``n_trees`` trees that share the tile's bin codes.
 
     Scatter-adds the row tile's sufficient statistics into the
-    VMEM-resident (NG·S, d·n_bins) accumulator block: the node-masked
+    VMEM-resident (T·NG·S, d·n_bins) accumulator block: the node-masked
     stats operand and the bin one-hot are built in VMEM and consumed by
     the MXU — never written to HBM. The accumulator block is indexed by
     g only, so it stays resident while the row tiles stream past (t is
@@ -239,29 +251,43 @@ def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
 
     Every value is 2-D with the tile's rows in lanes, as the operands
     arrive — Mosaic refuses the rank-3 broadcasts/reshapes the XLA
-    oracle's formulation uses. The stats operand's row ``node·S + s`` is
-    selected from the (1, tile) node-id row; the one-hot is built
-    transposed, one 128-column group of the histogram at a time:
-    ``col`` is each (feature, row)'s global histogram column
-    ``f·n_bins + code``, so a group's one-hot is the OR, over the few
-    features whose columns fall in it, of a sublane-broadcast compare.
-    Operands mirror the XLA oracle's dtype (bf16 on TPU, f32 elsewhere);
-    {0,1} one-hot products are exact and every dot accumulates in f32.
+    oracle's formulation uses. A tree's stats operand's row
+    ``node·S + s`` is selected from its (1, tile) node-id row, and the
+    trees' (NG·S, tile) operands stack along the matmul's rows; the
+    one-hot is built transposed, one 128-column group of the histogram
+    at a time, ONCE for all the trees: ``col`` is each (feature, row)'s
+    global histogram column ``f·n_bins + code``, so a group's one-hot is
+    the OR, over the few features whose columns fall in it, of a
+    sublane-broadcast compare. The one-hot is the operand the MXU holds
+    (a 128×128 block of it per 128 rows of the tile) and what it costs
+    to build and to load does not depend on how many stats rows stream
+    past it: at T = 1 each loaded block meets NG·S rows, at T trees
+    T·NG·S. Operands mirror the XLA oracle's dtype (bf16 on TPU, f32
+    elsewhere); {0,1} one-hot products are exact and every dot
+    accumulates in f32 along the tile, per output row — so a tree's
+    rows read the same whether it rides alone or stacked.
     """
     g = pl.program_id(0)
     t = pl.program_id(1)
     d, tile = codes_ref.shape
-    S = stats_ref.shape[0]
-    NGS, Wp = out_ref.shape
+    S = stats_ref.shape[-2]
+    NGS = out_ref.shape[0] // n_trees
+    Wp = out_ref.shape[1]
 
     # Inactive/padded rows carry rel = -1 and rows of other node groups
     # fall outside [0, NGS): neither matches any accumulator row.
-    first = (rel_ref[:] - g * (NGS // S)) * S                 # (1, tile)
     row = jax.lax.broadcasted_iota(jnp.int32, (NGS, tile), 0)
-    At = jnp.zeros((NGS, tile), jnp.float32)
-    for s in range(S):
-        At = jnp.where(row == first + s, stats_ref[s:s + 1, :], At)
-    At = At.astype(operand_dtype)
+    parts = []
+    for k in range(n_trees):
+        first = (rel_ref[k:k + 1, :] - g * (NGS // S)) * S    # (1, tile)
+        At = jnp.zeros((NGS, tile), jnp.float32)
+        for s in range(S):
+            stat = (stats_ref[s:s + 1, :] if len(stats_ref.shape) == 2
+                    else stats_ref[k, s:s + 1, :])
+            At = jnp.where(row == first + s, stat, At)
+        parts.append(At)
+    At = parts[0] if n_trees == 1 else jnp.concatenate(parts, axis=0)
+    At = At.astype(operand_dtype)                        # (T·NGS, tile)
 
     codes = codes_ref[:].astype(jnp.int32)                    # (d, tile)
     col = jnp.where(
@@ -287,37 +313,97 @@ def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
             preferred_element_type=jnp.float32)
 
 
-def _hist_call(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
-               operand_dtype):
-    """Shared pallas_call for tree_histogram / tree_leaf_stats. Returns
-    the flat (n_nodes·S, d·n_bins) f32 histogram."""
+def _hist_pallas(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
+                 operand_dtype):
+    """The histogram ``pallas_call``. One tree: stats_T (S, n), rel and
+    active (n,), returns (n_nodes·S, d·n_bins) f32 — the call a single
+    tree has always made (``tree_hist``). T trees that share the bin
+    matrix codes_T (d, n): stats_T (T, S, n), rel and active (T, n),
+    returns (T, n_nodes·S, d·n_bins) — the trees ride the same one-hot
+    as further matmul rows (``tree_hist_stacked``). Each takes its
+    operands in the shape the caller holds them: a reshape of an
+    11M-row array is a copy in HBM (a lone tree's (1, S, n)) or minutes
+    of compile (a batch flattened to (T·S, n))."""
     d, n = codes_T.shape
-    S = stats_T.shape[0]
+    stacked = stats_T.ndim == 3
+    T = stats_T.shape[0] if stacked else 1
+    S = stats_T.shape[-2]
     n_pad = -(-n // tile) * tile
     # Padded rows carry zero stats (callers pad stats with zeros) and an
     # inactive node id, so their contribution is an exact 0.
     rel = _row_vec(jnp.where(active, rel, -1), n_pad, -1)
-    NG = _tree_node_groups(n_nodes, S, d, n_bins)
+    NG = _tree_node_groups(n_nodes, T * S, d, n_bins)
     G = n_nodes // NG
     # The accumulator's lane width rounds up to whole 128-lane groups so
     # every in-kernel store is aligned; the group axis leads so a block
-    # always spans the array's full trailing dims, whatever NG·S is.
+    # always spans the array's full trailing dims, whatever T·NG·S is.
     Wp = -(-d * n_bins // _LANES) * _LANES
     out = pl.pallas_call(
-        partial(_tree_hist_kernel, n_bins=n_bins,
+        partial(_tree_hist_kernel, n_bins=n_bins, n_trees=T,
                 operand_dtype=operand_dtype),
         grid=(G, n_pad // tile),
         in_specs=[
             pl.BlockSpec((d, tile), lambda g, t: (0, t)),
-            pl.BlockSpec((S, tile), lambda g, t: (0, t)),
-            pl.BlockSpec((1, tile), lambda g, t: (0, t)),
+            (pl.BlockSpec((T, S, tile), lambda g, t: (0, 0, t)) if stacked
+             else pl.BlockSpec((S, tile), lambda g, t: (0, t))),
+            pl.BlockSpec((T, tile), lambda g, t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((None, NG * S, Wp), lambda g, t: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, NG * S, Wp), jnp.float32),
+        out_specs=pl.BlockSpec((None, T * NG * S, Wp),
+                               lambda g, t: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, T * NG * S, Wp), jnp.float32),
         interpret=_interpret(),
-        name="tree_hist",
+        name="tree_hist_stacked" if stacked else "tree_hist",
     )(_pad_lanes(codes_T, n_pad), _pad_lanes(stats_T, n_pad), rel)
-    return out.reshape(n_nodes * S, Wp)[:, :d * n_bins]
+    if not stacked:
+        return out.reshape(n_nodes * S, Wp)[:, :d * n_bins]
+    # Block g holds tree k's nodes [g·NG, (g+1)·NG) at rows k·NG·S…
+    out = out.reshape(G, T, NG * S, Wp).transpose(1, 0, 2, 3)
+    return out.reshape(T, n_nodes * S, Wp)[:, :, :d * n_bins]
+
+
+def _trees_per_call(n_nodes: int, n_stats: int, d: int, n_bins: int) -> int:
+    """How many trees of a batch one stacked call takes: as many as the
+    accumulator budget holds at the node group ONE tree gets — a wider
+    stack at a narrower node group would re-stream the rows once per
+    further group and could cost more passes than tree-at-a-time."""
+    ng = _tree_node_groups(n_nodes, n_stats, d, n_bins)
+    return max(1, _TREE_ACC_BYTES // (ng * n_stats * d * n_bins * 4))
+
+
+def _hist_call(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
+               operand_dtype):
+    """Shared call for tree_histogram / tree_leaf_stats: one tree's flat
+    (n_nodes·S, d·n_bins) f32 histogram, with the batching rule that
+    decides — from which operands carry the batch axis — what a ``vmap``
+    over it (rf's tree batch) compiles to."""
+    plain = partial(_hist_pallas, n_nodes=n_nodes, n_bins=n_bins, tile=tile,
+                    operand_dtype=operand_dtype)
+    call = jax.custom_batching.custom_vmap(plain)
+
+    @call.def_vmap
+    def _rule(axis_size, in_batched, codes_T, stats_T, rel, active):
+        d, S = codes_T.shape[-2], stats_T.shape[-2]
+        step = 1 if in_batched[0] else min(
+            axis_size, _trees_per_call(n_nodes, S, d, n_bins))
+        if step == 1:
+            # Nothing to share — every member has bin codes of its own
+            # (the leaf statistics, whose "codes" are the tree's
+            # assignment; a population's members) — or no room for a
+            # second tree's accumulator: the batch is a grid axis.
+            axes = [0 if b else None for b in in_batched]
+            return jax.vmap(plain, in_axes=axes)(
+                codes_T, stats_T, rel, active), True
+        # One bin matrix under the whole batch: its one-hot is built once
+        # a row tile and the members' stats rows stack on the matmul.
+        stats_T, rel, active = (
+            a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((stats_T, rel, active), in_batched[1:]))
+        outs = [plain(codes_T, stats_T[lo:lo + step], rel[lo:lo + step],
+                      active[lo:lo + step])
+                for lo in range(0, axis_size, step)]
+        return (outs[0] if len(outs) == 1 else jnp.concatenate(outs)), True
+
+    return call(codes_T, stats_T, rel, active)
 
 
 def tree_histogram(codes_T, stats_T, rel, active, *, n_nodes, n_bins,

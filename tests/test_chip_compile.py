@@ -6,8 +6,9 @@ the TPU compiler (rank-3 reshapes in VMEM). The TPU compiler is
 installed here and compiles for a *described* v5e that is not attached,
 so each Pallas kernel of the main path is compiled once at its published
 width: HIGGS's 28 features, the Spark-parity depth-5 / 32-bin defaults,
-rf's vmapped batch of 5 stat sets, the 256-bin uint8 extreme at its own
-tile, and the t-SNE repulsion at the 8,192-row plot size.
+rf's vmapped batch of 5 stat sets (stacked on the histogram kernel's
+matmul rows; its leaf statistics a grid axis), the 256-bin uint8 extreme
+at its own tile, and the t-SNE repulsion at the 8,192-row plot size.
 
 Nothing runs — a pass here says nothing about results or times, and is
 never reported as a chip run. This is the one file that describes a
@@ -72,20 +73,25 @@ def _hist(n_bins):
              ((N,), jnp.int32), ((N,), jnp.bool_)])
 
 
-def _hist_vmapped():
-    fn, (codes, stats, rel, act) = _hist(N_BINS)
+def _hist_vmapped(n_bins=N_BINS):
+    fn, (codes, stats, rel, act) = _hist(n_bins)
     # rf's batched build (trees._forest_batch_shape(20) → batches of 5):
-    # stats and node ids carry the tree axis, the bin matrix is shared.
+    # stats and node ids carry the tree axis, the bin matrix is shared —
+    # so the call's batching rule stacks the five trees on the kernel's
+    # matmul rows (at 256 bins two a call: the accumulator budget).
     return (jax.vmap(fn, in_axes=(None, 0, 0, 0)),
             [codes, ((5,) + stats[0], stats[1]), ((5,) + rel[0], rel[1]),
              ((5,) + act[0], act[1])])
 
 
-def _leaf():
-    # One synthetic feature, non-lane-aligned bin count (63).
-    return (partial(pk.tree_leaf_stats, n_nodes=M,
-                    tile=pk.tree_tile(D, N_BINS), operand_dtype=HDT),
-            [((N,), jnp.int32), ((S, N), jnp.float32)])
+def _leaf(trees=None):
+    # One synthetic feature, non-lane-aligned bin count (63). Under rf's
+    # vmap the "codes" are each tree's own assignment: the grid form.
+    lead = () if trees is None else (trees,)
+    fn = partial(pk.tree_leaf_stats, n_nodes=M,
+                 tile=pk.tree_tile(D, N_BINS), operand_dtype=HDT)
+    return (fn if trees is None else jax.vmap(fn),
+            [(lead + (N,), jnp.int32), (lead + (S, N), jnp.float32)])
 
 
 def _route():
@@ -122,7 +128,9 @@ CASES = {
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
     "tree_histogram-vmap5": _hist_vmapped,
+    "tree_histogram-vmap5-256bins": lambda: _hist_vmapped(256),
     "tree_leaf_stats": _leaf,
+    "tree_leaf_stats-vmap5": lambda: _leaf(5),
     "tree_route_level": _route,
     "tree_descend": _descend,
     "tree_descend-vmap20": lambda: _descend(20),
@@ -144,8 +152,10 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
 #: line (``tree_leaf_stats`` shares the histogram's ``pallas_call``).
 KERNEL_NAMES = {
     "tree_histogram-32bins": "tree_hist",
-    "tree_histogram-vmap5": "tree_hist",
+    "tree_histogram-vmap5": "tree_hist_stacked",
+    "tree_histogram-vmap5-256bins": "tree_hist",
     "tree_leaf_stats": "tree_hist",
+    "tree_leaf_stats-vmap5": "tree_hist",
     "tree_route_level": "tree_route",
     "tree_descend": "tree_descend",
     "tree_descend-vmap20": "tree_descend",
@@ -175,6 +185,7 @@ def test_kernel_is_named_in_the_compiled_module(case, one_chip,
     assert calls and all(named.search(ln) for ln in calls), \
         [ln[:80] for ln in calls]
     metric = {"tree_hist": "hist_kernel_s.sweep",
+              "tree_hist_stacked": "hist_kernel_s.sweep",
               "tree_route": "route_kernel_s.sweep"}.get(KERNEL_NAMES[case])
     if metric:           # the benchmark's own expression finds them too
         import json

@@ -20,6 +20,8 @@ Parity guarantee pinned here (docs/performance.md):
   equality is statistical (accuracy parity), not bitwise.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -184,6 +186,203 @@ def test_per_level_psum_parity_multi_shard():
     # active row lands in exactly one bin per feature; stats are
     # integers so the f32 total is exact).
     assert hk.sum() == d * float((stats.sum(0) * act).sum())
+
+
+def _hist_operands(T, d, n_bins, n, NL, S, seed=0):
+    """A tree batch's histogram operands: one bin matrix, per-tree
+    integer stats (f32 sums exact under any grouping), node ids, and an
+    active mask that leaves some rows out."""
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(0, n_bins, (d, n)).astype(np.uint8)),
+            jnp.asarray(rng.integers(0, 4, (T, S, n)).astype(np.float32)),
+            jnp.asarray(rng.integers(0, NL, (T, n)).astype(np.int32)),
+            jnp.asarray(rng.random((T, n)) < 0.8))
+
+
+def _pallas_calls(jaxpr):
+    """(name, grid, result shape) of every pallas_call in a jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield (eqn.params["name"], eqn.params["grid_mapping"].grid,
+                   eqn.outvars[0].aval.shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("T", [2, 5, 8])
+@pytest.mark.parametrize("d,n_bins", [(3, 2), (28, 32), (28, 256)])
+def test_stacked_histogram_equals_per_tree(T, d, n_bins):
+    """The batching rule's stacked call (bin matrix shared: the trees
+    are matmul rows under one one-hot) gives every tree the histogram
+    the plain call gives it alone, and the grid form (every member its
+    own codes) does too — n not a multiple of the tile, some rows
+    inactive."""
+    NL, S = 4, 2
+    tile = pk.tree_tile(d, n_bins)
+    n = 2 * tile + 77
+    codes, stats, rel, act = _hist_operands(T, d, n_bins, n, NL, S)
+    hist = jax.jit(partial(pk.tree_histogram, n_nodes=NL, n_bins=n_bins,
+                           tile=tile))
+    trees_share = jax.jit(jax.vmap(hist, in_axes=(None, 0, 0, 0)))
+
+    def alone(st):
+        return np.stack([np.asarray(hist(codes, st[k], rel[k], act[k]))
+                         for k in range(T)])
+
+    want = alone(stats)
+    np.testing.assert_array_equal(
+        np.asarray(trees_share(codes, stats, rel, act)), want)
+    grid = jax.jit(jax.vmap(hist))(
+        jnp.broadcast_to(codes, (T,) + codes.shape), stats, rel, act)
+    np.testing.assert_array_equal(np.asarray(grid), want)
+    # Real-valued stats (gb's): same operands, same contraction order
+    # along the tile, so stacked rows read what the lone tree reads.
+    fstats = stats * 0.37 + 0.011
+    np.testing.assert_allclose(
+        np.asarray(trees_share(codes, fstats, rel, act)), alone(fstats),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_batching_rule_branches():
+    """Which kernel a ``vmap`` over the histogram call compiles to is
+    read off the operands' own batch flags: codes shared → ONE call
+    whose matmul rows are M = T·NG·S; codes batched → the plain kernel
+    under a grid axis; member × tree nesting → stacked inside, grid
+    outside; no vmap → the plain call, untouched."""
+    T, Bm, d, n_bins, NL, S, n = 5, 3, 28, 32, 4, 2, 300
+    tile = pk.tree_tile(d, n_bins)
+    tiles, Wp = -(-n // tile), d * n_bins
+    codes, stats, rel, act = _hist_operands(T, d, n_bins, n, NL, S)
+    hist = partial(pk.tree_histogram, n_nodes=NL, n_bins=n_bins, tile=tile)
+    trees_share = jax.vmap(hist, in_axes=(None, 0, 0, 0))
+
+    def calls(fn, *args):
+        return list(_pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    assert calls(hist, codes, stats[0], rel[0], act[0]) == [
+        ("tree_hist", (1, tiles), (1, NL * S, Wp))]
+    assert calls(trees_share, codes, stats, rel, act) == [
+        ("tree_hist_stacked", (1, tiles), (1, T * NL * S, Wp))]
+    # stats alone batched (node ids shared): still one stacked call.
+    assert calls(jax.vmap(hist, in_axes=(None, 0, None, None)),
+                 codes, stats, rel[0], act[0]) == [
+        ("tree_hist_stacked", (1, tiles), (1, T * NL * S, Wp))]
+    assert calls(jax.vmap(hist), jnp.broadcast_to(codes, (T, d, n)),
+                 stats, rel, act) == [
+        ("tree_hist", (T, 1, tiles), (T, 1, NL * S, Wp))]
+    # The leaf statistics' "codes" are the tree's own assignment.
+    leaf = partial(pk.tree_leaf_stats, n_nodes=7, tile=tile)
+    assert calls(jax.vmap(leaf), rel, stats) == [
+        ("tree_hist", (T, 1, tiles), (T, 1, S, 128))]
+    # A population: members bring their own bin matrix, their trees
+    # share it.
+    pop = [jnp.broadcast_to(a, (Bm,) + a.shape)
+           for a in (codes, stats, rel, act)]
+    assert calls(jax.vmap(trees_share), *pop) == [
+        ("tree_hist_stacked", (Bm, 1, tiles), (Bm, 1, T * NL * S, Wp))]
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(trees_share)(*pop)[1]),
+        np.asarray(trees_share(codes, stats, rel, act)))
+
+
+def test_stack_width_follows_the_accumulator_budget(monkeypatch):
+    """No room for every tree's accumulator at the node group one tree
+    gets → as many trees a call as do fit (5 → 2 + 2 + 1), and with room
+    for one only, the per-tree grid form — from the shapes alone."""
+    T, d, n_bins, NL, S, n = 5, 28, 32, 4, 2, 300
+    tile = pk.tree_tile(d, n_bins)
+    codes, stats, rel, act = _hist_operands(T, d, n_bins, n, NL, S)
+    hist = partial(pk.tree_histogram, n_nodes=NL, n_bins=n_bins, tile=tile)
+    want = np.asarray(jax.vmap(hist, in_axes=(None, 0, 0, 0))(
+        codes, stats, rel, act))
+    one_tree = NL * S * d * n_bins * 4
+    for budget, rows in ((2 * one_tree, [2 * NL * S, 2 * NL * S, NL * S]),
+                         (one_tree, [NL * S]),
+                         (one_tree // 2, [NL * S // 2])):
+        monkeypatch.setattr(pk, "_TREE_ACC_BYTES", budget)
+        fn = jax.vmap(hist, in_axes=(None, 0, 0, 0))
+        got = list(_pallas_calls(
+            jax.make_jaxpr(fn)(codes, stats, rel, act).jaxpr))
+        assert [c[2][-2] for c in got] == rows, (budget, got)
+        # below two trees' worth the batch is the plain kernel's grid axis
+        assert [c[0] for c in got] == (
+            ["tree_hist_stacked"] * 3 if len(rows) == 3 else ["tree_hist"])
+        assert all(np.prod(c[2][-2:]) * 4 <= budget for c in got)
+        np.testing.assert_array_equal(
+            np.asarray(fn(codes, stats, rel, act)), want)
+
+
+def _binned_shards(n, seed, n_bins=32):
+    """(runtime, bin codes, labels, validity) on the 8-device mesh, as
+    ``_fit_cls_trees`` hands them to the jitted fit programs."""
+    X, y = _blobs(n, d=6, seed=seed)
+    rt = _runtime(True)
+    X_dev, n_real = rt.shard_rows(X)
+    B = trees.bin_features(X_dev, rt.replicate(
+        trees.quantile_edges(X, n_bins)))
+    y_dev, _ = rt.shard_rows(y)
+    valid, _ = rt.shard_rows(
+        (np.arange(X_dev.shape[0]) < n_real).astype(np.float32))
+    return rt, B, y_dev, valid
+
+
+@pytest.mark.parametrize("d,n_bins", [(3, 2), (6, 32), (28, 256)])
+def test_rf_stacked_fit_matches_oracle(d, n_bins):
+    """An rf fit on the kernel path — its batch of trees now stacked on
+    the histogram kernel's matmul rows — grows the oracle's trees, at
+    the sweep's shapes and a ragged row count."""
+    mk, mo, _, _ = _fit_pair("rf", 300, d=d, n_bins=n_bins, n_trees=4,
+                             max_depth=3)
+    _assert_params_bitexact("rf", mk, mo)
+
+
+def test_forest_batch_program_stacks_and_matches_fit_forest():
+    """The checkpoint-segmented per-batch program takes the stacked
+    kernel as ``_fit_forest`` does, and its trees are ``_fit_forest``'s
+    batch for batch."""
+    rt, B, y_dev, valid = _binned_shards(900, seed=3)
+    kw = dict(num_classes=2, max_depth=3, n_bins=32, n_trees=10,
+              mesh=rt.mesh, mtry=2, use_kernel=True)
+    key = jax.random.PRNGKey(5)
+    whole = trees._fit_forest(B, y_dev, valid, key, **kw)
+    tb, nb = trees._forest_batch_shape(10)
+    assert (tb, nb) == (5, 2)
+    keys = jax.random.split(key, nb * tb)
+    for b in range(nb):
+        part = trees._fit_forest_batch(B, y_dev, valid,
+                                       keys[b * tb:(b + 1) * tb], **kw)
+        for got, ref in zip(part, whole):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(ref)[b * tb:(b + 1) * tb])
+    for fn, k in ((trees._fit_forest, key), (trees._fit_forest_batch,
+                                             keys[:tb])):
+        names = [c[0] for c in _pallas_calls(
+            jax.make_jaxpr(partial(fn, **kw))(B, y_dev, valid, k).jaxpr)]
+        # one stacked histogram a level; the leaf pass on the grid form
+        assert names.count("tree_hist_stacked") == 1
+        assert names.count("tree_hist") == 1
+
+
+@pytest.mark.parametrize("kind", ["dt", "gb"])
+def test_single_tree_families_keep_their_histogram_call(kind):
+    """dt and gb offer one tree at a time: no batch axis reaches the
+    rule, and the histogram call in their programs is the plain one."""
+    rt, B, y_dev, valid = _binned_shards(400, seed=4)
+    if kind == "dt":
+        fn = partial(trees._fit_forest, num_classes=2, max_depth=3,
+                     n_bins=32, n_trees=1, mesh=rt.mesh, mtry=6,
+                     use_kernel=True)
+        args = (B, y_dev, valid, jax.random.PRNGKey(0))
+    else:
+        fn = partial(trees._fit_gbt, max_depth=3, n_bins=32, n_rounds=2,
+                     mesh=rt.mesh, use_kernel=True)
+        args = (B, y_dev, valid)
+    hists = [c for c in _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+             if "tree_hist" in c[0]]
+    tile = pk.tree_tile(6, 32)
+    assert hists and all(
+        c[0] == "tree_hist" and c[1] == (1, -(-50 // tile))
+        for c in hists), hists
 
 
 def test_tree_bench_smoke(monkeypatch):
