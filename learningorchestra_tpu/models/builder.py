@@ -39,7 +39,9 @@ span tree, each span opened around its work (so it also lies on a
 running device profile's timeline, utils/tracing.py): ``build`` >
 ``design.build``, ``fit.<c>`` > ``host_prep`` / ``gate_wait`` /
 ``dispatch`` / ``device`` / ``finish`` > ``score`` / ``model`` /
-``rows`` / ``store`` (docs/observability.md has the table). Output
+``rows`` / ``store``, and ``build.tail``: the finishing left when the
+last family's device phase has ended, which the chip has to wait for
+(docs/observability.md has the table). Output
 contract is preserved: dataset ``<name>_<classifier>`` per classifier,
 metrics in its metadata.
 """
@@ -432,8 +434,13 @@ class ModelBuilder:
 
         parent_ctx = tracing.current()
         job_rec = jobs.current_job_record()
+        # When the last family left its device phase (each writes the
+        # clock as it leaves; the last write stands): build.tail runs
+        # from there to the end of the round.
+        device_done: Optional[float] = None
 
         def fit_guarded(c: str) -> FitReport:
+            nonlocal device_done
             with tracing.attach(parent_ctx), \
                     jobs.attach_job_record(job_rec):
                 try:
@@ -464,6 +471,7 @@ class ModelBuilder:
                             probs, device_s = collect_fit(c, model, pre_s)
                         finally:
                             gate.release()
+                            device_done = time.monotonic()
                         # fit_time = prep + dispatch + device spans, no
                         # scheduler waits: the per-family sum estimates
                         # the serialized sweep, and the gap to build
@@ -476,7 +484,11 @@ class ModelBuilder:
         with ThreadPoolExecutor(
                 max_workers=max(len(classifiers), 1)) as pool:
             futures = {c: pool.submit(fit_guarded, c) for c in classifiers}
-            return [fut.result() for fut in futures.values()]
+            reports = [fut.result() for fut in futures.values()]
+        if device_done is not None:
+            tracing.record_span("build.tail",
+                                time.monotonic() - device_done)
+        return reports
 
     def _build_dispatched(self, train, test, prediction_name, classifiers,
                           label, steps, hparams, X_train, X_test, state,
@@ -555,6 +567,7 @@ class ModelBuilder:
                                   device_s)
                 except Exception as exc:  # noqa: BLE001 — per-model boundary
                     results[c] = exc
+            device_done = time.monotonic()
         reports = []
         for c in classifiers:               # phase 3: host finishing
             res = results[c]
@@ -565,6 +578,7 @@ class ModelBuilder:
                 reports.append(finish_host(c, *res))
             except Exception as exc:  # noqa: BLE001 — per-model boundary
                 reports.append(fail_report(c, exc))
+        tracing.record_span("build.tail", time.monotonic() - device_done)
         return reports
 
     def predict(self, model_name: str, dataset: str, out_name: str,
@@ -717,17 +731,17 @@ class ModelBuilder:
         model_builder.py:191-248 drops 'features'/'rawPrediction' and
         converts the probability vector to a plain list). Two spans
         under the caller's ``phase``: ``<phase>.rows`` gathers the
-        columns (the probability lists are a Python loop over every
-        row), ``<phase>.store`` appends and commits them."""
+        columns, ``<phase>.store`` appends and commits them."""
         ds = self.store.get(name)
         n = len(preds)
 
         def prob_objcol(block_probs: np.ndarray) -> np.ndarray:
-            # Object array of Python lists (np.array(list-of-lists,
-            # dtype=object) would build a 2-D array instead).
+            # Object array of Python lists of Python floats, filled by
+            # one tolist() (np.array(list-of-lists, dtype=object) would
+            # build a 2-D array instead; a loop over the rows holds the
+            # GIL against the other families' finishing).
             out = np.empty(len(block_probs), dtype=object)
-            for i, p in enumerate(block_probs):
-                out[i] = [float(x) for x in p]
+            out[:] = block_probs.astype(np.float64).tolist()
             return out
 
         def commit() -> None:

@@ -1,36 +1,31 @@
-"""Evaluation metrics, device-side.
+"""Evaluation metrics, on the host.
 
 The reference evaluates each fitted model with two Spark
 ``MulticlassClassificationEvaluator`` jobs — metricName "f1" (weighted by
 class support) and "accuracy" (reference model_builder.py:206-225). Both are
-reproduced here from a single confusion matrix built with one scatter-add
-pass on device, so evaluation costs one kernel instead of two cluster jobs.
+reproduced here from a single confusion matrix counted on the host: the
+labels and predictions are host arrays already, and a device program
+would queue behind every kernel the other families of a sweep have
+enqueued — a family's finishing waits for nothing but its own results.
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Dict
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-
-@partial(jax.jit, static_argnames=("num_classes",))
-def confusion_matrix(y_true: jax.Array, y_pred: jax.Array,
-                     num_classes: int) -> jax.Array:
-    idx = y_true * num_classes + y_pred
-    flat = jnp.zeros(num_classes * num_classes, jnp.float32).at[idx].add(1.0)
-    return flat.reshape(num_classes, num_classes)
 
 
 def classification_metrics(y_true: np.ndarray, y_pred: np.ndarray,
                            num_classes: int) -> Dict[str, float]:
     """accuracy + support-weighted F1 (pyspark's default "f1")."""
-    cm = np.asarray(confusion_matrix(
-        jnp.asarray(y_true, jnp.int32), jnp.asarray(y_pred, jnp.int32),
-        num_classes))
+    idx = (np.asarray(y_true, np.int64) * num_classes
+           + np.asarray(y_pred, np.int64))
+    # Counted in integers, then float32: the arithmetic below is the
+    # one the device's float32 confusion matrix went through, so f1 and
+    # accuracy keep their digits.
+    cm = np.bincount(idx, minlength=num_classes * num_classes).reshape(
+        num_classes, num_classes).astype(np.float32)
     support = cm.sum(axis=1)
     tp = np.diag(cm)
     pred_pos = cm.sum(axis=0)

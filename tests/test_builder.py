@@ -168,6 +168,43 @@ def test_pipelined_build_matches_direct_sequential_fits(store, runtime, cfg):
                                    err_msg=c)
 
 
+@pytest.mark.parametrize("streamed", [False, True],
+                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("num_classes", [2, 10])
+def test_probability_column_equals_the_per_row_loops(
+        store, runtime, cfg, monkeypatch, num_classes, streamed):
+    """The probability column is built without a loop over the rows and
+    is what the loop built: a 1-D object array of Python lists of
+    Python floats, in both branches of ``_save_predictions``."""
+    from learningorchestra_tpu.models.base import FitReport
+
+    _titanic_like(store, "pc_te", n=50, seed=5)
+    cfg.stream_design = streamed
+    mb = ModelBuilder(store, runtime, cfg)
+    out = store.create("pc_out", parent="pc_te")
+    probs = np.random.default_rng(num_classes).random(
+        (50, num_classes)).astype(np.float32)
+    want = [[float(x) for x in row] for row in probs]   # the per-row loop
+
+    handed, real = [], out.append_columns
+    monkeypatch.setattr(
+        out, "append_columns",
+        lambda cols: (handed.append(cols["probability"]), real(cols))[1])
+    mb._save_predictions("pc_out", store.get("pc_te"),
+                         np.argmax(probs, axis=1), probs,
+                         FitReport(kind="nb", fit_time=0.0),
+                         phase="fit.nb.finish")
+    (col,) = handed
+    assert col.dtype == object and col.shape == (50,)
+    assert all(type(row) is list and all(type(x) is float for x in row)
+               for row in col)
+    assert list(col) == want
+    stored = store.get("pc_out")
+    assert stored.metadata.finished
+    assert list(stored.read_rows(["probability"], 0, 50)["probability"]) \
+        == want
+
+
 def test_exec_preprocess_gated(store, runtime, cfg):
     _titanic_like(store, "train")
     _titanic_like(store, "test", n=50, seed=3)

@@ -224,6 +224,58 @@ def test_metrics_weighted_f1_matches_sklearn():
         f1_score(y, p, average="weighted"), abs=1e-6)
 
 
+def _scatter_metrics(y_true, y_pred, num_classes):
+    """The reference: the confusion matrix as one float32 scatter-add on
+    the device (what the program ran before ISSUE 31), then f1 and
+    accuracy by their definitions."""
+    import jax.numpy as jnp
+
+    idx = jnp.asarray(y_true, jnp.int32) * num_classes \
+        + jnp.asarray(y_pred, jnp.int32)
+    cm = np.asarray(jnp.zeros(num_classes * num_classes, jnp.float32)
+                    .at[idx].add(1.0)).reshape(num_classes, num_classes)
+    support, tp, pred_pos = cm.sum(axis=1), np.diag(cm), cm.sum(axis=0)
+    precision = np.where(pred_pos > 0, tp / np.maximum(pred_pos, 1), 0.0)
+    recall = np.where(support > 0, tp / np.maximum(support, 1), 0.0)
+    denom = precision + recall
+    f1 = np.where(denom > 0,
+                  2 * precision * recall / np.maximum(denom, 1e-12), 0.0)
+    total = max(support.sum(), 1)
+    return {"f1": float((f1 * support).sum() / total),
+            "accuracy": float(tp.sum() / total)}
+
+
+def _labels(num_classes, n, true_of=None, pred_of=None, seed=0):
+    rng = np.random.default_rng(seed)
+    return (rng.choice(true_of or num_classes, n).astype(np.int32),
+            rng.choice(pred_of or num_classes, n).astype(np.int64))
+
+
+@pytest.mark.parametrize("num_classes,y,p", [
+    (2, *_labels(2, 200)),
+    (3, *_labels(3, 200)),
+    (10, *_labels(10, 500)),
+    (3, *_labels(3, 200, true_of=[0, 1])),       # class 2 has no support
+    (3, *_labels(3, 200, pred_of=[0, 2])),       # class 1 is never predicted
+    (2, *_labels(2, 1)),
+], ids=["2", "3", "10", "no-support", "never-predicted", "one-row"])
+def test_metrics_equal_the_scatter_reference_with_no_device_call(
+        num_classes, y, p):
+    """Scoring is host work: equal to the device scatter it replaced,
+    digit for digit, with no transfer and no program compiled."""
+    import jax
+
+    from learningorchestra_tpu.utils import resources
+
+    want = _scatter_metrics(y, p, num_classes)
+    resources.ensure_listener()
+    compiles = resources.compile_snapshot()["compiles"]
+    with jax.transfer_guard("disallow"):
+        got = classification_metrics(y, p, num_classes)
+    assert resources.compile_snapshot()["compiles"] == compiles
+    assert got == want
+
+
 def test_probabilities_sum_to_one(runtime):
     X, y = _blobs(n=300, classes=2)
     for kind in ("lr", "nb", "gb", "rf"):

@@ -135,3 +135,71 @@ def test_interrupted_hot_swap_recovers_on_init(built):
     reg3 = ModelRegistry(mb.cfg)
     assert reg3.manifest("ptm_dt") == reg2.manifest("ptm_dt")
     assert not os.path.isdir(os.path.join(reg3.root, ".old.ptm_dt"))
+
+
+def test_models_wait_only_for_their_own_name(built, monkeypatch):
+    """One lock per model name (ISSUE 31): while a re-save of model A is
+    held inside its orbax write, save / load / version / manifest of
+    model B return; a load of A waits the save out and then reads one
+    whole version — the new manifest with the new params."""
+    import threading
+
+    import orbax.checkpoint as ocp
+
+    mb, _ = built
+    reg = mb.registry
+    man_a, model_a = reg.load("ptm_lr")
+    _, model_b = reg.load("ptm_dt")
+    old_version = reg.version("ptm_lr")
+    old_leaves = jax.tree.leaves(model_a.params)
+
+    entered, release = threading.Event(), threading.Event()
+
+    class Held(ocp.PyTreeCheckpointer):
+        def save(self, directory, *args, **kwargs):
+            if ".tmp.ptm_lr" in str(directory):
+                entered.set()
+                assert release.wait(60)
+            return super().save(directory, *args, **kwargs)
+
+    monkeypatch.setattr(ocp, "PyTreeCheckpointer", Held)
+
+    def in_thread(fn, *args, **kwargs):
+        box = []
+        t = threading.Thread(
+            target=lambda: box.append(fn(*args, **kwargs)), daemon=True)
+        t.start()
+        return t, box
+
+    model_a.params = jax.tree.map(lambda x: np.asarray(x) + 1.0,
+                                  model_a.params)
+    saver, _ = in_thread(reg.save, "ptm_lr", model_a,
+                         metrics={"version": 2},
+                         preprocess=man_a["preprocess"])
+    try:
+        assert entered.wait(60)
+        # Model B: every entry point returns while A's save is held.
+        for fn, args in ((reg.save, ("ptm_dt", model_b)),
+                         (reg.load, ("ptm_dt",)),
+                         (reg.version, ("ptm_dt",)),
+                         (reg.manifest, ("ptm_dt",))):
+            t, box = in_thread(fn, *args)
+            t.join(60)
+            assert box, f"{fn.__name__} of ptm_dt waited for ptm_lr's save"
+        # Model A, meanwhile: the lock-free reads still see the old
+        # version (never missing), and a load waits.
+        assert reg.version("ptm_lr") == old_version
+        assert reg.manifest("ptm_lr")["metrics"] == man_a["metrics"]
+        loader, loaded = in_thread(reg.load, "ptm_lr")
+        loader.join(0.5)
+        assert loader.is_alive() and not loaded
+    finally:
+        release.set()
+    saver.join(60)
+    loader.join(60)
+    assert not saver.is_alive() and not loader.is_alive()
+    (man, model), = loaded
+    assert man["metrics"] == {"version": 2}
+    for new, old in zip(jax.tree.leaves(model.params), old_leaves):
+        np.testing.assert_array_equal(new, np.asarray(old) + 1.0)
+    assert reg.version("ptm_lr") > old_version

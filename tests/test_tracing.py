@@ -457,6 +457,33 @@ def test_sync_sweep_span_tree(served):
     assert "attribution_dropped" not in tracing.counters_snapshot()
 
 
+def test_build_tail_is_the_finishing_after_the_last_device_phase(served):
+    """``build.tail`` (ISSUE 31): once a build, under ``build``, from
+    the end of the last family's device phase to the end of the round —
+    the finishing nothing on the device overlaps."""
+    ctx, _app = served
+    resp = _sync_sweep(ctx, "tr_tail")
+    spans = requests.get(ctx.url(
+        f"/trace/{resp.headers['X-Request-Id']}")).json()["spans"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+
+    def end(s):
+        return s["start"] + s["duration_ms"] / 1e3
+
+    (build,) = by_name["build"]
+    (tail,) = by_name["build.tail"]
+    assert tail["parent_id"] == build["span_id"]
+    assert tail["status"] == "ok"
+    last_device = max(end(by_name[f"fit.{c}.device"][0]) for c in SWEEP)
+    last_finish = max(end(by_name[f"fit.{c}.finish"][0]) for c in SWEEP)
+    # Spans round to the millisecond; the clocks are read a few
+    # statements apart.
+    assert tail["start"] == pytest.approx(last_device, abs=0.02)
+    assert last_finish - 0.02 <= end(tail) <= end(build) + 0.002
+
+
 def test_unsampled_sweep_records_no_span(served):
     ctx, _app = served
     tracing.set_sample(0.0)
