@@ -4,7 +4,7 @@ Drives the main path once on one TPU chip, through the entry points a
 user calls: the REST server (started in THIS process on a free localhost
 port — one process holds the chip, and no child that needs JAX is
 started) driven over HTTP with the client SDK. The data is the
-HIGGS-like workload of ``benchmarks/workload.py`` (28 float32 features +
+HIGGS-like workload of ``higgs_like_xy`` below (28 float32 features +
 binary label, seeded) written to CSV under a scratch directory and
 ingested by ``file://`` URL; nothing needs the network.
 
@@ -13,13 +13,13 @@ Phases, each timed and printed as one JSON line:
 1. ingest     POST /files of the train / test / viz CSVs
 2. catalog    one projection, one dtype coercion, one histogram
 3. sweep      POST /models lr/dt/rf/gb/nb at the Spark-parity defaults,
-              tree kernels on; accuracy floors of bench.py; cold and warm
+              tree kernels on; the ``ACC_FLOOR`` accuracy floors; cold and warm
 4. online     one model into the AOT plane, 32 predict_online requests,
               answers equal the batch predictions of the same rows
 5. viz        PCA and t-SNE plots over REST; the repulsion kernel against
               its XLA reference
    (then phase 3 once more at 11,000,000 rows — the repo's headline
-   size — placed straight into the store as bench.py does)
+   size — placed straight into the store, as perfbench does)
 6. shutdown   drain, stop, join
 
 ``--chips 4`` runs, instead of all that, only the mesh comparison: the
@@ -45,23 +45,79 @@ import tempfile
 import threading
 import time
 
+import numpy as np
+
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 
-#: HIGGS's 10% sample — the size BASELINE.md measured its CPU stand-in
-#: at — and bench.py's evaluation split.
+#: HIGGS's 10% sample, and the evaluation split.
 N_TRAIN = 1_100_000
 N_TEST = 100_000
-#: bench.py's headline size, placed straight into the store (no CSV).
+#: The headline size (perfbench's ``higgs-11m``), placed straight into
+#: the store (no CSV).
 N_HEADLINE = 11_000_000
 #: Rows of the PCA / t-SNE plots (16 repulsion tiles of 512).
 N_VIZ = 8192
 TSNE_ITERS = 250
 CLASSIFIERS = ["lr", "dt", "rf", "gb", "nb"]
-FEATURES = [f"f{i}" for i in range(28)]
+D = 28
+FEATURES = [f"f{i}" for i in range(D)]
 #: Statistical-parity bound between two fits of one family that sum in
 #: different orders (tests/test_tree_kernel.py uses the same ±0.01).
 ACC_PARITY = 0.01
+#: Per-family held-out accuracy floors on the workload below: they catch
+#: a broken fit, not a slow one.
+ACC_FLOOR = {"lr": 0.62, "nb": 0.62, "dt": 0.66, "rf": 0.70, "gb": 0.75}
+
+# The HIGGS-like workload. Each family gets its own signal, classes
+# balanced 50/50, so that shallow-tree ensembles beat linear models as on
+# the real HIGGS (Baldi et al. 2014):
+# - three *mean-shift* features (±delta): the linear food lr and nb eat;
+# - five *bimodal* features: class 1 draws from a two-mode mixture whose
+#   mean AND variance match class 0's N(0,1), so lr and gaussian-nb are
+#   blind to them while axis-aligned tree splits separate the modes;
+# - four *correlation-sign pairs*: (a, b) jointly gaussian with rho =
+#   +0.55 for class 1 and -0.55 for class 0; both marginals are N(0,1),
+#   so only feature interactions (ensembled trees) can learn them;
+# - the remaining features are pure N(0,1) noise, as distractors.
+_DELTA = 0.24          # mean-shift half-gap (linear signal strength)
+_MODE = 0.95           # bimodal mode offset; mode sd keeps variance at 1
+_RHO = 0.55            # correlation magnitude of the sign pairs
+_SHIFT_FEATURES = (10, 11, 12)
+_BIMODAL_FEATURES = range(13, 18)
+_PAIR_FEATURES = tuple((20 + 2 * j, 21 + 2 * j) for j in range(4))
+
+
+def higgs_like_xy(n: int, seed: int):
+    """(X float32 [n, 28], y int32 [n]) with the calibrated class
+    structure above."""
+    rng = np.random.default_rng(seed)
+    y = rng.integers(0, 2, n).astype(np.int32)
+    X = rng.normal(size=(n, D)).astype(np.float32)
+    mode_sd = float(np.sqrt(1.0 - _MODE * _MODE))
+    for f in _BIMODAL_FEATURES:
+        sign = rng.integers(0, 2, n) * 2 - 1
+        bim = (_MODE * sign + mode_sd * rng.normal(size=n)).astype(
+            np.float32)
+        X[:, f] = np.where(y == 1, bim, X[:, f])
+    resid = float(np.sqrt(1.0 - _RHO * _RHO))
+    for a, b in _PAIR_FEATURES:
+        z = rng.normal(size=n).astype(np.float32)
+        e = rng.normal(size=n).astype(np.float32)
+        r = np.where(y == 1, _RHO, -_RHO).astype(np.float32)
+        X[:, a] = z
+        X[:, b] = r * z + np.float32(resid) * e
+    for f in _SHIFT_FEATURES:
+        X[:, f] += np.where(y == 1, _DELTA, -_DELTA).astype(np.float32)
+    return X, y
+
+
+def higgs_like_columns(n: int, seed: int) -> dict:
+    """The same workload as catalog columns."""
+    X, y = higgs_like_xy(n, seed)
+    cols = {f"f{i}": X[:, i] for i in range(D)}
+    cols["label"] = y.astype(np.int64)
+    return cols
 
 
 def final_line(ok: bool, device: dict) -> dict:
@@ -100,11 +156,8 @@ def build_native_parser() -> str:
 def write_csv(path: str, n: int, seed: int):
     """One HIGGS-like CSV; returns its (X float32, y) for later checks.
     Float32 values are written in their shortest round-trip form."""
-    import numpy as np
     import pyarrow as pa
     import pyarrow.csv as pacsv
-
-    from benchmarks.workload import higgs_like_columns
 
     cols = higgs_like_columns(n, seed)
     pacsv.write_csv(pa.table(cols), path)
@@ -113,9 +166,7 @@ def write_csv(path: str, n: int, seed: int):
 
 def check_sweep(db, prefix: str) -> dict:
     """Every ``<prefix>_<c>`` metadata doc carries f1/accuracy/fit_time
-    and clears bench.py's accuracy floor for its family."""
-    from bench import ACC_FLOOR
-
+    and clears the accuracy floor for its family."""
     out = {}
     for kind in CLASSIFIERS:
         doc = db.read_file(f"{prefix}_{kind}", limit=1)[0]
@@ -163,7 +214,6 @@ def repulsion_check(n: int, seed: int) -> dict:
     relative to the force scale, not as an absolute."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from learningorchestra_tpu.ops import pallas_kernels
     from learningorchestra_tpu.viz import tsne
@@ -202,8 +252,6 @@ def repulsion_check(n: int, seed: int) -> dict:
 
 
 def run_one_chip(seed: int) -> None:
-    import numpy as np
-
     from learningorchestra_tpu.client import (
         Context, DatabaseApi, DataTypeHandler, Histogram, Model,
         Observability, Pca, Projection, Tsne)
@@ -324,11 +372,9 @@ def run_one_chip(seed: int) -> None:
         emit("viz", t0, rows=N_VIZ, png=png,
              repulsion=repulsion_check(N_VIZ, seed))
 
-        # The headline size, straight into the store as bench.py does
-        # (the one step no user's call reaches: there is no 3 GB CSV).
+        # The headline size, straight into the store (the one step no
+        # user's call reaches: there is no 3 GB CSV).
         t0 = time.time()
-        from benchmarks.workload import higgs_like_columns
-
         app.store.create("train11m", columns=higgs_like_columns(
             N_HEADLINE, seed), finished=True)
         made = round(time.time() - t0, 3)
@@ -356,9 +402,6 @@ def mesh_sweep(store, cfg, devices, tag: str) -> dict:
     """The phase-3 sweep through the library entry points on a mesh
     over ``devices``: cold and warm wall-clock, per-family accuracy
     (floors checked) and the warm sweep's test-set predictions."""
-    import numpy as np
-
-    from bench import ACC_FLOOR
     from learningorchestra_tpu.models.builder import ModelBuilder
     from learningorchestra_tpu.parallel.mesh import MeshRuntime, local_mesh
 
@@ -389,9 +432,7 @@ def run_four_chips(seed: int) -> None:
     row-sharded t-SNE repulsion and descent against single-device."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from benchmarks.workload import higgs_like_columns, higgs_like_xy
     from learningorchestra_tpu.catalog.store import DatasetStore
     from learningorchestra_tpu.config import Settings
     from learningorchestra_tpu.parallel import distributed

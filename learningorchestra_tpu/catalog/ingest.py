@@ -291,7 +291,7 @@ def _open_url_stream(url: str, timeout: float, offset: int = 0,
                      expect_identity: Optional[dict] = None
                      ) -> Iterator[bytes]:
     """Yield byte chunks from a URL (http(s)://) or local file (file:// or
-    bare path — used by tests and the bench harness), optionally starting
+    bare path — used by tests and chip_smoke.py), optionally starting
     at a byte offset (ingest resume). HTTP uses a Range request, falling
     back to skip-reading when the server ignores it — unless
     ``require_range`` is set (partition workers), in which case a

@@ -394,7 +394,7 @@ class Settings:
     )
     #: HBM budget (MB) for sizing a tune population wave: the largest
     #: candidate count whose modeled per-member footprint (models/
-    #: flops.py bytes model, raised to the family's recorded
+    #: tune.py ``_per_member_bytes``, raised to the family's recorded
     #: ``peak_hbm_bytes`` watermark when one exists) fits this budget
     #: runs as ONE vmapped device program; extra candidates spill into
     #: sequential waves (counted on ``/metrics``). ``0`` = unlimited
@@ -473,8 +473,7 @@ class Settings:
         default_factory=lambda: _env("LO_TPU_TRACE_BUFFER_SPANS", 4096)
     )
     #: Probability (0.0-1.0) that a new trace records spans. 1.0 traces
-    #: every request/job; 0.0 disables recording (ids still propagate,
-    #: which is what the bench's overhead A/B toggles).
+    #: every request/job; 0.0 disables recording (ids still propagate).
     trace_sample: float = field(
         default_factory=lambda: _env("LO_TPU_TRACE_SAMPLE", 1.0)
     )
@@ -700,25 +699,6 @@ def process_id() -> Optional[int]:
     (``LO_TPU_PROCESS_ID``); None = unset (single-host)."""
     raw = os.environ.get("LO_TPU_PROCESS_ID")
     return int(raw) if raw is not None and raw != "" else None
-
-
-def peak_flops() -> float:
-    """Override for the per-chip peak dense-matmul FLOP/s used as the
-    MFU denominator (``LO_TPU_PEAK_FLOPS``; unset, models/flops.py looks
-    the device up in its table of published peaks). 0.0 = unset."""
-    try:
-        return float(os.environ.get("LO_TPU_PEAK_FLOPS", "") or 0.0)
-    except ValueError:
-        return 0.0
-
-
-def peak_bw() -> float:
-    """Override for the per-chip peak HBM bandwidth used as the
-    ``bw_util`` denominator (``LO_TPU_PEAK_BW``). 0.0 = unset."""
-    try:
-        return float(os.environ.get("LO_TPU_PEAK_BW", "") or 0.0)
-    except ValueError:
-        return 0.0
 
 
 def failpoint_spec() -> str:

@@ -30,7 +30,7 @@ same-family configs becomes ONE device program:
   and scores.
 - **profile-guided population sizing**: per-member HBM footprint is
   modeled analytically and raised to the family's recorded
-  ``peak_hbm_bytes`` watermark (utils/resources.py, models/flops.py);
+  ``peak_hbm_bytes`` watermark (utils/resources.py);
   the largest candidate count that fits ``LO_TPU_TUNE_HBM_BUDGET_MB``
   runs as one wave, extras spill into sequential waves (counted on
   ``/metrics`` as ``lo_tune_hbm_spill_waves_total``).

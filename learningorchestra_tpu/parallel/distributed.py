@@ -93,9 +93,9 @@ def place_compile_cache() -> Optional[str]:
     ``JAX_COMPILATION_CACHE_DIR`` says if it is set (JAX reads it
     itself — nothing is set in code), else ``<checkout>/.jax_cache``.
     Returns the directory set in code, None when the environment
-    decides. Called by every long-lived entry point (the server, the
-    benches, chip_smoke.py), so a restart re-reads its fit programs
-    instead of recompiling them."""
+    decides. Called by every long-lived entry point (the server,
+    perfbench/server.py, chip_smoke.py), so a restart re-reads its fit
+    programs instead of recompiling them."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     path = os.path.join(_CHECKOUT, ".jax_cache")

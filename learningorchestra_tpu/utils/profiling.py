@@ -155,7 +155,7 @@ def device_span(fn, name: str):
     When the caller serializes device work (one fit in its device phase
     at a time), the span is the fit's device occupancy plus its transfer
     tail — the ``device_s`` figure that separates host jitter from
-    device compute in the bench. Under overlapped dispatch it includes
+    device compute. Under overlapped dispatch it includes
     queue waits behind other programs and is reported as such.
 
     ``name`` opens a trace span (ambient context) around the work,
@@ -170,8 +170,9 @@ def device_span(fn, name: str):
     the counter is process-global) and a device-bytes reading at its
     end merge into the current job's watermarks (``peak_hbm_bytes``)
     and — for ``fit.<family>.device`` names — the per-family table
-    bench.py and the job profile's ``fit_resources`` read; the span
-    carries the phase's ``compiles`` / ``compile_s`` as attributes.
+    ``tune.plan_waves`` and the job profile's ``fit_resources`` read;
+    the span carries the phase's ``compiles`` / ``compile_s`` as
+    attributes.
     Best-effort: a sampling failure degrades to an unprofiled span,
     never a failed fit.
     """

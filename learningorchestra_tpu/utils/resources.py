@@ -42,7 +42,7 @@ last-known snapshot.
 Counters are process-global (one server process = one metrics surface,
 the OpTimer convention); concurrent jobs' compile windows overlap, so a
 job's ``compile_s`` reads "compile seconds this process spent during the
-job's window" — exact when jobs serialize (the bench, the SPMD dispatch
+job's window" — exact when jobs serialize (the SPMD dispatch
 guard), an honest upper bound when they overlap.
 """
 
@@ -362,14 +362,9 @@ def remote_snapshots() -> Dict[int, Dict[str, Any]]:
 # -- phase sampling (the seam jobs/builder/spmd/profiling hook into) ----------
 
 #: Per-family watermark table accumulated across sweeps since the last
-#: reset — what bench.py reads for its ``resources`` block (builds run
-#: outside a managed job there, so the job profile can't carry them).
+#: reset — what models/tune.py ``plan_waves`` sizes a population wave by
+#: (a fit outside a managed job has no job profile to carry them).
 _families: Dict[str, Dict[str, Any]] = {}
-
-
-def reset_watermarks() -> None:
-    with _lock:
-        _families.clear()
 
 
 def family_watermarks() -> Dict[str, Dict[str, Any]]:

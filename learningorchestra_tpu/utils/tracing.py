@@ -35,8 +35,7 @@ Design (stdlib-only, like lolint):
 - **sampling** (``LO_TPU_TRACE_SAMPLE``): the record/skip decision is
   made once per trace; unsampled traces still mint + propagate ids (the
   response's ``X-Request-Id`` must always be quotable) but record
-  nothing and skip all child-span bookkeeping — the bench's overhead
-  A/B flips exactly this knob.
+  nothing and skip all child-span bookkeeping.
 
 Recording is cheap by construction: one ``os.urandom`` id + a dict and
 a deque-append under a short lock per span, no I/O, no serialization
@@ -135,7 +134,7 @@ _lock = threading.Lock()
 _spans: "deque[Span]" = deque()
 _counters = {"spans_recorded": 0, "spans_dropped": 0, "spans_ingested": 0,
              "traces_started": 0, "traces_unsampled": 0}
-#: None = read the knob from config.settings on use; tests/bench pin via
+#: None = read the knob from config.settings on use; tests pin via
 #: set_sample / set_capacity (the readpipe set_cache_budget pattern).
 _sample_override: Optional[float] = None
 _capacity_override: Optional[int] = None
@@ -174,7 +173,7 @@ def _sample_rate() -> float:
 
 
 def set_sample(rate: Optional[float]) -> None:
-    """Pin the sampling rate (tests, bench A/B); None restores the
+    """Pin the sampling rate (tests); None restores the
     ``LO_TPU_TRACE_SAMPLE`` process default."""
     global _sample_override
     _sample_override = rate

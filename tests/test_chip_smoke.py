@@ -81,23 +81,3 @@ def test_compile_cache_defaults_into_the_checkout(monkeypatch,
     want = os.path.join(REPO, ".jax_cache")
     assert distributed.place_compile_cache() == want
     assert cache_config.jax_compilation_cache_dir == want
-
-
-def test_no_peak_means_no_utilization(monkeypatch):
-    """A device outside models/flops.py's table (the CPU these tests
-    run on included) has no mfu or bw_util — never a v5e's by default.
-    The env overrides and the table entry still answer."""
-    from learningorchestra_tpu.models import flops
-
-    monkeypatch.delenv("LO_TPU_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("LO_TPU_PEAK_BW", raising=False)
-    assert flops.device_peak("flops") is None          # this CPU
-    assert flops.device_peak("bw", "TPU v9 imaginary") is None
-    assert flops.mfu(1e12, 1.0) is None
-    assert flops.bw_util(1e9, 1.0) is None
-    assert flops.device_peak("flops", "TPU v5 lite") == 197e12
-    assert flops.device_peak("bw", "TPU v5 lite") == 819e9
-    assert flops.mfu(197e12, 2.0, flops.device_peak(
-        "flops", "TPU v5 lite")) == pytest.approx(0.5)
-    monkeypatch.setenv("LO_TPU_PEAK_FLOPS", "1e12")
-    assert flops.mfu(1e12, 2.0) == pytest.approx(0.5)

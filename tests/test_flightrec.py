@@ -128,14 +128,22 @@ def test_quarantine_dumps_bundle_and_history_survives_restart(
         assert "serving_quarantined" in alerts_doc["firing"]
         assert alerts_doc["flightrec_latest"]
 
-        bundles = requests.get(f"{base}/debug/flightrec",
-                               timeout=10).json()
-        reasons = [b["reason"] for b in bundles]
-        assert any(r_ == "serving.quarantine" for r_ in reasons)
-        alert_bundles = [b for b in bundles
-                         if b["reason"] == "alert:serving_quarantined"]
-        assert alert_bundles
-        bdir = alert_bundles[0]["path"]
+        # The batcher fails its waiters BEFORE it dumps (a blocked
+        # caller gets its 503 at once), so its bundle may still be on
+        # the dispatcher thread's way to disk when the 503 is back and
+        # the alert's is already written: wait for both.
+        want = {"serving.quarantine", "alert:serving_quarantined"}
+        deadline = time.monotonic() + 30.0
+        while True:
+            bundles = requests.get(f"{base}/debug/flightrec",
+                                   timeout=10).json()
+            reasons = {b["reason"] for b in bundles}
+            if want <= reasons or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
+        assert want <= reasons, reasons
+        bdir = next(b["path"] for b in bundles
+                    if b["reason"] == "alert:serving_quarantined")
 
         # Bundle contents: the alert transition...
         with open(os.path.join(bdir, "manifest.json")) as f:

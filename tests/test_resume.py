@@ -34,6 +34,28 @@ def _expected(n):
             [f"tag{i % 5}" for i in range(n)])
 
 
+def _dying_stream(real_open, die_after):
+    """``_open_url_stream`` whose source dies once it has served
+    ``die_after`` bytes, in 4 KB pieces.
+
+    The pipeline parses up to ``min(8, max(4, cores)) + 2`` blocks ahead
+    of the one it appends and commits (ingest.py ``max_inflight``), and a
+    dead stream abandons them as a dead process would. At 200 rows a
+    block (6,400 bytes of this CSV) that is up to 64,000 bytes parsed and
+    not yet committed, so ``die_after`` has to lie well beyond it for any
+    core count to leave committed rows behind."""
+    def dying(url, timeout, offset=0):
+        served = 0
+        for chunk in real_open(url, timeout, offset=offset):
+            for i in range(0, len(chunk), 4 << 10):
+                piece = chunk[i:i + (4 << 10)]
+                served += len(piece)
+                yield piece
+                if served > die_after:
+                    raise ConnectionError("stream died")
+    return dying
+
+
 def _assert_rows_identical(ds, n):
     ea, eb, es = _expected(n)
     assert ds.num_rows == n
@@ -68,24 +90,13 @@ def test_interrupted_ingest_resumes_byte_identical(cfg, tmp_path):
     cfg.persist = True
     cfg.ingest_chunk_rows = 200
     cfg.ingest_commit_bytes = 0
-    n = 5000
+    n = 20_000
     p = _write_csv(tmp_path / "d.csv", n)
 
     real_open = ing._open_url_stream
-
-    def dying(url, timeout, offset=0):
-        served = 0
-        for chunk in real_open(url, timeout, offset=offset):
-            for i in range(0, len(chunk), 4 << 10):
-                piece = chunk[i:i + (4 << 10)]
-                served += len(piece)
-                yield piece
-                if served > 60_000:
-                    raise ConnectionError("stream died")
-
     store = DatasetStore(cfg)
     store.create("d", url=p)
-    ing._open_url_stream = dying
+    ing._open_url_stream = _dying_stream(real_open, 200_000)
     try:
         with pytest.raises(ConnectionError):
             ingest_csv_url(store, "d", p, cfg)
@@ -168,23 +179,12 @@ def test_resume_refuses_changed_source(cfg, tmp_path):
     cfg.persist = True
     cfg.ingest_chunk_rows = 200
     cfg.ingest_commit_bytes = 0
-    p = _write_csv(tmp_path / "d.csv", 5000)
+    p = _write_csv(tmp_path / "d.csv", 20_000)
 
     real_open = ing._open_url_stream
-
-    def dying(url, timeout, offset=0):
-        served = 0
-        for chunk in real_open(url, timeout, offset=offset):
-            for i in range(0, len(chunk), 4 << 10):
-                piece = chunk[i:i + (4 << 10)]
-                served += len(piece)
-                yield piece
-                if served > 40_000:
-                    raise ConnectionError("stream died")
-
     store = DatasetStore(cfg)
     store.create("d", url=p)
-    ing._open_url_stream = dying
+    ing._open_url_stream = _dying_stream(real_open, 150_000)
     try:
         with pytest.raises(ConnectionError):
             ingest_csv_url(store, "d", p, cfg)

@@ -385,21 +385,6 @@ def test_single_tree_families_keep_their_histogram_call(kind):
         for c in hists), hists
 
 
-def test_tree_bench_smoke(monkeypatch):
-    """The bench harness's tree-phase microbenchmark runs end to end on
-    the CPU mesh (LO_BENCH_TREE_ROWS smoke regime) and reports both
-    paths per phase."""
-    import bench
-
-    monkeypatch.setattr(bench, "N_TREE", 2048)
-    doc = bench.tree_bench()
-    assert doc["rows"] == 2048
-    assert set(doc["speedup"]) == {"hist", "route", "descend"}
-    for path in ("kernel", "xla"):
-        assert all(doc[path][k] > 0 for k in
-                   ("hist_ms", "route_ms", "descend_ms"))
-
-
 @pytest.mark.slow
 @pytest.mark.parametrize("kind", ["dt", "rf"])
 @pytest.mark.parametrize("n", [300, 3001])

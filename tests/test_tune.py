@@ -17,7 +17,7 @@ Acceptance bars from the PR issue:
    both /tune and /models, and the ``lo_tune_*`` /metrics series.
 
 Full 16-config population chaos (budget-forced waves + crash + resume)
-is slow-marked; tier-1 keeps the small-population smoke.
+is slow-marked; tier-1 keeps the small populations.
 """
 
 import json
@@ -70,6 +70,21 @@ def _by_config(board, config):
         if r["config"] == config:
             return r
     raise AssertionError(f"config {config} missing from board")
+
+
+def _config_grid(family: str, pop: int) -> list:
+    """``pop`` same-family configs varying the knobs a real sweep varies,
+    static-shape ones (depth, bins, iteration counts) included: serial
+    fits recompile per distinct value, the population program masks them
+    into one compile."""
+    if family == "dt":
+        return [{"max_depth": 2 + (i % 4),
+                 "n_bins": (8, 16, 32)[i % 3]} for i in range(pop)]
+    if family == "lr":
+        return [{"solver": "adam", "iters": 40 + 10 * (i % 6),
+                 "lr": round(0.02 * 1.3 ** (i % 8), 6),
+                 "l2": (1e-4, 1e-3)[i % 2]} for i in range(pop)]
+    raise ValueError(family)
 
 
 def _mk_cfg(tmp_path=None, **knobs):
@@ -362,10 +377,8 @@ def test_full_population_halving_chaos(runtime, tmp_path):
     """16-config population forced into HBM-budget waves, interrupted at
     a mid-wave halving rung, resumed: identical survivors and scores to
     the uninterrupted oracle under the SAME budget."""
-    import bench
-
     X, y = _blobs(n=400, seed=31)
-    configs = bench._tune_config_grid("lr", 16)
+    configs = _config_grid("lr", 16)
     cfg = _mk_cfg(tmp_path, tune_max_population=12)  # 12 // 2 folds -> waves
     oracle = tune.sweep(runtime, X, y, 2, "lr", configs, cfg=cfg,
                         folds=2, rungs=3)
@@ -529,23 +542,23 @@ def test_metrics_expose_tune_section(served):
         assert series in txt, series
 
 
-# -- bench smoke --------------------------------------------------------------
+# -- compile reuse -------------------------------------------------------------
 
-def test_tune_bench_smoke(runtime, monkeypatch):
-    """tune_bench runs end to end in the tiny regime; the 3x gate stays
-    UNARMED below the 16-config/2k-row measurement floor (the armed
-    sweep is the slow/CI-bench lane's job)."""
-    import bench
+def test_identical_second_sweep_compiles_nothing(runtime):
+    """A wave of shapes already seen pays no compile: an identical second
+    sweep leaves the process compile counter where it was, and its winner
+    is still the best of the serial fits."""
+    from learningorchestra_tpu.utils import resources
 
-    monkeypatch.setattr(bench, "N_TUNE_ROWS", 400)
-    monkeypatch.setattr(bench, "N_TUNE_CONFIGS", 4)
-    doc = bench.tune_bench(runtime, families=("dt",))
-    assert doc["rows"] == 400 and doc["population"] == 4
-    assert not doc["gate"]["armed"]
-    fam = doc["dt"]
-    assert fam["pop_wall_s"] > 0 and fam["serial_wall_s"] > 0
-    assert fam["compiles_pop"] >= 0 and fam["compiles_serial"] > 0
-    # The per-wave marginal compile claim holds even in the tiny
-    # regime: an identical second sweep reuses every compiled program.
-    assert fam["compiles_per_wave"] <= 2
-    assert 0.0 <= fam["winner_mean_score"] <= 1.0
+    X, y = _blobs(n=400, seed=7)
+    configs = _config_grid("dt", 4)
+    first = tune.sweep(runtime, X, y, 2, "dt", configs, cfg=Settings(),
+                       folds=1, rungs=1)
+    c0 = resources.compile_snapshot()["compiles"]
+    board = tune.sweep(runtime, X, y, 2, "dt", configs, cfg=Settings(),
+                       folds=1, rungs=1)
+    assert resources.compile_snapshot()["compiles"] == c0
+    assert ([(r["config"], r["fold_scores"]) for r in board["results"]]
+            == [(r["config"], r["fold_scores"]) for r in first["results"]])
+    assert board["winner"]["mean_score"] == max(
+        _serial_score(runtime, "dt", c, X, y, 2) for c in configs)
