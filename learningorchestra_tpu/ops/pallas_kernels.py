@@ -180,13 +180,23 @@ def tsne_repulsion(Y: jax.Array, valid: jax.Array, *, tile: int = TILE):
 # Binned-histogram tree-fitting kernels (models/trees.py hot loops)
 # ---------------------------------------------------------------------------
 
-#: VMEM byte budget for the in-kernel (tile, d·n_bins) bin one-hot — the
-#: operand the kernel exists to keep out of HBM. Bounds the row tile.
+#: Bounds the row tile (``tree_tile``): the bytes a whole (tile, d·n_bins)
+#: f32 bin one-hot would take. The kernel holds no such block — it
+#: builds one 128-column group's (128, tile) compare mask at a time and
+#: the MXU takes the mask itself as its weights — so this is not what
+#: VMEM holds (that is the step's operand blocks, double-buffered, and
+#: the accumulator under ``_TREE_ACC_BYTES``). It stays because the tile
+#: is the length of every dot's contraction, hence the order in which
+#: gb's real-valued statistics are summed: another tile is another
+#: last bit.
 _TREE_ONEHOT_BYTES = 4 << 20
 #: VMEM byte budget for the resident (node·stat, d·n_bins) histogram
 #: accumulator block; larger accumulators split over a node-group grid
 #: dimension (each group re-streams the row tiles).
 _TREE_ACC_BYTES = 2 << 20
+#: VMEM byte budget for one grid step's operand blocks of the histogram
+#: kernel (bin codes, stats, node ids; the pipeline holds two steps).
+_TREE_STEP_BYTES = 1 << 20
 #: Row tile for the routing/descent kernels (pure VPU, tiny per-row
 #: state) and the minimum prediction batch that engages ``tree_descend``
 #: (below it, padding overhead beats the fusion win — e.g. the online
@@ -198,9 +208,9 @@ _LANES = 128
 
 
 def tree_tile(d: int, n_bins: int) -> int:
-    """Histogram-kernel row tile: the largest power of two ≤ 1024 whose
-    in-kernel one-hot block fits the VMEM budget. Floor 128 keeps the
-    f32/bf16 sublane tiling utilized even at d·n_bins extremes
+    """Histogram-kernel row tile — the rows one dot contracts over: the
+    largest power of two ≤ 1024 under ``_TREE_ONEHOT_BYTES``. Floor 128
+    keeps the f32/bf16 sublane tiling utilized even at d·n_bins extremes
     (d=128 × n_bins=256 → 128-row tiles)."""
     tile = 1024
     while tile > 128 and tile * max(d * n_bins, 1) * 4 > _TREE_ONEHOT_BYTES:
@@ -216,6 +226,18 @@ def _tree_node_groups(n_nodes: int, n_stats: int, d: int,
     while ng > 1 and ng * n_stats * d * n_bins * 4 > _TREE_ACC_BYTES:
         ng //= 2
     return ng
+
+
+def _tiles_per_step(tile_bytes: int, n_tiles: int) -> int:
+    """Row tiles one grid step of the histogram kernel takes (an in-kernel
+    loop over them): up to eight, as many as keep the step's operand
+    blocks under ``_TREE_STEP_BYTES``. A 1,024-row step is shorter than
+    the latency of the three DMAs that fetch the next one, and waits for
+    them at every step: 8.70 ms a pass at 11M × 28, 32 bins, against
+    7.92 ms at eight tiles a step (chip run, PR 33). A loop and not an
+    unrolled body: every process start traces and lowers the body again,
+    persistent compile cache or not."""
+    return max(1, min(8, _TREE_STEP_BYTES // tile_bytes, n_tiles))
 
 
 def _pad_lanes(arr: jax.Array, n_pad: int, value=0) -> jax.Array:
@@ -237,17 +259,34 @@ def _row_vec(v: jax.Array, n_pad: int, value=0) -> jax.Array:
                       value)
 
 
-def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
-                      n_trees, operand_dtype):
-    """One (node-group g, row-tile t) cell of the histogram grid, for the
-    ``n_trees`` trees that share the tile's bin codes.
+def _group_runs(lo: int, n_bins: int, d: int):
+    """Static plan of the 128-column histogram group that starts at
+    column ``lo``: its sublanes cut into runs that each lie in ONE
+    feature's columns, as (feature, first sublane, bin at that sublane,
+    sublanes); feature None past the last real column ``d·n_bins``."""
+    runs, c = [], lo
+    while c < lo + _LANES:
+        f = c // n_bins
+        end = min((f + 1) * n_bins, lo + _LANES)
+        runs.append((f if f < d else None, c - lo, c - f * n_bins, end - c))
+        c = end
+    return runs
 
-    Scatter-adds the row tile's sufficient statistics into the
+
+def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
+                      n_trees, operand_dtype, tile, n_tiles):
+    """One (node-group g, step t) cell of the histogram grid, for the
+    ``n_trees`` trees that share the bin codes: the step's row tiles
+    (``_tiles_per_step``), ``tile`` rows each, in the table's order.
+
+    Scatter-adds each row tile's sufficient statistics into the
     VMEM-resident (T·NG·S, d·n_bins) accumulator block: the node-masked
     stats operand and the bin one-hot are built in VMEM and consumed by
     the MXU — never written to HBM. The accumulator block is indexed by
     g only, so it stays resident while the row tiles stream past (t is
-    the innermost grid dimension).
+    the innermost grid dimension). One dot per tile and group, whatever
+    the step holds: the tile is the contraction, so the sums and their
+    order are those of a one-tile step.
 
     Every value is 2-D with the tile's rows in lanes, as the operands
     arrive — Mosaic refuses the rank-3 broadcasts/reshapes the XLA
@@ -255,62 +294,95 @@ def _tree_hist_kernel(codes_ref, stats_ref, rel_ref, out_ref, *, n_bins,
     ``node·S + s`` is selected from its (1, tile) node-id row, and the
     trees' (NG·S, tile) operands stack along the matmul's rows; the
     one-hot is built transposed, one 128-column group of the histogram
-    at a time, ONCE for all the trees: ``col`` is each (feature, row)'s
-    global histogram column ``f·n_bins + code``, so a group's one-hot is
-    the OR, over the few features whose columns fall in it, of a
-    sublane-broadcast compare. The one-hot is the operand the MXU holds
-    (a 128×128 block of it per 128 rows of the tile) and what it costs
-    to build and to load does not depend on how many stats rows stream
-    past it: at T = 1 each loaded block meets NG·S rows, at T trees
-    T·NG·S. Operands mirror the XLA oracle's dtype (bf16 on TPU, f32
-    elsewhere); {0,1} one-hot products are exact and every dot
-    accumulates in f32 along the tile, per output row — so a tree's
-    rows read the same whether it rides alone or stacked.
+    at a time, ONCE for all the trees. It never exists as a value: what
+    the kernel builds is the group's (128, tile) compare mask, which
+    the TPU's compiler packs to the operand's width and pushes into the
+    MXU as the weights themselves (1.0 under the mask), a 128×128 block
+    per 128 rows of the tile. A pass is paced by the mask-writing
+    instructions (two a bundle on the v5e), so the body spends one
+    compare a vreg where the shape allows it: when ``n_bins`` is a
+    multiple of the 8 sublanes of a vreg, every feature's run of a
+    group's sublanes (``_group_runs``) starts and ends on a vreg, so
+    the runs' sublane-broadcast code rows — each shifted so that a hit
+    reads "equals my sublane's index" — concatenate for free and meet
+    ONE iota in one compare, whatever the number of features in the
+    group and wherever a feature straddles two groups. Any other
+    ``n_bins`` (the 63 leaves of the leaf statistics, 2 bins) takes a
+    compare per feature of the group, OR-ed: ``col`` is each (feature,
+    row)'s global column ``f·n_bins + code``. The form is chosen from
+    the static shape alone. Operands mirror the XLA oracle's dtype
+    (bf16 on TPU, f32 elsewhere); {0,1} one-hot products are exact and
+    every dot accumulates in f32 along the tile, per output row — so a
+    tree's rows read the same whether it rides alone or stacked, and the
+    same under either form of the mask.
     """
     g = pl.program_id(0)
     t = pl.program_id(1)
-    d, tile = codes_ref.shape
+    d, step_rows = codes_ref.shape
+    per_step = step_rows // tile
     S = stats_ref.shape[-2]
     NGS = out_ref.shape[0] // n_trees
     Wp = out_ref.shape[1]
-
-    # Inactive/padded rows carry rel = -1 and rows of other node groups
-    # fall outside [0, NGS): neither matches any accumulator row.
     row = jax.lax.broadcasted_iota(jnp.int32, (NGS, tile), 0)
-    parts = []
-    for k in range(n_trees):
-        first = (rel_ref[k:k + 1, :] - g * (NGS // S)) * S    # (1, tile)
-        At = jnp.zeros((NGS, tile), jnp.float32)
-        for s in range(S):
-            stat = (stats_ref[s:s + 1, :] if len(stats_ref.shape) == 2
-                    else stats_ref[k, s:s + 1, :])
-            At = jnp.where(row == first + s, stat, At)
-        parts.append(At)
-    At = parts[0] if n_trees == 1 else jnp.concatenate(parts, axis=0)
-    At = At.astype(operand_dtype)                        # (T·NGS, tile)
-
-    codes = codes_ref[:].astype(jnp.int32)                    # (d, tile)
-    col = jnp.where(
-        codes < n_bins,
-        codes + n_bins * jax.lax.broadcasted_iota(jnp.int32, (d, tile), 0),
-        -1)
-    group = jax.lax.broadcasted_iota(jnp.int32, (_LANES, tile), 0)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_LANES, tile), 0)
+    one_compare = n_bins % 8 == 0
 
     @pl.when(t == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    for lo in range(0, Wp, _LANES):
-        cols = group + lo                     # this group's column ids
-        hit = None
-        for f in range(lo // n_bins,
-                       min(d - 1, (lo + _LANES - 1) // n_bins) + 1):
-            m = col[f:f + 1, :] == cols
-            hit = m if hit is None else hit | m
-        ohT = jnp.where(hit, 1.0, 0.0).astype(operand_dtype)  # (128, tile)
-        out_ref[:, lo:lo + _LANES] += jax.lax.dot_general(
-            At, ohT, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def one_tile(c, carry):
+        rows = pl.ds(pl.multiple_of(c * tile, tile), tile)
+        # Inactive/padded rows carry rel = -1 and rows of other node
+        # groups fall outside [0, NGS): neither matches any accumulator
+        # row.
+        parts = []
+        for k in range(n_trees):
+            first = (rel_ref[k:k + 1, rows] - g * (NGS // S)) * S  # (1, tile)
+            At = jnp.zeros((NGS, tile), jnp.float32)
+            for s in range(S):
+                stat = (stats_ref[s:s + 1, rows]
+                        if len(stats_ref.shape) == 2
+                        else stats_ref[k, s:s + 1, rows])
+                At = jnp.where(row == first + s, stat, At)
+            parts.append(At)
+        At = parts[0] if n_trees == 1 else jnp.concatenate(parts, axis=0)
+        At = At.astype(operand_dtype)                    # (T·NGS, tile)
+
+        codes = codes_ref[:, rows].astype(jnp.int32)          # (d, tile)
+        if not one_compare:
+            col = jnp.where(
+                codes < n_bins,
+                codes + n_bins * jax.lax.broadcasted_iota(
+                    jnp.int32, (d, tile), 0),
+                -1)
+        for lo in range(0, Wp, _LANES):
+            runs = _group_runs(lo, n_bins, d)
+            if one_compare:
+                # Sublane a + i of a run is bin b + i of its feature: a
+                # hit where code + (a - b) equals the sublane's own
+                # index. A code ≥ n_bins would need a sublane past its
+                # run's end.
+                want = [jnp.full((nr, tile), -1, jnp.int32) if f is None
+                        else jnp.broadcast_to(codes[f:f + 1, :] + (a - b),
+                                              (nr, tile))
+                        for f, a, b, nr in runs]
+                hit = (want[0] if len(want) == 1
+                       else jnp.concatenate(want, axis=0)) == sub
+            else:
+                hit = None
+                for f in (f for f, *_ in runs if f is not None):
+                    m = col[f:f + 1, :] == sub + lo
+                    hit = m if hit is None else hit | m
+            ohT = jnp.where(hit, 1.0, 0.0).astype(operand_dtype)
+            out_ref[:, lo:lo + _LANES] += jax.lax.dot_general(
+                At, ohT, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return carry
+
+    # The last step ends with the table, not with its block.
+    jax.lax.fori_loop(0, jnp.minimum(per_step, n_tiles - t * per_step),
+                      one_tile, 0)
 
 
 def _hist_pallas(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
@@ -338,15 +410,19 @@ def _hist_pallas(codes_T, stats_T, rel, active, *, n_nodes, n_bins, tile,
     # every in-kernel store is aligned; the group axis leads so a block
     # always spans the array's full trailing dims, whatever T·NG·S is.
     Wp = -(-d * n_bins // _LANES) * _LANES
+    n_tiles = n_pad // tile
+    # A row's operand bytes: d codes, and per tree S f32 stats + a node id.
+    step = tile * _tiles_per_step(
+        tile * (d * codes_T.dtype.itemsize + 4 * T * (S + 1)), n_tiles)
     out = pl.pallas_call(
         partial(_tree_hist_kernel, n_bins=n_bins, n_trees=T,
-                operand_dtype=operand_dtype),
-        grid=(G, n_pad // tile),
+                operand_dtype=operand_dtype, tile=tile, n_tiles=n_tiles),
+        grid=(G, -(-n_pad // step)),
         in_specs=[
-            pl.BlockSpec((d, tile), lambda g, t: (0, t)),
-            (pl.BlockSpec((T, S, tile), lambda g, t: (0, 0, t)) if stacked
-             else pl.BlockSpec((S, tile), lambda g, t: (0, t))),
-            pl.BlockSpec((T, tile), lambda g, t: (0, t)),
+            pl.BlockSpec((d, step), lambda g, t: (0, t)),
+            (pl.BlockSpec((T, S, step), lambda g, t: (0, 0, t)) if stacked
+             else pl.BlockSpec((S, step), lambda g, t: (0, t))),
+            pl.BlockSpec((T, step), lambda g, t: (0, t)),
         ],
         out_specs=pl.BlockSpec((None, T * NG * S, Wp),
                                lambda g, t: (g, 0, 0)),

@@ -9,6 +9,12 @@ width: HIGGS's 28 features, the Spark-parity depth-5 / 32-bin defaults,
 rf's vmapped batch of 5 stat sets (stacked on the histogram kernel's
 matmul rows; its leaf statistics a grid axis), the 256-bin uint8 extreme
 at its own tile, and the t-SNE repulsion at the 8,192-row plot size.
+Between them the histogram cases take every form of the bin one-hot the
+kernel's shape rule can choose (``pk._tree_hist_kernel``): one compare a
+128-column group with four features a group (32 bins), with one feature
+over two groups (256), with features that straddle groups and a width
+that is no multiple of 128 (48), each alone and stacked; and the compare
+per feature of the leaf statistics' 63 "bins".
 
 Nothing runs — a pass here says nothing about results or times, and is
 never reported as a chip run. This is the one file that describes a
@@ -127,8 +133,10 @@ def _tsne_rows():
 CASES = {
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
+    "tree_histogram-48bins-straddling": lambda: _hist(48),
     "tree_histogram-vmap5": _hist_vmapped,
     "tree_histogram-vmap5-256bins": lambda: _hist_vmapped(256),
+    "tree_histogram-vmap5-48bins-straddling": lambda: _hist_vmapped(48),
     "tree_leaf_stats": _leaf,
     "tree_leaf_stats-vmap5": lambda: _leaf(5),
     "tree_route_level": _route,
@@ -152,7 +160,10 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
 #: line (``tree_leaf_stats`` shares the histogram's ``pallas_call``).
 KERNEL_NAMES = {
     "tree_histogram-32bins": "tree_hist",
+    "tree_histogram-256bins": "tree_hist",
+    "tree_histogram-48bins-straddling": "tree_hist",
     "tree_histogram-vmap5": "tree_hist_stacked",
+    "tree_histogram-vmap5-48bins-straddling": "tree_hist_stacked",
     "tree_histogram-vmap5-256bins": "tree_hist",
     "tree_leaf_stats": "tree_hist",
     "tree_leaf_stats-vmap5": "tree_hist",

@@ -243,6 +243,110 @@ def test_stacked_histogram_equals_per_tree(T, d, n_bins):
         rtol=1e-6, atol=1e-6)
 
 
+def _parent_histogram(codes_T, stats_T, rel, active, *, n_nodes, n_bins,
+                      tile, operand_dtype):
+    """The oracle of the kernel body's own schedule: PR 32's formulation
+    of one tree's call, tile by tile in plain jnp — per 128-column group
+    a compare per feature of the group against the global column ids,
+    OR-ed, selected to {0,1} in the operand's dtype, and the same dot on
+    the same shapes in the same order. Returns the flat
+    (n_nodes·S, d·n_bins) f32 block."""
+    d, n = codes_T.shape
+    S = stats_T.shape[0]
+    n_pad = -(-n // tile) * tile
+    codes = jnp.pad(codes_T.astype(jnp.int32), ((0, 0), (0, n_pad - n)))
+    stats = jnp.pad(stats_T, ((0, 0), (0, n_pad - n)))
+    rel = jnp.pad(jnp.where(active, rel, -1).astype(jnp.int32)[None, :],
+                  ((0, 0), (0, n_pad - n)), constant_values=-1)
+    M = n_nodes * S
+    Wp = -(-d * n_bins // 128) * 128
+    row = jax.lax.broadcasted_iota(jnp.int32, (M, tile), 0)
+    group = jax.lax.broadcasted_iota(jnp.int32, (128, tile), 0)
+    out = jnp.zeros((M, Wp), jnp.float32)
+    for t in range(n_pad // tile):
+        sl = slice(t * tile, (t + 1) * tile)
+        At = jnp.zeros((M, tile), jnp.float32)
+        for s in range(S):
+            At = jnp.where(row == rel[:, sl] * S + s, stats[s:s + 1, sl], At)
+        At = At.astype(operand_dtype)
+        col = jnp.where(
+            codes[:, sl] < n_bins,
+            codes[:, sl] + n_bins * jax.lax.broadcasted_iota(
+                jnp.int32, (d, tile), 0),
+            -1)
+        for lo in range(0, Wp, 128):
+            cols = group + lo
+            hit = None
+            for f in range(lo // n_bins,
+                           min(d - 1, (lo + 127) // n_bins) + 1):
+                m = col[f:f + 1, :] == cols
+                hit = m if hit is None else hit | m
+            ohT = jnp.where(hit, 1.0, 0.0).astype(operand_dtype)
+            out = out.at[:, lo:lo + 128].add(jax.lax.dot_general(
+                At, ohT, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32))
+    return out[:, :d * n_bins]
+
+
+@pytest.mark.parametrize("operand_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("d,n_bins", [(3, 2), (6, 32), (28, 32), (28, 256),
+                                      (5, 48)])
+def test_histogram_body_equals_the_parents_formulation(d, n_bins, T,
+                                                       operand_dtype):
+    """Whichever form of the bin one-hot the static shape selects (one
+    compare a 128-column group where ``n_bins`` is a multiple of 8 — with
+    four features a group, one feature over two groups, or, at 48 bins,
+    features that straddle groups and a width that is no multiple of
+    128 — and a compare per feature at 2 bins), the histogram is the
+    parent's bit for bit: real-valued stats, n no multiple of the tile
+    (five tiles, so a grid step of four ends past the table), inactive
+    rows, codes ≥ ``n_bins``; one tree and five stacked."""
+    NL, S = 4, 2
+    tile = pk.tree_tile(d, n_bins)
+    n = 4 * tile + 77
+    rng = np.random.default_rng(d * n_bins + T)
+    codes = jnp.asarray(
+        rng.integers(0, min(n_bins + 3, 256), (d, n)).astype(np.uint8))
+    stats = jnp.asarray(rng.normal(size=(T, S, n)).astype(np.float32))
+    rel = jnp.asarray(rng.integers(0, NL, (T, n)).astype(np.int32))
+    act = jnp.asarray(rng.random((T, n)) < 0.8)
+    kw = dict(n_nodes=NL, n_bins=n_bins, tile=tile,
+              operand_dtype=operand_dtype)
+    hist = jax.jit(partial(pk.tree_histogram, **kw))
+    if T == 1:
+        got = hist(codes, stats[0], rel[0], act[0])[None]
+    else:
+        got = jax.vmap(hist, in_axes=(None, 0, 0, 0))(codes, stats, rel, act)
+    want = np.stack([
+        np.asarray(_parent_histogram(codes, stats[k], rel[k], act[k], **kw))
+        .reshape(NL, S, d, n_bins).transpose(0, 2, 3, 1) for k in range(T)])
+    assert (n_bins == 256) or bool((np.asarray(codes) >= n_bins).any())
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("operand_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_leaf_stats_body_equals_the_parents_formulation(operand_dtype):
+    """The leaf statistics' shape: one synthetic feature whose int32
+    "codes" are node ids, 63 "bins" (no multiple of 8: the per-feature
+    compare), one lane group, one node."""
+    M, S = 63, 2
+    tile = pk.tree_tile(28, 32)
+    n = 2 * tile + 77
+    rng = np.random.default_rng(63)
+    assign = jnp.asarray(rng.integers(0, M, (n,)).astype(np.int32))
+    stats = jnp.asarray(rng.normal(size=(S, n)).astype(np.float32))
+    got = pk.tree_leaf_stats(assign, stats, n_nodes=M, tile=tile,
+                             operand_dtype=operand_dtype)
+    want = _parent_histogram(
+        assign[None, :], stats, jnp.zeros((n,), jnp.int32),
+        jnp.ones((n,), bool), n_nodes=1, n_bins=M, tile=tile,
+        operand_dtype=operand_dtype)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_batching_rule_branches():
     """Which kernel a ``vmap`` over the histogram call compiles to is
     read off the operands' own batch flags: codes shared → ONE call
