@@ -39,6 +39,9 @@ class TrainedModel:
     predict_proba_fn: Callable        # (params, X_dev) -> (n, C) probs
     num_classes: int
     hparams: Dict[str, Any] = field(default_factory=dict)
+    #: What the fit kept of its own course (per-step losses, counters):
+    #: the builder stores it with the model's metrics.
+    fit_metrics: Dict[str, Any] = field(default_factory=dict)
 
     #: Rows per device predict call — bounds transient device memory on
     #: huge test sets (an (n, C)-shaped probability tensor lane-pads its

@@ -333,6 +333,7 @@ class ModelBuilder:
                         report.metrics = classification_metrics(
                             y_test, preds, num_classes)
                 report.metrics["device_s"] = round(device_s, 6)
+                report.metrics.update(model.fit_metrics)
                 if self.cfg.persist_models:
                     # Best-effort: a persistence failure must not discard
                     # an otherwise successful fit's predictions; surface it

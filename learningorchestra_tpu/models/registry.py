@@ -28,7 +28,9 @@ CLASSIFIERS: Dict[str, Callable] = {
 #: serves: every continuous-feature family. "tx" is excluded — it
 #: consumes token sequences, so inline JSON feature rows are
 #: out-of-domain for it (its serving story is the batch predictions
-#: route).
+#: route). That holds with its ``arch`` block too: a row of a published
+#: language model is thousands of token columns and its forward pass is
+#: seconds of chip time, not a micro-batch.
 ONLINE_KINDS = ("lr", "nb", "dt", "rf", "gb", "mlp")
 
 
@@ -85,7 +87,25 @@ HPARAM_SPECS: Dict[str, Dict[str, Tuple[Callable, str]]] = {
            "d_ff": _int_range(8, 16384), "vocab": _int_range(0, 2 ** 22),
            "train_steps": _int_range(1, 1_000_000),
            "batch": _int_range(1, 1 << 22), "lr": _positive(),
-           "causal": _boolean(), "remat": _boolean()},
+           "causal": _boolean(), "remat": _boolean(),
+           # The architecture block (models/transformer.py's options): a
+           # nested table, validated key by key and persisted whole in
+           # the model's hparams so predictor_for rebuilds the model.
+           "arch": {
+               "rms_norm": _boolean(), "norm_eps": _positive(),
+               "n_kv_heads": _int_range(1, 64),
+               "head_dim": _int_range(2, 512), "rope_theta": _positive(),
+               "qk_norm": _boolean(), "indexer_heads": _int_range(1, 64),
+               "indexer_head_dim": _int_range(2, 512),
+               "indexer_topk": _int_range(1, 1 << 20),
+               "q_chunk": _int_range(1, 1 << 16),
+               "n_experts": _int_range(1, 4096),
+               "experts_per_token": _int_range(1, 64),
+               "expert_width": _int_range(1, 65536),
+               "experts_first": _int_range(0, 4095),
+               "experts_held": _int_range(1, 4096),
+               "norm_topk_prob": _boolean(), "lm_head": _boolean(),
+               "init_std": _positive()}},
 }
 
 
@@ -101,16 +121,29 @@ def validate_hparams(classifier: str, hparams: Any) -> None:
         raise ValueError(
             f"hparams for classifier {classifier!r} must be an object of "
             f"name->value, got {type(hparams).__name__}")
-    spec = HPARAM_SPECS[classifier]
-    for key, value in hparams.items():
+    _validate_table(classifier, HPARAM_SPECS[classifier], hparams, "")
+
+
+def _validate_table(classifier: str, spec: Dict, table: Dict,
+                    prefix: str) -> None:
+    for key, value in table.items():
+        name = prefix + key
         if key not in spec:
             raise ValueError(
-                f"unknown hparam {key!r} for classifier {classifier!r}; "
-                f"known: {sorted(spec)}")
+                f"unknown hparam {name!r} for classifier {classifier!r}; "
+                f"known: {sorted(prefix + k for k in spec)}")
+        if isinstance(spec[key], dict):
+            if not isinstance(value, dict):
+                raise ValueError(
+                    f"hparam {name!r} for classifier {classifier!r} must "
+                    f"be an object of name->value, got "
+                    f"{type(value).__name__}")
+            _validate_table(classifier, spec[key], value, name + ".")
+            continue
         check, expect = spec[key]
         if not check(value):
             raise ValueError(
-                f"hparam {key!r} for classifier {classifier!r} is out of "
+                f"hparam {name!r} for classifier {classifier!r} is out of "
                 f"range: expected {expect}, got {value!r}")
 
 

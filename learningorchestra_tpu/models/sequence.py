@@ -14,11 +14,27 @@ batch rows shard over ``data``, attention heads / FFN hidden over
 ``model`` (Megatron-style), and sequence length over ``seq`` with exact
 ring attention (parallel/ring_attention.py) — the REST surface is a thin
 adapter over exactly the machinery ``dryrun_multichip`` compiles for
-pods. No reference behavior exists to match (the reference predates
-sequence models, SURVEY.md §5).
+pods.
+
+With an ``arch`` block in its hyperparameters the same family is one of
+today's language-model blocks (RMSNorm, RoPE, grouped-query attention,
+the sparse-attention indexer, routed experts of which this holder was
+told its share, a next-token loss with the label-token readout:
+models/transformer.py). What it is held to then is the benchmark's plain
+reference (``perfbench/reference_tx.py``); the small block without
+``arch`` has no published model to match.
+
+The step loop touches the host once a fit: the token table is placed on
+the device, each step draws its batch there from the seed, the steps are
+enqueued back to back, and every step's losses, gradient norms and
+counters are fetched together at the end.
 """
 
 from __future__ import annotations
+
+import functools
+import threading
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +44,66 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.transformer import (
-    TxConfig, forward_reference, init_params, make_train_step, shard_params)
+    TxConfig, forward_reference, has_options, make_fit_programs)
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, MeshRuntime)
+from learningorchestra_tpu.utils import tracing
+
+#: A fit's routing and selection readings: span attributes of
+#: ``fit.tx.steps`` and, of the last fit, counters on ``GET /metrics``.
+_READINGS = ("keys_kept_mean", "queries_short_share", "absent_share",
+             "moe_imbalance")
+
+_counters_lock = threading.Lock()
+_counters: Dict[str, Any] = {"fits": 0, "steps": 0, "tokens": 0,
+                             "dropped_tokens": 0}
+
+
+def counters_snapshot() -> Dict[str, Any]:
+    """The family's counters for ``GET /metrics``: totals since the
+    start, and the last fit's routing and selection readings."""
+    with _counters_lock:
+        return dict(_counters)
+
+
+@functools.lru_cache(maxsize=8)
+def _fit_programs(cfg: TxConfig, mesh, lr: float, batch: int):
+    """One pair of fit programs per configuration, mesh, rate and batch:
+    a second fit of the same request traces and compiles nothing."""
+    return make_fit_programs(cfg, mesh, optax.adam(lr), batch)
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def _fit_metrics(reports: list, cfg: TxConfig, tokens_per_step: int) -> dict:
+    """What a fit keeps of its steps (stored with the model's metrics):
+    each step's loss parts and gradient norms, and the steps' counters."""
+    steps = len(reports)
+    out = {"steps": steps, "tokens": steps * tokens_per_step,
+           "loss": [float(r["loss_main"] + r["loss_index"]) for r in reports],
+           "loss_main": [float(r["loss_main"]) for r in reports],
+           "grad_norm": {g: [float(r["grad_norm"][g]) for r in reports]
+                         for g in reports[0]["grad_norm"]}}
+    queries = float(steps * tokens_per_step * cfg.n_layers)
+    if cfg.indexer_heads:
+        out["loss_index"] = [float(r["loss_index"]) for r in reports]
+    if cfg.n_kv_heads and cfg.causal:
+        out["keys_kept_mean"] = sum(
+            float(r["keys_kept"]) for r in reports) / queries
+        out["queries_short_share"] = sum(
+            float(r["queries_short"]) for r in reports) / queries
+    if cfg.n_experts:
+        moe = np.sum([np.asarray(r["moe"], np.float64) for r in reports], 0)
+        per_expert = np.sum([np.asarray(r["experts"], np.float64)
+                             for r in reports], 0)
+        out["absent_share"] = float(moe[1] / max(moe[0], 1.0))
+        out["dropped_tokens"] = int(round(moe[2]))
+        out["expert_tokens"] = [int(c) for c in per_expert]
+        out["moe_imbalance"] = float(
+            per_expert.max() / max(per_expert.mean(), 1e-9))
+    return out
 
 
 def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
@@ -42,14 +111,19 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
         n_heads: int = 4, n_layers: int = 2, d_ff: int = 128,
         vocab: int = 0, train_steps: int = 300, batch: int = 1024,
         lr: float = 1e-3, causal: bool = False,
-        remat: bool = False) -> TrainedModel:
+        remat: bool = False,
+        arch: Optional[Dict[str, Any]] = None) -> TrainedModel:
     """Token-column design matrix → fitted transformer classifier.
 
     The feature columns ARE the sequence: column j holds token id at
     position j (the design matrix arrives float32; values cast back to
-    int). ``vocab=0`` infers the vocabulary from the data.
+    int). ``vocab=0`` infers the vocabulary from the data. ``arch``
+    switches the block's architecture options on (each key the
+    ``TxConfig`` field of that name; ``registry.HPARAM_SPECS`` lists them); its
+    sizes are a published model's and are never rounded to fit a mesh.
     """
     mesh = runtime.mesh
+    arch = dict(arch or {})
     tokens_all = np.maximum(np.asarray(X, np.float32), 0.0).astype(np.int32)
     n, T = tokens_all.shape
     if n == 0 or T == 0:
@@ -68,49 +142,78 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
     M = mesh.shape[MODEL_AXIS]
     T_pad = _round_up(T, S)
     if T_pad > T:
+        if arch.get("lm_head"):
+            raise ValueError(f"{T} token columns do not divide over a seq "
+                             f"axis of {S}, and a next-token loss cannot "
+                             "pad them")
         tokens_all = np.pad(tokens_all, ((0, 0), (0, T_pad - T)))
-    n_heads = _round_up(max(n_heads, 1), M)
+    if arch:
+        for key, size in (("n_heads", n_heads),
+                          ("n_kv_heads", arch.get("n_kv_heads", 0)),
+                          ("experts_held", arch.get("experts_held")
+                           or arch.get("n_experts", 0))):
+            if size % M:
+                raise ValueError(f"{key} {size} does not divide over a "
+                                 f"model axis of {M}")
+    else:
+        n_heads = _round_up(max(n_heads, 1), M)
+        d_model = _round_up(max(d_model, n_heads), n_heads)
     d_ff = _round_up(max(d_ff, 1), M)
-    d_model = _round_up(max(d_model, n_heads), n_heads)
     batch = min(_round_up(batch, Dax), _round_up(n, Dax))
 
     cfg = TxConfig(vocab=vocab, d_model=d_model, n_heads=n_heads,
                    n_layers=n_layers, d_ff=d_ff, n_classes=num_classes,
-                   max_len=T_pad, causal=causal, remat=remat)
-    params = shard_params(init_params(jax.random.PRNGKey(seed), cfg),
-                          cfg, mesh)
-    opt = optax.adam(lr)
-    opt_state = opt.init(params)   # zeros_like → inherits shardings
-    train_step = make_train_step(cfg, mesh, opt)
+                   max_len=T_pad, causal=causal, remat=remat, **arch)
+    init, step = _fit_programs(cfg, mesh, float(lr), batch)
+    key = jax.random.PRNGKey(seed)
+    with tracing.span("fit.tx.init"):
+        table = runtime.replicate(tokens_all)
+        labels = runtime.replicate(np.asarray(y, np.int32))
+        state = jax.block_until_ready(init(key))
 
-    tok_sharding = NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS))
-    lab_sharding = NamedSharding(mesh, P(DATA_AXIS))
-    y_all = np.asarray(y, np.int32)
-    rng = np.random.default_rng(seed)
     # XLA's CPU backend can abort/deadlock when collective programs
     # pipeline deeply (shared thunk pool — see viz/tsne.py's identical
     # mitigation), so the simulated-mesh rig serializes steps; TPU keeps
     # the async dispatch queue.
     sync_steps = jax.default_backend() == "cpu"
-    for _ in range(int(train_steps)):
-        sel = rng.integers(0, n, batch)
-        bt = jax.device_put(tokens_all[sel], tok_sharding)
-        bl = jax.device_put(y_all[sel], lab_sharding)
-        params, opt_state, _loss = train_step(params, opt_state, bt, bl)
-        if sync_steps:
-            jax.block_until_ready(_loss)
+    batch_key = jax.random.fold_in(key, 1 << 20)
+    attrs: Dict[str, Any] = {"steps": int(train_steps),
+                             "tokens": int(train_steps) * batch * T_pad}
+    with tracing.span("fit.tx.steps", attrs):
+        reports = []
+        for _ in range(int(train_steps)):
+            state, report = step(state, batch_key, table, labels)
+            reports.append(report)
+            if sync_steps:
+                jax.block_until_ready(report)
+        reports = jax.device_get(reports)    # the one wait of the fit
+        metrics = _fit_metrics(reports, cfg, batch * T_pad)
+        attrs.update(dropped_tokens=metrics.get("dropped_tokens", 0),
+                     loss_first=metrics["loss"][0],
+                     loss_last=metrics["loss"][-1],
+                     **{k: metrics[k] for k in _READINGS if k in metrics})
+    with _counters_lock:
+        _counters["fits"] += 1
+        _counters["steps"] += metrics["steps"]
+        _counters["tokens"] += metrics["tokens"]
+        _counters["dropped_tokens"] += metrics.get("dropped_tokens", 0)
+        _counters.update({k: metrics[k] for k in _READINGS + ("expert_tokens",)
+                          if k in metrics})
 
     # Replicate the fitted params: predict then runs the unsharded
     # forward under plain data parallelism on any topology, and
     # checkpointing stays a process-local numpy write (persistence.py).
-    params = jax.device_put(params, NamedSharding(mesh, P()))
+    params = jax.device_put(state[0], NamedSharding(mesh, P()))
     hp = {"vocab": vocab, "d_model": d_model, "n_heads": n_heads,
           "n_layers": n_layers, "d_ff": d_ff, "n_classes": num_classes,
           "max_len": T_pad, "causal": causal, "train_steps": train_steps,
-          "lr": lr}
+          "lr": lr, "seed": seed, "batch": batch}
+    if arch:
+        hp["arch"] = arch
     return TrainedModel(kind="tx", params=params,
                         predict_proba_fn=predictor(hp),
-                        num_classes=num_classes, hparams=hp)
+                        num_classes=num_classes, hparams=hp,
+                        fit_metrics=metrics)
 
 
 def predictor(hparams: dict):
@@ -122,19 +225,37 @@ def predictor(hparams: dict):
                    d_ff=int(hparams["d_ff"]),
                    n_classes=int(hparams["n_classes"]),
                    max_len=int(hparams["max_len"]),
-                   causal=bool(hparams.get("causal", False)))
+                   causal=bool(hparams.get("causal", False)),
+                   **(hparams.get("arch") or {}))
+    proba = _proba_program(cfg)
 
+    def timed(params, X):
+        with tracing.span("fit.tx.predict", rows=int(X.shape[0])):
+            return jax.block_until_ready(proba(params, X))
+
+    return timed
+
+
+@functools.lru_cache(maxsize=8)
+def _proba_program(cfg: TxConfig):
     @jax.jit
     def proba(params, X):
         tokens = jnp.clip(X.astype(jnp.int32), 0, cfg.vocab - 1)
         pad = cfg.max_len - tokens.shape[1]
-        if pad < 0:
+        if pad < 0 or (pad and cfg.lm_head):
             raise ValueError(
                 f"dataset has {tokens.shape[1]} token columns but the "
                 f"model was trained with max_len {cfg.max_len}")
         if pad:
             tokens = jnp.pad(tokens, ((0, 0), (0, pad)))
-        return jax.nn.softmax(
-            forward_reference(params, tokens, cfg=cfg), axis=-1)
+        if not has_options(cfg):
+            logits = forward_reference(params, tokens, cfg=cfg)
+        else:
+            # A block of rows at a time: a published-width forward of
+            # every test row at once does not fit beside the weights.
+            logits = jax.lax.map(
+                lambda row: forward_reference(params, row[None], cfg=cfg)[0],
+                tokens, batch_size=max(1, 8192 // cfg.max_len))
+        return jax.nn.softmax(logits, axis=-1)
 
     return proba

@@ -1,30 +1,59 @@
 """Sequence transformer — the long-context model family (dp × tp × sp).
 
-No reference behavior exists to match (the reference predates sequence
-models, SURVEY.md §5); this family exists because long-context and
-distributed execution are first-class in the rebuild. The training step is
-one SPMD program over the full 3-axis mesh (parallel/mesh.py):
+One block function with options. With every option off it is the seed's
+small classifier (LayerNorm, learned positions, fused multi-head
+attention through the ring, GELU MLP, mean-pool head). The options are
+what today's open language models are built from, each switched by its
+own ``TxConfig`` field, so a published architecture is a set of numbers
+and not a second model file:
 
-- ``data``  — batch rows sharded (the reference's only parallelism axis);
-- ``model`` — Megatron-style tensor parallelism: attention heads and the
-  FFN hidden dimension are column-split, output projections row-split with
-  one ``psum`` per block over ICI;
-- ``seq``   — context parallelism: sequence length is sharded and exact
-  attention runs as a ring of ``ppermute`` hops
-  (parallel/ring_attention.py), so max context scales linearly with the
-  seq-axis size.
+- ``rms_norm``: RMSNorm without bias instead of LayerNorm;
+- ``n_kv_heads``: grouped-query attention with separate q/k/v
+  projections of ``head_dim``, ``rope_theta`` rotary positions over the
+  whole head (rotate-half), ``qk_norm`` an RMSNorm per head on q and k;
+- ``indexer_heads``: learned sparse attention (DeepSeek-Sparse-Attention
+  as Keye-VL-2.0 configures it): a small indexer scores every earlier
+  position, a query attends to the ``indexer_topk`` best, and the
+  indexer learns from an alignment loss against the main attention's
+  own probabilities;
+- ``n_experts``: a routed expert layer that is TOLD WHICH EXPERTS IT
+  HOLDS (``experts_first``, ``experts_held``): it routes over all of
+  them and adds only its own experts' part, which is what one chip of
+  an expert-parallel deployment computes. No token is dropped;
+- ``lm_head``: a per-position next-token loss over an untied vocabulary
+  head, with the label-token readout that keeps the family a classifier
+  (class ``c`` is token id ``c``; a row's target at its last position is
+  its label token).
 
-Differentiation goes *through* ``shard_map`` (check_vma replication
-tracking makes the psum/ppermute transposes produce correctly-reduced
-gradients for replicated and sharded parameters alike), so the optimizer
-update is ordinary optax on sharded pytrees.
+The reference has no sequence models (SURVEY.md §5); the plain
+reference these options are held to is the benchmark's
+(``perfbench/reference_tx.py``, the published equations in float32).
+
+The training step is one SPMD program over the 3-axis mesh
+(parallel/mesh.py):
+
+- ``data``  — batch rows sharded;
+- ``model`` — Megatron-style tensor parallelism: attention heads, the
+  FFN hidden dimension and the held experts are split, output
+  projections reduce with one ``psum`` per block;
+- ``seq``   — context parallelism: exact attention as a ring of
+  ``ppermute`` hops (parallel/ring_attention.py). The ring rotates every
+  K/V block past every query, so a query that attends to chosen keys
+  cannot ride it: with the indexer on, a ``seq`` axis > 1 raises.
+
+Differentiation goes *through* ``shard_map``; the layers are stacked and
+scanned, so the program compiles one layer whatever the depth. Every
+array is float32 and every large product runs at the backend's default
+precision: on a TPU that is bfloat16 operands with float32 accumulation
+and float32 gradients, with no second copy of the weights in a lower
+type; only the router's small product asks for float32 operands.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, List
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +63,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS)
-from learningorchestra_tpu.parallel.ring_attention import ring_attention
+from learningorchestra_tpu.parallel.ring_attention import (
+    reference_attention, ring_attention)
 
 
 @dataclass(frozen=True)
@@ -48,71 +78,424 @@ class TxConfig:
     max_len: int = 1024
     causal: bool = False          # classifier default; True for LM-style
     #: Rematerialize each layer's activations in the backward pass
-    #: (jax.checkpoint) — trades ~30% step time for O(1)-in-depth live
-    #: activation memory, the standard long-context lever (32k tokens on
-    #: one 16 GB chip needs it).
+    #: (jax.checkpoint): O(1)-in-depth live activation memory for one
+    #: more forward pass a step (its cost on the chip: not measured
+    #: alone; the 8k-token cell cannot run without it).
     remat: bool = False
+    # --- the architecture options (all off: the block above) -------------
+    rms_norm: bool = False
+    norm_eps: float = 1e-5
+    n_kv_heads: int = 0           # > 0: grouped-query attention
+    head_dim: int = 0             # 0: d_model // n_heads
+    rope_theta: float = 0.0       # > 0: rotary positions, no learned table
+    qk_norm: bool = False
+    indexer_heads: int = 0        # > 0: learned sparse attention
+    indexer_head_dim: int = 64
+    indexer_topk: int = 2048
+    q_chunk: int = 512            # queries scored at a time (tiling only)
+    n_experts: int = 0            # > 0: routed experts instead of the MLP
+    experts_per_token: int = 8
+    expert_width: int = 768
+    experts_first: int = 0        # the experts this holder was told it has
+    experts_held: int = 0         # 0: all of them
+    norm_topk_prob: bool = True
+    lm_head: bool = False
+    token_chunk: int = 1024       # positions an expert / head pass holds
+    init_std: float = 0.02        # the options' init (normal, this std)
+
+    def __post_init__(self):
+        if (self.rope_theta or self.qk_norm or self.indexer_heads) \
+                and not self.n_kv_heads:
+            raise ValueError("rope_theta, qk_norm and indexer_heads are "
+                             "options of grouped-query attention: set "
+                             "n_kv_heads")
+        if self.n_kv_heads and self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_heads {self.n_heads} is not a multiple of "
+                             f"n_kv_heads {self.n_kv_heads}")
+        if self.indexer_heads and not self.causal:
+            raise ValueError("the indexer scores earlier positions: it "
+                             "needs causal attention")
+        if self.n_experts:
+            held = self.experts_held or self.n_experts
+            if not 0 <= self.experts_first <= self.n_experts - held:
+                raise ValueError(
+                    f"experts [{self.experts_first}, "
+                    f"{self.experts_first + held}) are not among the "
+                    f"{self.n_experts} routed experts")
+            if not 1 <= self.experts_per_token <= self.n_experts:
+                raise ValueError("experts_per_token must lie in "
+                                 f"[1, {self.n_experts}]")
+        if self.lm_head and not self.causal:
+            raise ValueError("a next-token loss needs causal attention")
+        if self.lm_head and self.n_classes > self.vocab:
+            raise ValueError(f"{self.n_classes} label tokens do not fit a "
+                             f"vocabulary of {self.vocab}")
+
+    @property
+    def hd(self) -> int:
+        return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+
+class Axes(NamedTuple):
+    """The mesh axis names a forward pass reduces over; ``None``: that
+    axis does not exist (the unsharded forward of predict and tests)."""
+    data: Optional[str] = None
+    model: Optional[str] = None
+    seq: Optional[str] = None
+
+
+MESH_AXES = Axes(DATA_AXIS, MODEL_AXIS, SEQ_AXIS)
+NO_AXES = Axes()
+
+#: Gradient-norm groups of a step's report: leaf name → group. The
+#: benchmark's comparison reads them by these names.
+GRAD_GROUPS = {
+    "embed": "embedding", "pos": "embedding",
+    "head_w": "head", "head_b": "head", "lnf_g": "head",
+    "ln1_g": "attention", "ln1_b": "attention", "wqkv": "attention",
+    "wq": "attention", "wk": "attention", "wv": "attention",
+    "wo": "attention", "q_norm": "attention", "k_norm": "attention",
+    "ix_wq": "indexer", "ix_wk": "indexer", "ix_kn_g": "indexer",
+    "ix_kn_b": "indexer", "ix_ww": "indexer",
+    "router": "router",
+    "ln2_g": "experts", "ln2_b": "experts", "we_gate": "experts",
+    "we_up": "experts", "we_down": "experts",
+    "w1": "experts", "b1": "experts", "w2": "experts", "b2": "experts",
+}
+
+
+def _psum(x, axis):
+    return x if axis is None else jax.lax.psum(x, axis)
+
+
+def _axis_size(axis) -> int:
+    return 1 if axis is None else jax.lax.psum(1, axis)
+
+
+def _axis_index(axis):
+    return 0 if axis is None else jax.lax.axis_index(axis)
+
+
+# --- parameters -------------------------------------------------------------
+
+def _leaf_shapes(cfg: TxConfig) -> Dict[str, Any]:
+    """``{name: (shape, init)}`` top-level and ``{"layers": {...}}`` with
+    the layer axis leading; ``init`` is "normal", "ones" or "zeros"."""
+    L, d, H, hd = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.hd
+    top = {"embed": ((cfg.vocab, d), "normal")}
+    if not cfg.rope_theta:
+        top["pos"] = ((cfg.max_len, d), "normal")
+    if cfg.lm_head:
+        top["lnf_g"] = ((d,), "ones")
+        top["head_w"] = ((d, cfg.vocab), "normal")
+    else:
+        top["head_w"] = ((d, cfg.n_classes), "normal")
+        top["head_b"] = ((cfg.n_classes,), "zeros")
+    lay = {"ln1_g": ((L, d), "ones"), "ln2_g": ((L, d), "ones")}
+    if not cfg.rms_norm:
+        lay["ln1_b"] = ((L, d), "zeros")
+        lay["ln2_b"] = ((L, d), "zeros")
+    if cfg.n_kv_heads:
+        G = cfg.n_kv_heads
+        lay.update(wq=((L, d, H, hd), "normal"), wk=((L, d, G, hd), "normal"),
+                   wv=((L, d, G, hd), "normal"), wo=((L, H, hd, d), "normal"))
+        if cfg.qk_norm:
+            lay.update(q_norm=((L, hd), "ones"), k_norm=((L, hd), "ones"))
+    else:
+        lay.update(wqkv=((L, d, 3, H, hd), "normal"),
+                   wo=((L, H, hd, d), "normal"))
+    if cfg.indexer_heads:
+        Hi, di = cfg.indexer_heads, cfg.indexer_head_dim
+        lay.update(ix_wq=((L, d, Hi, di), "normal"),
+                   ix_wk=((L, d, di), "normal"),
+                   ix_kn_g=((L, di), "ones"), ix_kn_b=((L, di), "zeros"),
+                   ix_ww=((L, d, Hi), "normal"))
+    if cfg.n_experts:
+        E, f = cfg.held, cfg.expert_width
+        lay.update(router=((L, d, cfg.n_experts), "normal"),
+                   we_gate=((L, E, d, f), "normal"),
+                   we_up=((L, E, d, f), "normal"),
+                   we_down=((L, E, f, d), "normal"))
+    else:
+        lay.update(w1=((L, d, cfg.d_ff), "normal"), b1=((L, cfg.d_ff), "zeros"),
+                   w2=((L, cfg.d_ff, d), "normal"), b2=((L, d), "zeros"))
+    return dict(top, layers=lay)
+
+
+def has_options(cfg: TxConfig) -> bool:
+    """Any architecture option on. The leaves then draw ``normal *
+    init_std``, leaf ``i`` in sorted-name order from ``fold_in(key, i)``
+    (a recipe a second implementation can follow without this file:
+    perfbench's does), and predict takes a block of rows at a time."""
+    return bool(cfg.rms_norm or cfg.n_kv_heads or cfg.n_experts
+                or cfg.lm_head)
+
+
+def leaf_order(cfg: TxConfig) -> list:
+    """Leaf paths in the order the init recipe numbers them."""
+    shapes = _leaf_shapes(cfg)
+    top = sorted(k for k in shapes if k != "layers")
+    return top + [f"layers.{k}" for k in sorted(shapes["layers"])]
 
 
 def init_params(key, cfg: TxConfig) -> Dict[str, Any]:
-    hd = cfg.d_model // cfg.n_heads
-    keys = iter(jax.random.split(key, 4 + 6 * cfg.n_layers))
+    shapes = _leaf_shapes(cfg)
+    arch = has_options(cfg)
 
-    def dense(k, *shape, scale=None):
-        scale = scale or 1.0 / np.sqrt(shape[0])
-        return (jax.random.normal(k, shape, jnp.float32) * scale)
+    def make(i, name, shape, init):
+        if init != "normal":
+            return (jnp.ones if init == "ones" else jnp.zeros)(
+                shape, jnp.float32)
+        if arch:
+            scale = cfg.init_std
+        elif name in ("embed", "pos"):
+            scale = 0.02
+        elif name == "wo":
+            scale = 1.0 / np.sqrt(cfg.d_model)
+        else:   # fan-in of the seed's block: the first non-layer dim
+            scale = 1.0 / np.sqrt(shape[1] if name in (
+                "wqkv", "w1", "w2") else shape[0])
+        return jax.random.normal(jax.random.fold_in(key, i), shape,
+                                 jnp.float32) * scale
 
-    params: Dict[str, Any] = {
-        "embed": dense(next(keys), cfg.vocab, cfg.d_model, scale=0.02),
-        "pos": dense(next(keys), cfg.max_len, cfg.d_model, scale=0.02),
-        "head_w": dense(next(keys), cfg.d_model, cfg.n_classes),
-        "head_b": jnp.zeros(cfg.n_classes),
-        "layers": [],
-    }
-    for _ in range(cfg.n_layers):
-        params["layers"].append({
-            "ln1_g": jnp.ones(cfg.d_model), "ln1_b": jnp.zeros(cfg.d_model),
-            "wqkv": dense(next(keys), cfg.d_model, 3, cfg.n_heads, hd),
-            "wo": dense(next(keys), cfg.n_heads, hd, cfg.d_model,
-                        scale=1.0 / np.sqrt(cfg.d_model)),
-            "ln2_g": jnp.ones(cfg.d_model), "ln2_b": jnp.zeros(cfg.d_model),
-            "w1": dense(next(keys), cfg.d_model, cfg.d_ff),
-            "b1": jnp.zeros(cfg.d_ff),
-            "w2": dense(next(keys), cfg.d_ff, cfg.d_model),
-            "b2": jnp.zeros(cfg.d_model),
-        })
+    params: Dict[str, Any] = {"layers": {}}
+    for i, path in enumerate(leaf_order(cfg)):
+        if path.startswith("layers."):
+            name = path[len("layers."):]
+            params["layers"][name] = make(i, name, *shapes["layers"][name])
+        else:
+            params[path] = make(i, path, *shapes[path])
     return params
 
 
 def param_specs(cfg: TxConfig) -> Dict[str, Any]:
-    """PartitionSpec per leaf: heads / FFN hidden on the model axis, the
-    rest replicated (small embeddings; sharding them buys nothing here)."""
-    layer = {
-        "ln1_g": P(), "ln1_b": P(),
-        "wqkv": P(None, None, MODEL_AXIS, None),
-        "wo": P(MODEL_AXIS, None, None),
-        "ln2_g": P(), "ln2_b": P(),
-        "w1": P(None, MODEL_AXIS), "b1": P(MODEL_AXIS),
-        "w2": P(MODEL_AXIS, None), "b2": P(),
-    }
-    return {"embed": P(), "pos": P(), "head_w": P(), "head_b": P(),
-            "layers": [dict(layer) for _ in range(cfg.n_layers)]}
+    """PartitionSpec per leaf: heads, FFN hidden and held experts on the
+    model axis, the rest replicated (layer axis first, never split)."""
+    M = MODEL_AXIS
+    split = {"wqkv": P(None, None, None, M, None), "wo": P(None, M, None, None),
+             "wq": P(None, None, M, None), "wk": P(None, None, M, None),
+             "wv": P(None, None, M, None),
+             "w1": P(None, None, M), "b1": P(None, M), "w2": P(None, M, None),
+             "we_gate": P(None, M, None, None), "we_up": P(None, M, None, None),
+             "we_down": P(None, M, None, None)}
+    shapes = _leaf_shapes(cfg)
+    specs = {k: P() for k in shapes if k != "layers"}
+    specs["layers"] = {k: split.get(k, P()) for k in shapes["layers"]}
+    return specs
 
 
-def _ln(x, g, b, eps=1e-5):
+# --- the block's parts ------------------------------------------------------
+
+def _rms(x, g, eps: float):
+    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+def _norm(cfg: TxConfig, x, g, b=None):
+    if cfg.rms_norm:
+        return _rms(x, g, cfg.norm_eps)
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
+    return (x - mu) * jax.lax.rsqrt(var + cfg.norm_eps) * g + b
 
 
-def forward_shard(params, tokens, *, cfg: TxConfig):
-    """Per-shard forward (runs inside shard_map over the 3-axis mesh).
+def _rope(x, pos, theta: float):
+    """Rotate-half rotary embedding over the whole last dim of
+    x (B, T, heads, D) or (B, T, D), positions ``pos`` (T,)."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (T, half)
+    if x.ndim == 4:
+        ang = ang[:, None, :]
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
-    tokens: (B_local, T_local) int32 → logits (B_local, n_classes),
-    replicated over model and seq axes.
-    """
-    seq_idx = jax.lax.axis_index(SEQ_AXIS)
-    seq_size = jax.lax.psum(1, SEQ_AXIS)
+
+def _select_topk(scores, k: int):
+    """``scores`` (C, T) float32, ``-inf`` where not allowed. The mask of
+    each row's ``k`` largest (all of a row with fewer; ties at the k-th
+    value all kept): the k-th largest is found exactly, by bisection
+    over the floats' ordered bit patterns, 32 counting passes."""
+    x = scores + 0.0                                  # -0.0 -> +0.0
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    ukey = jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+    def body(b, ans):
+        cand = ans | (jnp.uint32(1) << (jnp.uint32(31) - b.astype(jnp.uint32)))
+        enough = (ukey >= cand[:, None]).sum(-1) >= k
+        return jnp.where(enough, cand, ans)
+
+    ans = jax.lax.fori_loop(0, 32, body, ukey[:, 0] * jnp.uint32(0))
+    return ukey >= ans[:, None]
+
+
+def _chosen_attention(cfg: TxConfig, ax: Axes, q, k, v, ix):
+    """Causal grouped-query attention of ONE row over the keys the
+    indexer chose (every earlier key where there is no indexer), a chunk
+    of queries at a time; the chunk body is rematerialised, so a
+    (heads, chunk, T) score block lives once.
+
+    q (T, H, D); k, v (T, G, D); ``ix``: ``None`` or the indexer's
+    ``(qI (T, Hi, Di), kI (T, Di), w (T, Hi))``. Returns ``(o (T, H, D),
+    stats)``: ``stats`` = [index loss summed over queries, keys kept
+    summed, queries with fewer keys than top-k]."""
+    T, H, D = q.shape
+    G = k.shape[1]
+    R = H // G
+    C = next(c for c in range(min(cfg.q_chunk, T), 0, -1) if T % c == 0)
+    kpos = jnp.arange(T)
+    heads_all = H * _axis_size(ax.model)
+
+    def chunk(i):
+        q_c = jax.lax.dynamic_slice_in_dim(q, i * C, C, 0)
+        allowed = kpos[None, :] <= (i * C + jnp.arange(C))[:, None]
+        if ix is not None:
+            qi_c = jax.lax.dynamic_slice_in_dim(ix[0], i * C, C, 0)
+            w_c = jax.lax.dynamic_slice_in_dim(ix[2], i * C, C, 0)
+            sc = jnp.einsum("qjd,kd->qjk", qi_c, ix[1])
+            score = (jax.nn.relu(sc) * w_c[:, :, None]).sum(1) * (
+                cfg.indexer_head_dim ** -0.5 * cfg.indexer_heads ** -0.5)
+            chosen = allowed & _select_topk(
+                jnp.where(allowed, jax.lax.stop_gradient(score), -jnp.inf),
+                cfg.indexer_topk)
+        else:
+            chosen = allowed
+        s = jnp.einsum("qgrd,kgd->grqk", q_c.reshape(C, G, R, D), k) * D ** -0.5
+        p = jax.nn.softmax(jnp.where(chosen, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("grqk,kgd->qgrd", p, v).reshape(C, H, D)
+        kept = chosen.sum(-1)
+        stats = jnp.stack([
+            kept.sum().astype(jnp.float32),
+            (kept < cfg.indexer_topk).sum().astype(jnp.float32)])
+        if ix is None:
+            return o, jnp.concatenate([jnp.zeros(1), stats])
+        # The alignment loss: KL(main attention's head-summed
+        # probabilities over the chosen keys, L1-normalised, detached ||
+        # the indexer's softmax over the same keys).
+        target = _psum(jax.lax.stop_gradient(p).sum((0, 1)),
+                       ax.model) / heads_all
+        logq = jax.nn.log_softmax(jnp.where(chosen, score, -jnp.inf), -1)
+        kl = jnp.where(target > 0, target * (
+            jnp.log(jnp.where(target > 0, target, 1.0)) - jnp.where(
+                chosen, logq, 0.0)), 0.0).sum()
+        return o, jnp.concatenate([kl[None], stats])
+
+    o, stats = jax.lax.map(jax.checkpoint(chunk), jnp.arange(T // C))
+    return o.reshape(T, H, D), stats.sum(0)
+
+
+def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
+    """The block's attention half on the normed input ``h`` (B, T, d):
+    ``(out (B, T, d) before the model-axis reduce, stats (3,))``."""
+    if not cfg.n_kv_heads:
+        qkv = jnp.einsum("btd,dkhe->btkhe", h, lyr["wqkv"])
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        attn = (ring_attention(q, k, v, axis_name=ax.seq, causal=cfg.causal)
+                if ax.seq is not None else
+                reference_attention(q, k, v, causal=cfg.causal))
+        return jnp.einsum("bthe,hed->btd", attn, lyr["wo"]), jnp.zeros(3)
+    q = jnp.einsum("btd,dhe->bthe", h, lyr["wq"])
+    k = jnp.einsum("btd,dge->btge", h, lyr["wk"])
+    v = jnp.einsum("btd,dge->btge", h, lyr["wv"])
+    if cfg.qk_norm:
+        q = _rms(q, lyr["q_norm"], cfg.norm_eps)
+        k = _rms(k, lyr["k_norm"], cfg.norm_eps)
+    if cfg.rope_theta:
+        q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
+    rep = cfg.n_heads // cfg.n_kv_heads
+    if cfg.indexer_heads or (ax.seq is None and cfg.causal):
+        ix = None
+        if cfg.indexer_heads:
+            # The indexer reads the block's input detached: only its own
+            # alignment loss trains it, and that loss trains nothing else.
+            hi = jax.lax.stop_gradient(h)
+            qi = jnp.einsum("btd,dje->btje", hi, lyr["ix_wq"])
+            ki = jnp.einsum("btd,de->bte", hi, lyr["ix_wk"])
+            mu = ki.mean(-1, keepdims=True)
+            ki = (ki - mu) * jax.lax.rsqrt(
+                ((ki - mu) ** 2).mean(-1, keepdims=True) + cfg.norm_eps
+            ) * lyr["ix_kn_g"] + lyr["ix_kn_b"]
+            if cfg.rope_theta:
+                qi, ki = (_rope(qi, pos, cfg.rope_theta),
+                          _rope(ki, pos, cfg.rope_theta))
+            ix = (qi, ki, jnp.einsum("btd,dj->btj", hi, lyr["ix_ww"]))
+
+        def row(args):
+            return _chosen_attention(cfg, ax, args[0], args[1], args[2],
+                                     None if ix is None else args[3:])
+
+        o, stats = jax.lax.map(row, (q, k, v) + (ix or ()))
+        stats = stats.sum(0)
+    else:
+        # Dense GQA: each key/value head repeated for its group of query
+        # heads; over a sharded sequence, the ring.
+        k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+        o = (reference_attention(q, k, v, causal=False) if ax.seq is None
+             else ring_attention(q, k, v, axis_name=ax.seq,
+                                 causal=cfg.causal))
+        stats = jnp.zeros(3)
+    return jnp.einsum("bthe,hed->btd", o, lyr["wo"]), stats
+
+
+def _experts(cfg: TxConfig, ax: Axes, h, lyr):
+    """The routed expert layer on the normed input ``h`` (B, T, d). Routes
+    over all ``n_experts`` (softmax, top-k, renormalised), and computes
+    the part of the result that the experts held HERE give, for every
+    token routed to them: the held experts run as one wide gated FFN
+    whose hidden blocks are scaled by the token's gate for that expert
+    (0 where it was not routed), so no capacity exists and no token can
+    be dropped. Returns ``(out before the model-axis reduce, counts
+    (held_local,) assignments per held expert, [assignments routed,
+    assignments to absent experts, dropped])``."""
+    B, T, d = h.shape
+    x = h.reshape(B * T, d)
+    logits = jnp.einsum("nd,de->ne", x, lyr["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    top_g, top_e = jax.lax.top_k(jax.nn.softmax(logits, -1),
+                                 cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_g = top_g / top_g.sum(-1, keepdims=True)
+    e_loc = lyr["we_gate"].shape[0]           # this model shard's experts
+    ids = cfg.experts_first + _axis_index(ax.model) * e_loc + jnp.arange(e_loc)
+    hit = top_e[:, :, None] == ids[None, None, :]              # (N, K, e)
+    gate = (hit * top_g[:, :, None]).sum(1)                    # (N, e)
+    N = B * T
+    C = next(c for c in range(min(cfg.token_chunk, N), 0, -1) if N % c == 0)
+
+    def part(args):
+        xc, gc = args
+        a = jnp.einsum("nd,edf->nef", xc, lyr["we_gate"])
+        b = jnp.einsum("nd,edf->nef", xc, lyr["we_up"])
+        return jnp.einsum("nef,efd->nd", jax.nn.silu(a) * b * gc[:, :, None],
+                   lyr["we_down"])
+
+    out = jax.lax.map(jax.checkpoint(part), (
+        x.reshape(N // C, C, d), gate.reshape(N // C, C, e_loc)))
+    counts = hit.sum((0, 1)).astype(jnp.float32)
+    here = _psum(counts.sum(), ax.model)
+    routed = jnp.float32(B * T * cfg.experts_per_token)
+    applied = _psum((gate > 0).sum().astype(jnp.float32), ax.model)
+    return out.reshape(B, T, d), counts, jnp.stack(
+        [routed, routed - here, here - applied])
+
+
+def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
+    """Embedding and the stacked blocks. tokens (B, T_local) int32 →
+    ``(x (B, T_local, d), aux)``; ``aux``: ``attn`` (3,) [index loss
+    summed over queries and layers, keys kept, short queries], ``moe``
+    (3,) [routed, absent, dropped] and ``experts`` (held_local,) counts,
+    all summed over layers and over this shard's rows."""
+    seq_size = _axis_size(ax.seq)
+    if cfg.indexer_heads and seq_size > 1:
+        raise ValueError(
+            f"the indexer's top-{cfg.indexer_topk} selection does not run "
+            f"across a sequence axis of {seq_size}: keys chosen per query "
+            "cannot ride the ring; use a mesh whose seq axis is 1")
     Tl = tokens.shape[1]
     if Tl * seq_size > cfg.max_len:
         # Caught at trace time (both values static): an out-of-range
@@ -120,48 +503,121 @@ def forward_shard(params, tokens, *, cfg: TxConfig):
         raise ValueError(
             f"sequence length {Tl * seq_size} exceeds max_len "
             f"{cfg.max_len}")
-    pos = seq_idx * Tl + jnp.arange(Tl)
-    x = params["embed"][tokens] + params["pos"][pos][None, :, :]
+    pos = _axis_index(ax.seq) * Tl + jnp.arange(Tl)
+    x = params["embed"][tokens]
+    if not cfg.rope_theta:
+        x = x + params["pos"][pos][None, :, :]
 
     def layer_fn(x, lyr):
-        # --- attention: heads column-split (tp), ring over seq (sp) -------
-        h = _ln(x, lyr["ln1_g"], lyr["ln1_b"])
-        qkv = jnp.einsum("btd,dkhe->btkhe", h, lyr["wqkv"])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        attn = ring_attention(q, k, v, axis_name=SEQ_AXIS,
-                              causal=cfg.causal)
-        out = jnp.einsum("bthe,hed->btd", attn, lyr["wo"])
-        x = x + jax.lax.psum(out, MODEL_AXIS)      # row-parallel reduce
-        # --- FFN: hidden dim column-split (tp) ----------------------------
-        h = _ln(x, lyr["ln2_g"], lyr["ln2_b"])
-        ff = jax.nn.gelu(h @ lyr["w1"] + lyr["b1"])
-        return x + jax.lax.psum(ff @ lyr["w2"], MODEL_AXIS) + lyr["b2"]
+        h = _norm(cfg, x, lyr["ln1_g"], lyr.get("ln1_b"))
+        out, attn = _attention(cfg, ax, h, lyr, pos)
+        x = x + _psum(out, ax.model)               # row-parallel reduce
+        h = _norm(cfg, x, lyr["ln2_g"], lyr.get("ln2_b"))
+        if cfg.n_experts:
+            out, counts, moe = _experts(cfg, ax, h, lyr)
+            x = x + _psum(out, ax.model)
+        else:
+            ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lyr["w1"])
+                             + lyr["b1"])
+            x = x + _psum(jnp.einsum("btf,fd->btd", ff, lyr["w2"]),
+                          ax.model) + lyr["b2"]
+            counts, moe = jnp.zeros(1), jnp.zeros(3)
+        return x, {"attn": attn, "moe": moe, "experts": counts}
 
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn)
-    for lyr in params["layers"]:
-        x = layer_fn(x, lyr)
-
-    # Mean-pool over the (sharded) sequence, then classify.
-    pool = jax.lax.psum(x.sum(axis=1), SEQ_AXIS) / (Tl * seq_size)
-    return pool @ params["head_w"] + params["head_b"]
+    x, aux = jax.lax.scan(layer_fn, x, params["layers"])
+    return x, jax.tree.map(lambda a: a.sum(0), aux)
 
 
-def make_loss_fn(cfg: TxConfig, mesh: Mesh):
+def _class_logits(params, x, cfg: TxConfig, ax: Axes):
+    """(B, n_classes) from the trunk's output: the mean-pool head, or
+    with ``lm_head`` the last position's logits over the label tokens."""
+    if not cfg.lm_head:
+        pool = _psum(x.sum(axis=1), ax.seq) / (x.shape[1] * _axis_size(ax.seq))
+        return pool @ params["head_w"] + params["head_b"]
+    last = _norm(cfg, x[:, -1], params["lnf_g"])
+    logits = jnp.einsum("bd,dc->bc", last, params["head_w"][:, :cfg.n_classes])
+    if ax.seq is None:
+        return logits
+    # The row's last position lives on the last sequence shard.
+    mine = _axis_index(ax.seq) == _axis_size(ax.seq) - 1
+    return _psum(jnp.where(mine, logits, 0.0), ax.seq)
+
+
+def forward_shard(params, tokens, *, cfg: TxConfig):
+    """Per-shard forward (runs inside shard_map over the 3-axis mesh).
+
+    tokens: (B_local, T_local) int32 → class logits (B_local, n_classes),
+    replicated over model and seq axes."""
+    x, _ = _trunk(params, tokens, cfg, MESH_AXES)
+    return _class_logits(params, x, cfg, MESH_AXES)
+
+
+def _next_token_loss(params, x, tokens, labels, cfg: TxConfig, ax: Axes):
+    """Summed next-token cross-entropy of this shard's positions: the
+    target of position t is token t+1, of a row's last position its
+    label token. Logits a ``token_chunk`` of positions at a time."""
+    B, Tl, d = x.shape
+    nxt = labels[:, None]
+    if ax.seq is not None and _axis_size(ax.seq) > 1:
+        S = _axis_size(ax.seq)
+        first = jax.lax.ppermute(tokens[:, :1], ax.seq,
+                                 [(j, (j - 1) % S) for j in range(S)])
+        nxt = jnp.where(_axis_index(ax.seq) == S - 1, nxt, first)
+    targets = jnp.concatenate([tokens[:, 1:], nxt], axis=1).reshape(B * Tl)
+    h = _norm(cfg, x, params["lnf_g"]).reshape(B * Tl, d)
+    N = B * Tl
+    C = next(c for c in range(min(cfg.token_chunk, N), 0, -1) if N % c == 0)
+
+    def chunk(args):
+        hc, tc = args
+        logp = jax.nn.log_softmax(jnp.einsum("nd,dv->nv", hc,
+                                      params["head_w"]), -1)
+        return -jnp.take_along_axis(logp, tc[:, None], axis=1).sum()
+
+    return jax.lax.map(jax.checkpoint(chunk), (
+        h.reshape(N // C, C, d), targets.reshape(N // C, C))).sum()
+
+
+def make_loss_fn(cfg: TxConfig, mesh: Mesh, with_aux: bool = False):
+    """``loss(params, tokens, labels)``; with ``with_aux`` it returns
+    ``(loss, aux)``: ``aux`` has the loss's parts and the step's counters
+    (see ``make_fit_step``)."""
     specs = param_specs(cfg)
+    ax = MESH_AXES
 
     def shard_fn(params, tokens, labels):
-        logits = forward_shard(params, tokens, cfg=cfg)
-        logp = jax.nn.log_softmax(logits)
-        local = -jnp.take_along_axis(logp, labels[:, None], axis=1).sum()
-        n = jax.lax.psum(jnp.float32(labels.shape[0]), DATA_AXIS)
-        return jax.lax.psum(local, DATA_AXIS) / n
+        x, aux = _trunk(params, tokens, cfg, ax)
+        rows = jax.lax.psum(jnp.float32(labels.shape[0]), DATA_AXIS)
+        if cfg.lm_head:
+            n_pos = rows * tokens.shape[1] * _axis_size(ax.seq)
+            local = _next_token_loss(params, x, tokens, labels, cfg, ax)
+            main = jax.lax.psum(local, (DATA_AXIS, SEQ_AXIS)) / n_pos
+        else:
+            n_pos = rows * tokens.shape[1] * _axis_size(ax.seq)
+            logp = jax.nn.log_softmax(_class_logits(params, x, cfg, ax))
+            local = -jnp.take_along_axis(logp, labels[:, None], axis=1).sum()
+            main = jax.lax.psum(local, DATA_AXIS) / rows
+        both = (DATA_AXIS, SEQ_AXIS)
+        attn = jax.lax.psum(aux["attn"], both)
+        index = attn[0] / n_pos
+        out = {"loss_main": main, "loss_index": index,
+               "keys_kept": attn[1], "queries_short": attn[2],
+               "moe": jax.lax.psum(aux["moe"], both),
+               "experts": jax.lax.psum(aux["experts"], both)}
+        return main + index, out
+
+    aux_specs = {"loss_main": P(), "loss_index": P(), "keys_kept": P(),
+                 "queries_short": P(), "moe": P(),
+                 "experts": P(MODEL_AXIS) if cfg.n_experts else P()}
 
     def loss_fn(params, tokens, labels):
-        return jax.shard_map(
+        loss, aux = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(specs, P(DATA_AXIS, SEQ_AXIS), P(DATA_AXIS)),
-            out_specs=P())(params, tokens, labels)
+            out_specs=(P(), aux_specs))(params, tokens, labels)
+        return (loss, aux) if with_aux else loss
 
     return loss_fn
 
@@ -178,6 +634,57 @@ def make_train_step(cfg: TxConfig, mesh: Mesh, opt: optax.GradientTransformation
     return train_step
 
 
+def group_norms(grads) -> Dict[str, Any]:
+    """L2 norm of the gradient per ``GRAD_GROUPS`` group."""
+    sq: Dict[str, Any] = {}
+    for name, g in list(grads["layers"].items()) + [
+            (k, v) for k, v in grads.items() if k != "layers"]:
+        grp = GRAD_GROUPS[name]
+        sq[grp] = sq.get(grp, 0.0) + jnp.sum(jnp.square(g))
+    return {k: jnp.sqrt(v) for k, v in sq.items()}
+
+
+def make_fit_programs(cfg: TxConfig, mesh: Mesh,
+                      opt: optax.GradientTransformation, batch: int):
+    """A fit's two programs, ``(init, step)``, which the host only
+    enqueues. ``init(key) -> state`` makes the weights on the mesh, each
+    leaf where ``param_specs`` puts it (no host copy exists), with the
+    optimizer's state: ``state = (params, opt_state, t)``.
+    ``step(state, key, table, labels) -> (state, report)`` is the whole
+    of a training step; its batch is drawn ON THE DEVICE: step ``t``
+    takes rows ``randint(fold_in(key, t), (batch,), 0, n)`` of the
+    resident token table. ``report`` stays on the device until the fit
+    fetches every step's at once: the loss's parts, the gradient norm
+    per group, and the step's counters."""
+    loss_fn = make_loss_fn(cfg, mesh, with_aux=True)
+    tok_sharding = NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS))
+    lab_sharding = NamedSharding(mesh, P(DATA_AXIS))
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             param_specs(cfg),
+                             is_leaf=lambda x: isinstance(x, P))
+
+    @jax.jit
+    def init(key):
+        params = jax.lax.with_sharding_constraint(
+            init_params(key, cfg), shardings)
+        return params, opt.init(params), jnp.zeros((), jnp.int32)
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def step(state, key, table, labels):
+        params, opt_state, t = state
+        sel = jax.random.randint(jax.random.fold_in(key, t), (batch,), 0,
+                                 table.shape[0])
+        tokens = jax.lax.with_sharding_constraint(table[sel], tok_sharding)
+        labs = jax.lax.with_sharding_constraint(labels[sel], lab_sharding)
+        (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, tokens, labs)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        report = dict(aux, grad_norm=group_norms(grads))
+        return (optax.apply_updates(params, updates), opt_state, t + 1), report
+
+    return init, step
+
+
 def shard_params(params, cfg: TxConfig, mesh: Mesh):
     """Place a host/param pytree on the mesh per param_specs."""
     specs = param_specs(cfg)
@@ -186,26 +693,11 @@ def shard_params(params, cfg: TxConfig, mesh: Mesh):
         params, specs, is_leaf=lambda x: isinstance(x, P))
 
 
-# --- single-device numerics oracle (tests) --------------------------------
+# --- the unsharded forward (predict on any topology; tests) -----------------
 
 @partial(jax.jit, static_argnames=("cfg",))
 def forward_reference(params, tokens, *, cfg: TxConfig):
-    """Unsharded forward: same math, no mesh — must match forward_shard."""
-    from learningorchestra_tpu.parallel.ring_attention import (
-        reference_attention)
-
-    Tl = tokens.shape[1]
-    if Tl > cfg.max_len:
-        raise ValueError(f"sequence length {Tl} exceeds max_len "
-                         f"{cfg.max_len}")
-    x = params["embed"][tokens] + params["pos"][jnp.arange(Tl)][None]
-    for lyr in params["layers"]:
-        h = _ln(x, lyr["ln1_g"], lyr["ln1_b"])
-        qkv = jnp.einsum("btd,dkhe->btkhe", h, lyr["wqkv"])
-        attn = reference_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                                   causal=cfg.causal)
-        x = x + jnp.einsum("bthe,hed->btd", attn, lyr["wo"])
-        h = _ln(x, lyr["ln2_g"], lyr["ln2_b"])
-        x = x + jax.nn.gelu(h @ lyr["w1"] + lyr["b1"]) @ lyr["w2"] + lyr["b2"]
-    pool = x.mean(axis=1)
-    return pool @ params["head_w"] + params["head_b"]
+    """Unsharded forward: the same block with no mesh axis — must match
+    forward_shard. (B, T) int32 → class logits (B, n_classes)."""
+    x, _ = _trunk(params, tokens, cfg, NO_AXES)
+    return _class_logits(params, x, cfg, NO_AXES)

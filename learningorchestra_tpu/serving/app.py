@@ -811,6 +811,7 @@ class App:
         from learningorchestra_tpu import jobs as jobs_module
         from learningorchestra_tpu.catalog import ingest as ingest_module
         from learningorchestra_tpu.catalog import readpipe
+        from learningorchestra_tpu.models import sequence as sequence_module
         from learningorchestra_tpu.models import tune as tune_module
         from learningorchestra_tpu.utils import fitckpt
         from learningorchestra_tpu.utils.profiling import op_timer
@@ -832,6 +833,9 @@ class App:
                # spills (rendered as lo_tune_* on the exposition
                # surface).
                "tune": tune_module.counters_snapshot(),
+               # The sequence family's fits: totals, and the last fit's
+               # routing and key-selection readings (lo_tx_*).
+               "tx": sequence_module.counters_snapshot(),
                "integrity": self.store.integrity_snapshot(),
                "read_pipeline": readpipe.snapshot(),
                # Range-partitioned ingest plane (lo_ingest_partition_*)

@@ -167,6 +167,8 @@ def render(doc: Dict[str, Any]) -> str:
              "Chunk-read pipeline counter"),
             ("tune", "lo_tune", _COUNTER,
              "Hyperparameter-search plane counter"),
+            # Totals beside the last fit's readings: gauge, as tracing.
+            ("tx", "lo_tx", _GAUGE, "Sequence-family fit metric"),
             ("integrity", "lo_integrity", _COUNTER,
              "Data-plane integrity counter"),
             ("ingest", "lo_ingest", _COUNTER,
