@@ -44,7 +44,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.transformer import (
-    TxConfig, forward_reference, has_options, make_fit_programs)
+    MESH_AXES, NO_AXES, TxConfig, attention_path, forward_reference,
+    has_options, make_fit_programs)
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, MeshRuntime)
 from learningorchestra_tpu.utils import tracing
@@ -178,7 +179,8 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
     sync_steps = jax.default_backend() == "cpu"
     batch_key = jax.random.fold_in(key, 1 << 20)
     attrs: Dict[str, Any] = {"steps": int(train_steps),
-                             "tokens": int(train_steps) * batch * T_pad}
+                             "tokens": int(train_steps) * batch * T_pad,
+                             **attention_path(cfg, MESH_AXES, T_pad // S)}
     with tracing.span("fit.tx.steps", attrs):
         reports = []
         for _ in range(int(train_steps)):
@@ -230,7 +232,8 @@ def predictor(hparams: dict):
     proba = _proba_program(cfg)
 
     def timed(params, X):
-        with tracing.span("fit.tx.predict", rows=int(X.shape[0])):
+        with tracing.span("fit.tx.predict", rows=int(X.shape[0]),
+                          **attention_path(cfg, NO_AXES, cfg.max_len)):
             return jax.block_until_ready(proba(params, X))
 
     return timed

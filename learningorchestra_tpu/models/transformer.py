@@ -15,7 +15,13 @@ and not a second model file:
   as Keye-VL-2.0 configures it): a small indexer scores every earlier
   position, a query attends to the ``indexer_topk`` best, and the
   indexer learns from an alignment loss against the main attention's
-  own probabilities;
+  own probabilities. A block of queries attends through the Pallas
+  kernels of ``ops/pallas_kernels.py:chosen_attention`` wherever the
+  TPU's compiler can tile the shapes (heads 128 wide, whole mask
+  tiles): key blocks stream through VMEM, which holds the online
+  softmax's running maximum, sum and accumulator and one score tile,
+  and stop at the causal edge, so no score block exists in HBM; other
+  shapes run the plain einsum body, the kernels' oracle;
 - ``n_experts``: a routed expert layer that is TOLD WHICH EXPERTS IT
   HOLDS (``experts_first``, ``experts_held``): it routes over all of
   them and adds only its own experts' part, which is what one chip of
@@ -61,6 +67,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from learningorchestra_tpu.ops import pallas_kernels as pk
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS)
 from learningorchestra_tpu.parallel.ring_attention import (
@@ -334,11 +341,63 @@ def _select_topk(scores, k: int):
     return ukey >= ans[:, None]
 
 
-def _chosen_attention(cfg: TxConfig, ax: Axes, q, k, v, ix):
+def _chunk(n: int, cap: int) -> int:
+    """Items a blocked pass holds at a time: the largest divisor of
+    ``n`` that is at most ``cap``."""
+    return next(c for c in range(min(cap, n), 0, -1) if n % c == 0)
+
+
+def _query_blocks(cfg: TxConfig, ax: Axes, T: int):
+    """How grouped-query attention runs for rows of ``T`` tokens a
+    shard, decided once from the configuration, the axes and the static
+    shapes: ``None`` where it does not go through ``_chosen_attention``
+    (dense attention, and causal attention on a sequence axis: the
+    ring); else ``(C, key_block)``: the queries a block holds, and the
+    key block ``pk.chosen_attention``'s kernels walk, 0 where the block
+    runs the plain body."""
+    if not cfg.n_kv_heads or not (
+            cfg.indexer_heads or (ax.seq is None and cfg.causal)):
+        return None
+    C = _chunk(T, cfg.q_chunk)
+    return C, pk.chosen_attn_key_block(T, C, cfg.hd,
+                                       cfg.n_heads // cfg.n_kv_heads)
+
+
+def attention_path(cfg: TxConfig, ax: Axes, T: int) -> Dict[str, Any]:
+    """``_query_blocks``' choice as span attributes: ``attn_kernel``
+    (share of attention layers on the Pallas kernels; the layers are
+    alike, so 1.0 or 0.0) and ``key_blocks_skipped_share`` (the (query
+    block, key block) pairs that start past the query block's last
+    query, which the kernels neither fetch nor compute; the plain body
+    scores them all). Empty where attention does not go through
+    ``_chosen_attention``."""
+    blocks = _query_blocks(cfg, ax, T)
+    if blocks is None:
+        return {}
+    C, key_block = blocks
+    skipped = 0.0
+    if key_block:
+        run = sum(((i + 1) * C - 1) // key_block + 1 for i in range(T // C))
+        skipped = 1.0 - run / ((T // C) * (T // key_block))
+    return {"attn_kernel": float(key_block > 0),
+            "key_blocks_skipped_share": skipped}
+
+
+def _chosen_attention(cfg: TxConfig, ax: Axes, blocks, q, k, v, ix):
     """Causal grouped-query attention of ONE row over the keys the
     indexer chose (every earlier key where there is no indexer), a chunk
-    of queries at a time; the chunk body is rematerialised, so a
-    (heads, chunk, T) score block lives once.
+    of queries at a time; the chunk body is rematerialised.
+
+    ``blocks`` is ``_query_blocks``' ``(C, key_block)``. Where the TPU's
+    compiler can tile the shapes and a group's working set fits VMEM
+    (``pk.chosen_attn_key_block``: heads 128 wide, a chunk of whole mask
+    tiles) the scores, the softmax and the probability-times-value
+    product of a chunk are ``pk.chosen_attention``'s kernels: key blocks
+    stream through VMEM, which holds the running maximum, sum and
+    accumulator of the chunk's heads, and stop at the causal edge; no
+    score block exists in HBM. Any other shape runs the plain body
+    below, whose (heads, chunk, T) float32 score block lives once; it
+    is the kernels' oracle.
 
     q (T, H, D); k, v (T, G, D); ``ix``: ``None`` or the indexer's
     ``(qI (T, Hi, Di), kI (T, Di), w (T, Hi))``. Returns ``(o (T, H, D),
@@ -347,7 +406,9 @@ def _chosen_attention(cfg: TxConfig, ax: Axes, q, k, v, ix):
     T, H, D = q.shape
     G = k.shape[1]
     R = H // G
-    C = next(c for c in range(min(cfg.q_chunk, T), 0, -1) if T % c == 0)
+    C, fused = blocks[0], blocks[1] > 0
+    if fused:      # heads side by side in lanes, once a row (a copy)
+        k, v = k.reshape(T, G * D), v.reshape(T, G * D)
     kpos = jnp.arange(T)
     heads_all = H * _axis_size(ax.model)
 
@@ -365,9 +426,14 @@ def _chosen_attention(cfg: TxConfig, ax: Axes, q, k, v, ix):
                 cfg.indexer_topk)
         else:
             chosen = allowed
-        s = jnp.einsum("qgrd,kgd->grqk", q_c.reshape(C, G, R, D), k) * D ** -0.5
-        p = jax.nn.softmax(jnp.where(chosen, s, -jnp.inf), axis=-1)
-        o = jnp.einsum("grqk,kgd->qgrd", p, v).reshape(C, H, D)
+        if fused:
+            o, heads_p = pk.chosen_attention(q_c, k, v, chosen, i)
+        else:
+            s = jnp.einsum("qgrd,kgd->grqk", q_c.reshape(C, G, R, D),
+                           k) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(chosen, s, -jnp.inf), axis=-1)
+            o = jnp.einsum("grqk,kgd->qgrd", p, v).reshape(C, H, D)
+            heads_p = p.sum((0, 1))
         kept = chosen.sum(-1)
         stats = jnp.stack([
             kept.sum().astype(jnp.float32),
@@ -377,15 +443,19 @@ def _chosen_attention(cfg: TxConfig, ax: Axes, q, k, v, ix):
         # The alignment loss: KL(main attention's head-summed
         # probabilities over the chosen keys, L1-normalised, detached ||
         # the indexer's softmax over the same keys).
-        target = _psum(jax.lax.stop_gradient(p).sum((0, 1)),
-                       ax.model) / heads_all
+        target = _psum(jax.lax.stop_gradient(heads_p), ax.model) / heads_all
         logq = jax.nn.log_softmax(jnp.where(chosen, score, -jnp.inf), -1)
         kl = jnp.where(target > 0, target * (
             jnp.log(jnp.where(target > 0, target, 1.0)) - jnp.where(
                 chosen, logq, 0.0)), 0.0).sum()
         return o, jnp.concatenate([kl[None], stats])
 
-    o, stats = jax.lax.map(jax.checkpoint(chunk), jnp.arange(T // C))
+    # The kernels' output and log-sum-exp of every chunk are kept (a
+    # row's (T, H, D) and (T, H) a layer): the backward then recomputes
+    # the selection and the head-summed probabilities, not the forward.
+    keep = jax.checkpoint_policies.save_only_these_names(*pk.ATTN_RESIDUALS)
+    o, stats = jax.lax.map(jax.checkpoint(chunk, policy=keep),
+                           jnp.arange(T // C))
     return o.reshape(T, H, D), stats.sum(0)
 
 
@@ -407,8 +477,8 @@ def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
         k = _rms(k, lyr["k_norm"], cfg.norm_eps)
     if cfg.rope_theta:
         q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    if cfg.indexer_heads or (ax.seq is None and cfg.causal):
+    blocks = _query_blocks(cfg, ax, h.shape[1])
+    if blocks is not None:
         ix = None
         if cfg.indexer_heads:
             # The indexer reads the block's input detached: only its own
@@ -426,7 +496,7 @@ def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
             ix = (qi, ki, jnp.einsum("btd,dj->btj", hi, lyr["ix_ww"]))
 
         def row(args):
-            return _chosen_attention(cfg, ax, args[0], args[1], args[2],
+            return _chosen_attention(cfg, ax, blocks, *args[:3],
                                      None if ix is None else args[3:])
 
         o, stats = jax.lax.map(row, (q, k, v) + (ix or ()))
@@ -434,6 +504,7 @@ def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
     else:
         # Dense GQA: each key/value head repeated for its group of query
         # heads; over a sharded sequence, the ring.
+        rep = cfg.n_heads // cfg.n_kv_heads
         k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
         o = (reference_attention(q, k, v, causal=False) if ax.seq is None
              else ring_attention(q, k, v, axis_name=ax.seq,
@@ -465,7 +536,7 @@ def _experts(cfg: TxConfig, ax: Axes, h, lyr):
     hit = top_e[:, :, None] == ids[None, None, :]              # (N, K, e)
     gate = (hit * top_g[:, :, None]).sum(1)                    # (N, e)
     N = B * T
-    C = next(c for c in range(min(cfg.token_chunk, N), 0, -1) if N % c == 0)
+    C = _chunk(N, cfg.token_chunk)
 
     def part(args):
         xc, gc = args
@@ -568,7 +639,7 @@ def _next_token_loss(params, x, tokens, labels, cfg: TxConfig, ax: Axes):
     targets = jnp.concatenate([tokens[:, 1:], nxt], axis=1).reshape(B * Tl)
     h = _norm(cfg, x, params["lnf_g"]).reshape(B * Tl, d)
     N = B * Tl
-    C = next(c for c in range(min(cfg.token_chunk, N), 0, -1) if N % c == 0)
+    C = _chunk(N, cfg.token_chunk)
 
     def chunk(args):
         hc, tc = args

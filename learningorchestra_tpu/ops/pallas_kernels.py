@@ -32,12 +32,40 @@ ops XLA alone schedules sub-optimally. Residents:
   leaves the batch a grid axis of the one-tree kernel where every
   member has codes of its own (the leaf statistics, a population).
 
+- **Attention over chosen keys** (models/transformer.py
+  `_chosen_attention`: the `tx` family's grouped-query causal attention,
+  with or without the sparse-attention indexer's selection) — a block of
+  `C` queries against the row's `T` keys. The plain body writes the
+  (heads, C, T) float32 score block to HBM and reads it back for the
+  softmax and again for the probability-times-value product, forward and
+  backward (134 MB a block at 32 heads x 128 x 8,192). `chosen_attention`
+  is three kernels under one `jax.custom_vjp` that walk the row a key
+  block at a time: per key-value head, VMEM holds the group's stacked
+  queries (R·C, D), the current (key block, D) keys and values, the
+  (C, key block) int8 mask tile, one (R·C, key block) float32 score tile
+  with its exponentials in the MXU's operand type, and the running
+  maximum, sum and (R·C, D) accumulator of the online softmax
+  (`chosen_attn_fwd`); the head-summed probabilities the indexer's
+  alignment loss reads take a second walk once the log-sum-exp is final,
+  the (C, key block) sum staying in VMEM while the heads pass
+  (`chosen_attn_probs`); the backward recomputes scores and
+  probabilities from the log-sum-exp, keeps the `dq` (R·C, D)
+  accumulator in VMEM and writes each key block's `dk`, `dv` once
+  (`chosen_attn_bwd`). Key blocks that start past the block's last
+  query are neither computed nor fetched: the last needed block rides
+  scalar prefetch and the index maps clamp to it. The key block (512,
+  256 or 128 keys) is the largest whose working set at the group's R·C
+  rows fits half a v5e core's VMEM (`chosen_attn_key_block`), and that
+  working set is the scoped VMEM each call asks for; a group too tall
+  for the smallest runs the plain body.
+
 On non-TPU backends every `pallas_call` runs in interpreter mode, so the
 same code path is unit-tested on the CPU mesh (tests/conftest.py) and
 cross-checked against the pure-XLA reference implementation.
 
 Each `pallas_call` carries a `name=` (`tsne_repulsion`, `tree_hist`,
-`tree_hist_stacked`, `tree_route`, `tree_descend`): it becomes the
+`tree_hist_stacked`, `tree_route`, `tree_descend`, `chosen_attn_fwd`,
+`chosen_attn_probs`, `chosen_attn_bwd`): it becomes the
 innermost name scope, XLA names the custom-call instruction after it
 (`%tree_hist.3 = ... custom_call_target="tpu_custom_call"`), and that
 instruction text is the event's name on a device profile's `XLA Ops` line — which is all the
@@ -50,6 +78,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -619,3 +648,366 @@ def tree_descend(codes_T, feat, thr, internal, *, max_depth,
         name="tree_descend",
     )(_pad_lanes(codes_T, n_pad), _node_tables(feat, thr, internal))
     return out[0, :n]
+
+
+# ---------------------------------------------------------------------------
+# Attention over the chosen keys (models/transformer.py:_chosen_attention)
+# ---------------------------------------------------------------------------
+
+#: Key blocks the attention kernels may walk, largest first: a larger
+#: block leaves fewer grid steps to skip past the causal edge.
+_ATTN_KEY_BLOCKS = (512, 256, 128)
+#: What a rematerialised caller of ``chosen_attention`` may keep of a
+#: query block's forward for its backward (``jax.checkpoint_policies
+#: .save_only_these_names``): the block's output and its log-sum-exp.
+ATTN_RESIDUALS = ("chosen_attn_o", "chosen_attn_lse")
+#: Scoped VMEM the attention kernels may ask for: half the 128 MiB of a
+#: v5e core, the smallest VMEM of the chips this runs on.
+_ATTN_VMEM_BUDGET = 64 << 20
+
+
+def _attn_vmem_bytes(RC: int, C: int, D: int, tk: int) -> int:
+    """VMEM the backward kernel, the largest of the three, is given for
+    ``RC`` stacked query rows and a key block of ``tk``: every operand
+    and result block twice (the pipeline's two buffers), the (R·C, 1)
+    log-sum-exp and delta columns padded to a lane tile, the four
+    (R·C, tk) scratch tiles (scores and ``do·v`` float32, ``p`` and
+    ``ds`` as MXU operands) and the operands' bfloat16 copies. An upper
+    bound: the compiler for the described v5e takes about half (8 MiB of
+    these 13.9 in the benchmark's cell, 53 of 94.8 at R·C 8,192 and 512
+    keys)."""
+    rows = RC * (2 * 3 * D * 4         # q, do, dq
+                 + 2 * 2 * _LANES * 4  # lse, delta
+                 + 2 * D * 2           # q, do as operands
+                 + tk * (4 + 4 + 2 + 2))
+    keys = tk * (2 * 4 * D * 4         # k, v, dk, dv
+                 + 2 * D * 2           # k, v as operands
+                 + 2 * C)              # the int8 mask tile
+    return rows + keys
+
+
+def chosen_attn_key_block(T: int, C: int, D: int, R: int) -> int:
+    """The key block the attention kernels walk for ``C`` queries of a
+    ``T``-token row at head width ``D`` with ``R`` query heads a
+    key-value head, or 0 where the plain body runs: heads are a lane
+    tile wide, a query block is whole int8 mask tiles (32 sublanes), and
+    the key block is the largest that divides the row and whose working
+    set (``_attn_vmem_bytes``) fits ``_ATTN_VMEM_BUDGET``: 512 to
+    R·C 4,096 at heads of 128, 128 to 8,192, none beyond."""
+    if D % _LANES or C % 32 or T % C:
+        return 0
+    return next((b for b in _ATTN_KEY_BLOCKS if T % b == 0
+                 and _attn_vmem_bytes(R * C, C, D, b) <= _ATTN_VMEM_BUDGET), 0)
+
+
+def _attn_operand_dtype():
+    """What the MXU takes of a float32 product at default precision on
+    the chip; off it the plain body's products are float32 too."""
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+
+
+def _nt_dot(a, b):
+    """``a @ b.T`` with float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn_dot(a, b):
+    """``a.T @ b`` with float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _across(x, width: int):
+    """A (rows, 128) value whose lanes all hold the row's number, as
+    (rows, width), ``width`` a multiple of 128."""
+    reps = width // _LANES
+    return x if reps == 1 else jnp.concatenate([x] * reps, axis=1)
+
+
+def _bias_tile(mask_ref):
+    """(C, block) float32: 0 where the key was chosen, -inf elsewhere."""
+    return jnp.where(mask_ref[:].astype(jnp.int32) != 0, 0.0, -jnp.inf)
+
+
+def _chosen_fwd_kernel(last_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                       lse_ref, m_sc, l_sc, acc_sc, s_sc, p_sc, *, scale, dt):
+    """One (key-value head g, key block kb) cell of a query block's
+    forward: the online softmax of flash attention over the keys the
+    mask keeps. The group's R query heads are stacked on the matmul's
+    rows (q_ref (R·C, D)); the running maximum, sum and the (R·C, D)
+    accumulator stay in VMEM while the key blocks stream past (kb is
+    the innermost grid dimension), and a (R·C, block) score tile never
+    leaves it. Maximum and sum are kept across a vreg's 128 lanes, so
+    they meet scores and accumulator without a lane broadcast (14%
+    fewer bundles a tile than as columns). The softmax takes the tile a head (C rows) at a time, as
+    straight-line code: the compiler's schedule for the described v5e
+    overlaps one head's lane reductions with the next one's arithmetic
+    (18% fewer bundles a tile than a loop over the heads; 47% in the
+    second walk). ``last_ref[0]`` is the last key block that holds a key
+    at or before the block's last query: the blocks past it are not
+    computed, and the index maps fetch nothing for them. A query with
+    no chosen key in a block (or in none so far: its maximum is still
+    -inf) gets exactly 0 from it."""
+    kb = pl.program_id(1)
+    C = mask_ref.shape[0]
+    reps = q_ref.shape[0] // C
+
+    @pl.when(kb == 0)
+    def _init():
+        m_sc[:] = jnp.full_like(m_sc, -jnp.inf)
+        l_sc[:] = jnp.zeros_like(l_sc)
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+
+    @pl.when(kb <= last_ref[0])
+    def _step():
+        s_sc[:] = _nt_dot(q_ref[:].astype(dt), k_ref[:].astype(dt)) * scale
+        bias = _bias_tile(mask_ref)
+
+        for r in range(reps):
+            rows = slice(r * C, (r + 1) * C)
+            s = s_sc[rows, :] + bias
+            m_prev = m_sc[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            alpha = jnp.exp(m_prev - m_safe)
+            p = jnp.exp(s - _across(m_safe, s.shape[1]))
+            l_sc[rows, :] = alpha * l_sc[rows, :] + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_sc[rows, :] = _across(alpha, acc_sc.shape[1]) * acc_sc[rows, :]
+            m_sc[rows, :] = m_new
+            p_sc[rows, :] = p.astype(dt)
+        acc_sc[:] += jnp.dot(p_sc[:], v_ref[:].astype(dt),
+                             preferred_element_type=jnp.float32)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[:] = acc_sc[:] / _across(l_sc[:], acc_sc.shape[1])
+        lse_ref[:] = (m_sc[:] + jnp.log(l_sc[:]))[:, :1]
+
+
+def _chosen_probs_kernel(last_ref, q_ref, k_ref, mask_ref, lse_ref, out_ref,
+                         s_sc, *, scale, dt):
+    """One (key block kb, key-value head g) cell of the second walk: the
+    probabilities ``exp(s - lse)`` of the block's keys, summed over this
+    shard's heads into the (C, block) result, which stays in VMEM while
+    the heads pass (g is the innermost grid dimension)."""
+    kb, g = pl.program_id(0), pl.program_id(1)
+    C = mask_ref.shape[0]
+    reps = q_ref.shape[0] // C
+
+    @pl.when(g == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    @pl.when(kb <= last_ref[0])
+    def _step():
+        s_sc[:] = _nt_dot(q_ref[:].astype(dt), k_ref[:].astype(dt)) * scale
+        bias = _bias_tile(mask_ref)
+
+        total = jnp.zeros_like(out_ref)
+        for r in range(reps):
+            rows = slice(r * C, (r + 1) * C)
+            total += jnp.exp(s_sc[rows, :] + bias - lse_ref[rows, :])
+        out_ref[:] += total
+
+
+def _chosen_bwd_kernel(last_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
+                       v_ref, mask_ref, dq_ref, dk_ref, dv_ref, s_sc, dp_sc,
+                       p_sc, ds_sc, *, scale, dt):
+    """One (key-value head g, key block kb) cell of a query block's
+    backward: scores and probabilities recomputed from the log-sum-exp,
+    ``ds = p * (do·v - delta) * scale``; ``dq`` (R·C, D) accumulates in
+    VMEM over the key blocks, the block's ``dk`` and ``dv`` (block, D)
+    are written once: zeros past the causal edge."""
+    kb = pl.program_id(1)
+    C = mask_ref.shape[0]
+    reps = q_ref.shape[0] // C
+
+    @pl.when(kb == 0)
+    def _init():
+        dq_ref[:] = jnp.zeros_like(dq_ref)
+
+    @pl.when(kb > last_ref[0])
+    def _skip():
+        dk_ref[:] = jnp.zeros_like(dk_ref)
+        dv_ref[:] = jnp.zeros_like(dv_ref)
+
+    @pl.when(kb <= last_ref[0])
+    def _step():
+        q, do = q_ref[:].astype(dt), do_ref[:].astype(dt)
+        k, v = k_ref[:].astype(dt), v_ref[:].astype(dt)
+        s_sc[:] = _nt_dot(q, k) * scale
+        dp_sc[:] = _nt_dot(do, v)
+        bias = _bias_tile(mask_ref)
+
+        for r in range(reps):
+            rows = slice(r * C, (r + 1) * C)
+            p = jnp.exp(s_sc[rows, :] + bias - lse_ref[rows, :])
+            ds = p * (dp_sc[rows, :] - delta_ref[rows, :]) * scale
+            p_sc[rows, :] = p.astype(dt)
+            ds_sc[rows, :] = ds.astype(dt)
+        dv_ref[:] = _tn_dot(p_sc[:], do)
+        dk_ref[:] = _tn_dot(ds_sc[:], q)
+        dq_ref[:] += jnp.dot(ds_sc[:], k, preferred_element_type=jnp.float32)
+
+
+def _attn_call(kernel, name, last, args, *, grid, in_specs, out_specs,
+               out_shape, scratch, vmem):
+    """A ``pallas_call`` of the attention kernels: ``last`` (1,) int32,
+    the last key block to compute, rides scalar prefetch, so the index
+    maps clamp the key block to it and a skipped step re-names the block
+    already in VMEM: no DMA. ``vmem``: the scoped VMEM asked for.
+    Results vary over the mesh axes ``args[0]`` (the queries) varies
+    over."""
+    vma = jax.typeof(args[0]).vma
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
+                   for shape in out_shape],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        interpret=_interpret(),
+        name=name,
+    )(last, *args)
+
+
+def _attn_specs(RC, D, C, tk, heads_first: bool):
+    """``(per_head(width), keys, mask)`` block specs for a grid of
+    (heads, key blocks) or, not ``heads_first``, (key blocks, heads)."""
+    def at(fn):
+        if heads_first:
+            return lambda g, kb, last: fn(g, jnp.minimum(kb, last[0]))
+        return lambda kb, g, last: fn(g, jnp.minimum(kb, last[0]))
+
+    def per_head(width):
+        return pl.BlockSpec((None, RC, width), at(lambda g, kb: (g, 0, 0)))
+
+    return (per_head, pl.BlockSpec((tk, D), at(lambda g, kb: (kb, g))),
+            pl.BlockSpec((C, tk), at(lambda g, kb: (0, kb))))
+
+
+def _attn_dims(qg, mask):
+    """``(G, R·C, D, C, T, key block, VMEM to ask for)`` of one query
+    block's operands."""
+    G, RC, D = qg.shape
+    C, T = mask.shape
+    tk = chosen_attn_key_block(T, C, D, RC // C)
+    return G, RC, D, C, T, tk, _attn_vmem_bytes(RC, C, D, tk)
+
+
+def _chosen_attn_forward(qg, k2, v2, mask, last):
+    """``(o (G, R·C, D), lse (G, R·C, 1))`` of one query block."""
+    G, RC, D, C, T, tk, vmem = _attn_dims(qg, mask)
+    dt = _attn_operand_dtype()
+    per_head, keys, msk = _attn_specs(RC, D, C, tk, True)
+    return _attn_call(
+        partial(_chosen_fwd_kernel, scale=D ** -0.5, dt=dt),
+        "chosen_attn_fwd", last, (qg, k2, v2, mask),
+        grid=(G, T // tk), in_specs=[per_head(D), keys, keys, msk],
+        out_specs=[per_head(D), per_head(1)],
+        out_shape=[(G, RC, D), (G, RC, 1)],
+        scratch=[pltpu.VMEM((RC, _LANES), jnp.float32),
+                 pltpu.VMEM((RC, _LANES), jnp.float32),
+                 pltpu.VMEM((RC, D), jnp.float32),
+                 pltpu.VMEM((RC, tk), jnp.float32),
+                 pltpu.VMEM((RC, tk), dt)],
+        vmem=vmem)
+
+
+def _chosen_attn_probs(qg, k2, mask, lse, last):
+    """``Σ_heads p`` (C, T) of one query block, from the log-sum-exp."""
+    G, RC, D, C, T, tk, vmem = _attn_dims(qg, mask)
+    per_head, keys, msk = _attn_specs(RC, D, C, tk, False)
+    (probs,) = _attn_call(
+        partial(_chosen_probs_kernel, scale=D ** -0.5,
+                dt=_attn_operand_dtype()),
+        "chosen_attn_probs", last, (qg, k2, mask, lse),
+        grid=(T // tk, G), in_specs=[per_head(D), keys, msk, per_head(1)],
+        out_specs=[pl.BlockSpec((C, tk), lambda kb, g, last: (0, kb))],
+        out_shape=[(C, T)],
+        scratch=[pltpu.VMEM((RC, tk), jnp.float32)], vmem=vmem)
+    return probs
+
+
+def _chosen_attn_backward(qg, k2, v2, mask, last, lse, delta, do):
+    """``(dq (G, R·C, D), dk, dv (T, G·D))`` of one query block."""
+    G, RC, D, C, T, tk, vmem = _attn_dims(qg, mask)
+    dt = _attn_operand_dtype()
+    per_head, keys, msk = _attn_specs(RC, D, C, tk, True)
+    grads = pl.BlockSpec((tk, D), lambda g, kb, last: (kb, g))
+    return _attn_call(
+        partial(_chosen_bwd_kernel, scale=D ** -0.5, dt=dt),
+        "chosen_attn_bwd", last, (qg, do, lse, delta, k2, v2, mask),
+        grid=(G, T // tk),
+        in_specs=[per_head(D), per_head(D), per_head(1), per_head(1),
+                  keys, keys, msk],
+        out_specs=[per_head(D), grads, grads],
+        out_shape=[(G, RC, D), (T, G * D), (T, G * D)],
+        scratch=[pltpu.VMEM((RC, tk), jnp.float32),
+                 pltpu.VMEM((RC, tk), jnp.float32),
+                 pltpu.VMEM((RC, tk), dt),
+                 pltpu.VMEM((RC, tk), dt)],
+        vmem=vmem)
+
+
+def _chosen_attn_fwd(qg, k2, v2, mask, last):
+    o, lse = _chosen_attn_forward(qg, k2, v2, mask, last)
+    # Named, so a rematerialised caller may keep them (ATTN_RESIDUALS)
+    # and not run the forward kernel again before the backward. The
+    # log-sum-exp is kept lane-dense: a (.., 1) column pads to 128 lanes
+    # in HBM.
+    o = checkpoint_name(o, ATTN_RESIDUALS[0])
+    lse = checkpoint_name(lse[..., 0], ATTN_RESIDUALS[1])
+    return ((o, _chosen_attn_probs(qg, k2, mask, lse[..., None], last)),
+            (qg, k2, v2, mask, last, o, lse))
+
+
+@jax.custom_vjp
+def _chosen_attn(qg, k2, v2, mask, last):
+    """``(o (G, R·C, D), Σ_heads p (C, T))`` of one query block."""
+    return _chosen_attn_fwd(qg, k2, v2, mask, last)[0]
+
+
+def _chosen_attn_bwd(res, cts):
+    qg, k2, v2, mask, last, o, lse = res
+    do = cts[0]        # the probabilities are read detached: no cotangent
+    delta = (o * do).sum(-1, keepdims=True)
+    dq, dk, dv = _chosen_attn_backward(qg, k2, v2, mask, last,
+                                       lse[..., None], delta, do)
+    return dq, dk, dv, None, None
+
+
+_chosen_attn.defvjp(_chosen_attn_fwd, _chosen_attn_bwd)
+
+
+def chosen_attention(q, k, v, chosen, block):
+    """Causal grouped-query attention of one block of ``C`` queries over
+    the keys ``chosen`` keeps — the fused replacement for the score /
+    softmax / probability-times-value lines of
+    ``models/transformer.py:_chosen_attention``'s plain body, for shapes
+    ``chosen_attn_key_block`` admits.
+
+    q (C, H, D); k, v (T, G·D), float32, the key-value heads side by
+    side in lanes (a (T, G, D) array pads G to whole sublane tiles in
+    HBM, and flattening it is a copy: the caller flattens once a row,
+    not once a block); chosen (C, T) bool, which holds the causal mask
+    (every query keeps at least one key); ``block`` the query block's
+    index in the row (int32 scalar): key blocks that start past query
+    ``(block + 1)·C - 1`` are skipped. Returns ``(o (C, H, D), Σ_heads p
+    (C, T))`` in float32; differentiable in q, k and v (the
+    probabilities are for use under ``stop_gradient``). Operands are
+    rounded to bfloat16 only as the MXU takes them, as the default
+    precision does; scores, maxima, sums, the log-sum-exp and every
+    accumulator are float32."""
+    C, H, D = q.shape
+    T, G = k.shape[0], k.shape[1] // D
+    R = H // G
+    tk = chosen_attn_key_block(T, C, D, R)
+    last = (((block + 1) * C - 1) // tk).astype(jnp.int32).reshape(1)
+    qg = q.reshape(C, G, R, D).transpose(1, 2, 0, 3).reshape(G, R * C, D)
+    o, probs = _chosen_attn(qg, k, v, chosen.astype(jnp.int8), last)
+    return (o.reshape(G, R, C, D).transpose(2, 0, 1, 3).reshape(C, H, D),
+            probs)

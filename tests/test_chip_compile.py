@@ -8,7 +8,12 @@ so each Pallas kernel of the main path is compiled once at its published
 width: HIGGS's 28 features, the Spark-parity depth-5 / 32-bin defaults,
 rf's vmapped batch of 5 stat sets (stacked on the histogram kernel's
 matmul rows; its leaf statistics a grid axis), the 256-bin uint8 extreme
-at its own tile, and the t-SNE repulsion at the 8,192-row plot size.
+at its own tile, the t-SNE repulsion at the 8,192-row plot size, and
+the attention kernels of the ``tx`` family's query blocks at
+Keye-VL-2.0's widths (rows of 8,192 tokens, 32 query / 4 key-value heads
+of 128, blocks of 128 queries), forward and backward, and at the default
+block of 512 queries with 8 and with 16 heads a key-value head, where
+the kernels' shape rule sizes the key block to VMEM.
 Between them the histogram cases take every form of the bin one-hot the
 kernel's shape rule can choose (``pk._tree_hist_kernel``): one compare a
 128-column group with four features a group (32 bins), with one feature
@@ -38,6 +43,8 @@ NL = 2 ** (DEPTH - 1)                 # per-level node width (16)
 M = 2 ** (DEPTH + 1) - 1              # nodes of a depth-5 tree (63)
 HDT = jnp.bfloat16                    # trees._hist_dtype() on the chip
 N_TSNE = 8192
+#: The benchmark's ``keye-vl-2.0-30b-a3b`` attention: row, heads, block.
+T_TX, H_TX, G_TX, D_TX, C_TX = 8192, 32, 4, 128, 128
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +71,7 @@ def chip_compiler(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_attn_operand_dtype", lambda: HDT)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -130,7 +138,41 @@ def _tsne_rows():
              ((), jnp.int32)])
 
 
+def _chosen_attention(backward: bool, heads: int = H_TX, chunk: int = C_TX,
+                      key_block: int = 512):
+    """One query block of ``transformer._chosen_attention`` on the
+    kernels; the backward as the block's rematerialised forward and its
+    transpose, as the step runs it. ``key_block``: what the shape rule
+    is to choose for ``heads`` over ``G_TX`` key-value heads and a block
+    of ``chunk`` queries."""
+    assert pk.chosen_attn_key_block(T_TX, chunk, D_TX,
+                                    heads // G_TX) == key_block
+
+    def grads(q, k, v, chosen, block):
+        return jax.grad(lambda q, k, v: pk.chosen_attention(
+            q, k, v, chosen, block)[0].sum(), (0, 1, 2))(q, k, v)
+
+    return (grads if backward else pk.chosen_attention,
+            [((chunk, heads, D_TX), jnp.float32),
+             ((T_TX, G_TX * D_TX), jnp.float32),
+             ((T_TX, G_TX * D_TX), jnp.float32),
+             ((chunk, T_TX), jnp.bool_), ((), jnp.int32)])
+
+
 CASES = {
+    "chosen_attention-forward": lambda: _chosen_attention(False),
+    "chosen_attention-backward": lambda: _chosen_attention(True),
+    # ``TxConfig.q_chunk``'s default, 512 queries a block: 8 heads a
+    # group (Keye-VL's own) keep the key block of 512; 16 fit VMEM with
+    # one of 128 (``pk._attn_vmem_bytes``).
+    "chosen_attention-forward-8heads-a-group-512queries":
+        lambda: _chosen_attention(False, 32, 512, 512),
+    "chosen_attention-backward-8heads-a-group-512queries":
+        lambda: _chosen_attention(True, 32, 512, 512),
+    "chosen_attention-forward-16heads-a-group-512queries":
+        lambda: _chosen_attention(False, 64, 512, 128),
+    "chosen_attention-backward-16heads-a-group-512queries":
+        lambda: _chosen_attention(True, 64, 512, 128),
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
     "tree_histogram-48bins-straddling": lambda: _hist(48),
@@ -207,6 +249,39 @@ def test_kernel_is_named_in_the_compiled_module(case, one_chip,
                                metric + ".json")) as fh:
             (pattern,) = json.load(fh)["ops"]
         assert all(re.search(pattern, ln) for ln in calls)
+
+
+@pytest.mark.parametrize("case,kernels", [
+    ("chosen_attention-forward", ["chosen_attn_fwd", "chosen_attn_probs"]),
+    ("chosen_attention-backward", ["chosen_attn_fwd", "chosen_attn_bwd"]),
+])
+def test_attention_kernels_are_counted_once(case, kernels, one_chip,
+                                            chip_compiler):
+    """The attention kernels run INSIDE the query-block loops that
+    ``sparse_attn_s.txfit``'s first pattern matches, and the reader sums
+    its matches: a kernel there whose own name held ``sparse_attn``
+    would be counted twice. Each is named for what it is, and neither
+    of the metric's patterns (nor the expert layer's) finds it."""
+    import json
+    import os
+    import re
+
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == len(kernels)
+    for ln, kernel in zip(calls, kernels):
+        assert re.match(r"(ROOT )?%[^ ]*" + kernel + r"[^ ]* = ", ln), ln[:80]
+    for metric in ("sparse_attn_s.txfit", "sparse_attn_roofline.txfit",
+                   "moe_s.txfit"):
+        with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                               "perfbench", "layer_metrics",
+                               metric + ".json")) as fh:
+            patterns = json.load(fh)["ops"]
+        assert not any(re.search(p, ln) for p in patterns for ln in calls)
 
 
 @pytest.mark.parametrize("family", ["forest", "gbt"])

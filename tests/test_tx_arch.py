@@ -354,6 +354,36 @@ def test_rest_fit_with_the_architecture_block(served):
     assert counters["steps"] >= 6 and "moe_imbalance" in counters
 
 
+def test_attention_path_is_chosen_from_the_shapes_and_reported(served):
+    """The shape rule: the plain body at this file's widths (heads of 8
+    and 16), the Pallas kernels at aligned ones (the benchmark cell's);
+    and the fit's ``fit.tx.steps`` span says which ran."""
+    from learningorchestra_tpu.utils import tracing
+
+    assert tx.attention_path(config(), tx.MESH_AXES, T) == {
+        "attn_kernel": 0.0, "key_blocks_skipped_share": 0.0}
+    cell = config(n_heads=32, n_kv_heads=4, head_dim=128, q_chunk=128,
+                  max_len=8192)
+    assert tx.attention_path(cell, tx.MESH_AXES, 8192) == {
+        "attn_kernel": 1.0, "key_blocks_skipped_share": 0.46875}
+    # Without the indexer a fit's attention is the ring's, not the query
+    # blocks'; the unsharded forward of predict goes through them.
+    dense = config(indexer_heads=0)
+    assert tx.attention_path(dense, tx.MESH_AXES, T) == {}
+    assert tx.attention_path(dense, tx.NO_AXES, T)["attn_kernel"] == 0.0
+    assert tx.attention_path(tx.TxConfig(), tx.NO_AXES, T) == {}
+    _, _, model, _ = served
+    model.create_model("ax_train", "ax_test", "axk", ["tx"], "label",
+                       hparams={"tx": dict(ARCH_HP, train_steps=1)})
+    spans = {d["name"]: d for d in tracing.recent_span_docs()
+             if d["name"] in ("fit.tx.steps", "fit.tx.predict")}
+    for name in ("fit.tx.steps", "fit.tx.predict"):
+        attrs = spans[name]["attrs"]
+        assert attrs["attn_kernel"] == 0.0, name
+        assert attrs["key_blocks_skipped_share"] == 0.0, name
+    assert "keys_kept_mean" in spans["fit.tx.steps"]["attrs"]
+
+
 def test_saved_model_reloads_flat_and_predicts_the_same(served):
     app, db, model, tmp = served
     files = set(os.listdir(tmp / "store" / "_models" / "axp_tx"))
