@@ -105,7 +105,23 @@ HPARAM_SPECS: Dict[str, Dict[str, Tuple[Callable, str]]] = {
                "experts_first": _int_range(0, 4095),
                "experts_held": _int_range(1, 4096),
                "norm_topk_prob": _boolean(), "lm_head": _boolean(),
-               "init_std": _positive()}},
+               "init_std": _positive(),
+               # A period of layer kinds (F full attention, L gated
+               # delta-rule linear attention) and the linear mixer.
+               "layer_pattern": (
+                   lambda v: isinstance(v, str) and 0 < len(v) <= 64
+                   and not set(v) - set("FL"),
+                   "a string of F and L, at most 64 long"),
+               "linear_heads": _int_range(1, 256),
+               "linear_key_dim": _int_range(1, 1024),
+               "linear_value_dim": _int_range(1, 1024),
+               "linear_conv": _int_range(1, 16),
+               "linear_neg_eigval": _boolean(),
+               "linear_chunk": _int_range(1, 1024),
+               "gated_width": _int_range(1, 1 << 18),
+               "post_norm": _boolean(), "qk_norm_whole": _boolean(),
+               "no_positions": _boolean(),
+               "heads_held": _int_range(1, 64)}},
 }
 
 

@@ -19,10 +19,12 @@ pods.
 With an ``arch`` block in its hyperparameters the same family is one of
 today's language-model blocks (RMSNorm, RoPE, grouped-query attention,
 the sparse-attention indexer, routed experts of which this holder was
-told its share, a next-token loss with the label-token readout:
-models/transformer.py). What it is held to then is the benchmark's plain
-reference (``perfbench/reference_tx.py``); the small block without
-``arch`` has no published model to match.
+told its share, a layer pattern with gated delta-rule linear-attention
+layers, attention heads of which this holder was told its share, a
+next-token loss with the label-token readout: models/transformer.py).
+What it is held to then is the benchmark's plain references
+(``perfbench/reference_tx.py``, ``perfbench/reference_hybrid.py``); the
+small block without ``arch`` has no published model to match.
 
 The step loop touches the host once a fit: the token table is placed on
 the device, each step draws its batch there from the seed, the steps are
@@ -53,7 +55,7 @@ from learningorchestra_tpu.utils import tracing
 #: A fit's routing and selection readings: span attributes of
 #: ``fit.tx.steps`` and, of the last fit, counters on ``GET /metrics``.
 _READINGS = ("keys_kept_mean", "queries_short_share", "absent_share",
-             "moe_imbalance")
+             "moe_imbalance", "state_absmax")
 
 _counters_lock = threading.Lock()
 _counters: Dict[str, Any] = {"fits": 0, "steps": 0, "tokens": 0,
@@ -87,9 +89,13 @@ def _fit_metrics(reports: list, cfg: TxConfig, tokens_per_step: int) -> dict:
            "loss_main": [float(r["loss_main"]) for r in reports],
            "grad_norm": {g: [float(r["grad_norm"][g]) for r in reports]
                          for g in reports[0]["grad_norm"]}}
-    queries = float(steps * tokens_per_step * cfg.n_layers)
-    if cfg.indexer_heads:
+    queries = float(steps * tokens_per_step * cfg.n_full)
+    if cfg.indexer_heads or cfg.lm_head:   # 0.0 where there is no indexer
         out["loss_index"] = [float(r["loss_index"]) for r in reports]
+    if "state_absmax" in reports[0]:
+        # The largest |S| a linear layer's state held at the end of a
+        # chunk, over the fit: says the recurrence stayed bounded.
+        out["state_absmax"] = max(float(r["state_absmax"]) for r in reports)
     if cfg.n_kv_heads and cfg.causal:
         out["keys_kept_mean"] = sum(
             float(r["keys_kept"]) for r in reports) / queries
@@ -148,15 +154,7 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
                              f"axis of {S}, and a next-token loss cannot "
                              "pad them")
         tokens_all = np.pad(tokens_all, ((0, 0), (0, T_pad - T)))
-    if arch:
-        for key, size in (("n_heads", n_heads),
-                          ("n_kv_heads", arch.get("n_kv_heads", 0)),
-                          ("experts_held", arch.get("experts_held")
-                           or arch.get("n_experts", 0))):
-            if size % M:
-                raise ValueError(f"{key} {size} does not divide over a "
-                                 f"model axis of {M}")
-    else:
+    if not arch:
         n_heads = _round_up(max(n_heads, 1), M)
         d_model = _round_up(max(d_model, n_heads), n_heads)
     d_ff = _round_up(max(d_ff, 1), M)
@@ -165,6 +163,15 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
     cfg = TxConfig(vocab=vocab, d_model=d_model, n_heads=n_heads,
                    n_layers=n_layers, d_ff=d_ff, n_classes=num_classes,
                    max_len=T_pad, causal=causal, remat=remat, **arch)
+    if arch:      # a published size is never rounded to fit a mesh
+        for key, size in (("n_heads (held)", cfg.heads),
+                          ("n_kv_heads (held)", cfg.kv_heads),
+                          ("linear_heads (held)", cfg.lin_heads),
+                          ("experts_held", cfg.held),
+                          ("gated_width", cfg.gated_width)):
+            if size % M:
+                raise ValueError(f"{key} {size} does not divide over a "
+                                 f"model axis of {M}")
     init, step = _fit_programs(cfg, mesh, float(lr), batch)
     key = jax.random.PRNGKey(seed)
     with tracing.span("fit.tx.init"):
@@ -180,7 +187,12 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
     batch_key = jax.random.fold_in(key, 1 << 20)
     attrs: Dict[str, Any] = {"steps": int(train_steps),
                              "tokens": int(train_steps) * batch * T_pad,
-                             **attention_path(cfg, MESH_AXES, T_pad // S)}
+                             **attention_path(
+                                 cfg, MESH_AXES if S > 1 else
+                                 MESH_AXES._replace(seq=None), T_pad // S)}
+    if cfg.pattern:
+        attrs.update(layer_pattern=cfg.pattern, heads_held=cfg.heads,
+                     linear_chunk=cfg.linear_chunk)
     with tracing.span("fit.tx.steps", attrs):
         reports = []
         for _ in range(int(train_steps)):

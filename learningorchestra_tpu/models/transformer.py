@@ -29,7 +29,30 @@ and not a second model file:
 - ``lm_head``: a per-position next-token loss over an untied vocabulary
   head, with the label-token readout that keeps the family a classifier
   (class ``c`` is token id ``c``; a row's target at its last position is
-  its label token).
+  its label token);
+- ``layer_pattern``: a model is one PERIOD of layer kinds, repeated
+  (``"LLLF"``: three linear layers, then a full-attention one). ``L`` is
+  the gated delta-rule linear attention of Gated DeltaNet (Yang et al.,
+  arXiv:2412.06464) as Olmo-Hybrid configures it (``linear_heads``,
+  ``linear_key_dim``, ``linear_value_dim``, ``linear_conv``,
+  ``linear_neg_eigval``): a depthwise causal conv, L2-normed queries and
+  keys, and per head a (key dim, value dim) state that decays and takes
+  a delta-rule write a token. The program computes it a chunk of
+  ``linear_chunk`` tokens at a time (within a chunk the ``(I +
+  tril(diag(beta) K K^T * decay))^-1`` transform, across chunks a loop
+  that carries the state); the benchmark's reference runs the
+  recurrence token by token;
+- ``gated_width``: a gated MLP without bias, ``(silu(h Wg) * (h Wu))
+  Wd``; ``post_norm``: the norm sits on a sublayer's OUTPUT, ``x +
+  norm(f(x))`` (OLMo 2's order); ``qk_norm_whole``: one RMSNorm over the
+  whole q / k projection; ``no_positions``: neither a learned table nor
+  rotary (position reaches such a model through its linear layers);
+- ``heads_held``: attention of both kinds is TOLD HOW MANY OF ITS HEADS
+  IT HOLDS: it computes their part of ``o Wo`` and adds nothing for the
+  absent ones, which is what one chip of a tensor-parallel deployment
+  computes before the reduce. (Which heads they are changes no
+  computation, so no option names them: a holder's leaves are its own
+  heads'.)
 
 The reference has no sequence models (SURVEY.md §5); the plain
 reference these options are held to is the benchmark's
@@ -48,7 +71,9 @@ The training step is one SPMD program over the 3-axis mesh
   cannot ride it: with the indexer on, a ``seq`` axis > 1 raises.
 
 Differentiation goes *through* ``shard_map``; the layers are stacked and
-scanned, so the program compiles one layer whatever the depth. Every
+scanned (with a ``layer_pattern`` the scan runs over periods, each
+kind's leaves stacked on their own), so the program compiles one layer,
+or one period, whatever the depth. Every
 array is float32 and every large product runs at the backend's default
 precision: on a TPU that is bfloat16 operands with float32 accumulation
 and float32 gradients, with no second copy of the weights in a lower
@@ -109,13 +134,57 @@ class TxConfig:
     lm_head: bool = False
     token_chunk: int = 1024       # positions an expert / head pass holds
     init_std: float = 0.02        # the options' init (normal, this std)
+    layer_pattern: str = ""       # one period of kinds: F full, L linear
+    linear_heads: int = 0         # the linear mixer's key = value heads
+    linear_key_dim: int = 96
+    linear_value_dim: int = 192
+    linear_conv: int = 4          # depthwise causal conv width
+    linear_neg_eigval: bool = False   # beta in (0, 2) instead of (0, 1)
+    linear_chunk: int = 64        # tokens a chunk transform holds (tiling)
+    gated_width: int = 0          # > 0: gated MLP without bias, this wide
+    post_norm: bool = False       # x + norm(f(x)) instead of x + f(norm(x))
+    qk_norm_whole: bool = False   # one RMSNorm over the whole q / k
+    no_positions: bool = False    # neither learned nor rotary positions
+    heads_held: int = 0           # heads of each mixer held here; 0: all
 
     def __post_init__(self):
-        if (self.rope_theta or self.qk_norm or self.indexer_heads) \
-                and not self.n_kv_heads:
-            raise ValueError("rope_theta, qk_norm and indexer_heads are "
-                             "options of grouped-query attention: set "
-                             "n_kv_heads")
+        if (self.rope_theta or self.qk_norm or self.indexer_heads
+                or self.qk_norm_whole) and not self.n_kv_heads:
+            raise ValueError("rope_theta, qk_norm, qk_norm_whole and "
+                             "indexer_heads are options of grouped-query "
+                             "attention: set n_kv_heads")
+        if self.qk_norm and self.qk_norm_whole:
+            raise ValueError("qk_norm (per head) and qk_norm_whole are "
+                             "two kinds of one norm: set one")
+        if self.no_positions and self.rope_theta:
+            raise ValueError("no_positions and rope_theta exclude each "
+                             "other")
+        if set(self.layer_pattern) - set("FL"):
+            raise ValueError(f"layer_pattern {self.layer_pattern!r}: a "
+                             "period is made of F (full attention) and L "
+                             "(linear attention)")
+        period = len(self.layer_pattern)
+        if period and self.n_layers > period and self.n_layers % period:
+            raise ValueError(f"n_layers {self.n_layers} is neither a "
+                             f"multiple of the period {self.layer_pattern!r}"
+                             " nor a cut of it")
+        if "L" in self.pattern:
+            if not self.linear_heads:
+                raise ValueError("an L layer needs linear_heads")
+            if not self.causal:
+                raise ValueError("the linear layer's state runs forward "
+                                 "over the row: it needs causal attention")
+            if self.linear_chunk < 1 or self.linear_conv < 1:
+                raise ValueError("linear_chunk and linear_conv are at "
+                                 "least 1")
+        if not 0 <= self.heads_held <= self.n_heads:
+            raise ValueError(f"heads_held {self.heads_held} is more than "
+                             f"the {self.n_heads} heads")
+        for key in ("n_kv_heads", "linear_heads"):
+            if getattr(self, key) * self.heads % self.n_heads:
+                raise ValueError(
+                    f"{self.heads} of {self.n_heads} heads held is no "
+                    f"whole share of {key} {getattr(self, key)}")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads {self.n_heads} is not a multiple of "
                              f"n_kv_heads {self.n_kv_heads}")
@@ -146,6 +215,33 @@ class TxConfig:
     def held(self) -> int:
         return self.experts_held or self.n_experts
 
+    @property
+    def pattern(self) -> str:
+        """The period as it is run: cut to ``n_layers`` where the model
+        is shallower; empty where every layer is the same layer."""
+        return self.layer_pattern[:self.n_layers]
+
+    @property
+    def heads(self) -> int:
+        """Query heads held here; ``kv_heads`` and ``lin_heads`` are the
+        same share of the key-value and of the linear mixer's heads."""
+        return self.heads_held or self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads * self.heads // self.n_heads
+
+    @property
+    def lin_heads(self) -> int:
+        return self.linear_heads * self.heads // self.n_heads
+
+    @property
+    def n_full(self) -> int:
+        """Full-attention layers in the model."""
+        if not self.pattern:
+            return self.n_layers
+        return self.pattern.count("F") * (self.n_layers // len(self.pattern))
+
 
 class Axes(NamedTuple):
     """The mesh axis names a forward pass reduces over; ``None``: that
@@ -159,7 +255,9 @@ MESH_AXES = Axes(DATA_AXIS, MODEL_AXIS, SEQ_AXIS)
 NO_AXES = Axes()
 
 #: Gradient-norm groups of a step's report: leaf name → group. The
-#: benchmark's comparison reads them by these names.
+#: benchmark's comparison reads them by these names. (The GELU MLP's
+#: leaves have counted under "experts" since PR 34; the gated MLP's
+#: are "mlp".)
 GRAD_GROUPS = {
     "embed": "embedding", "pos": "embedding",
     "head_w": "head", "head_b": "head", "lnf_g": "head",
@@ -172,8 +270,11 @@ GRAD_GROUPS = {
     "ln2_g": "experts", "ln2_b": "experts", "we_gate": "experts",
     "we_up": "experts", "we_down": "experts",
     "w1": "experts", "b1": "experts", "w2": "experts", "b2": "experts",
+    "w_gate": "mlp", "w_up": "mlp", "w_down": "mlp",
+    **{"la_" + k: "linear_attention" for k in (
+        "ln_g", "ln_b", "wq", "wk", "wv", "wz", "wb", "wa", "cq", "ck",
+        "cv", "a_log", "dt_bias", "gn_g", "wo")},
 }
-
 
 def _psum(x, axis):
     return x if axis is None else jax.lax.psum(x, axis)
@@ -189,12 +290,94 @@ def _axis_index(axis):
 
 # --- parameters -------------------------------------------------------------
 
+def _layer_leaves(cfg: TxConfig) -> Dict[str, Dict[str, Any]]:
+    """One layer's leaves by the kind of layer that has them, ``{kind:
+    {name: (shape, init, model dim)}}``: ``F`` the full-attention
+    sublayer's, ``L`` the linear mixer's, ``*`` every layer's (the MLP
+    or expert sublayer). ``init`` is "normal", "ones", "zeros", "a_log"
+    or "dt_bias"; ``model dim`` the dim split over the model axis
+    (heads, FFN hidden, held experts), ``None``: replicated."""
+    d, H, hd = cfg.d_model, cfg.heads, cfg.hd
+    bias = not cfg.rms_norm
+    full = {"ln1_g": ((d,), "ones", None)}
+    if bias:
+        full["ln1_b"] = ((d,), "zeros", None)
+    if cfg.n_kv_heads:
+        G = cfg.kv_heads
+        full.update(wq=((d, H, hd), "normal", 1), wk=((d, G, hd), "normal", 1),
+                    wv=((d, G, hd), "normal", 1), wo=((H, hd, d), "normal", 0))
+        if cfg.qk_norm:
+            full.update(q_norm=((hd,), "ones", None),
+                        k_norm=((hd,), "ones", None))
+        if cfg.qk_norm_whole:
+            full.update(q_norm=((H, hd), "ones", 0),
+                        k_norm=((G, hd), "ones", 0))
+    else:
+        full.update(wqkv=((d, 3, H, hd), "normal", 2),
+                    wo=((H, hd, d), "normal", 0))
+    if cfg.indexer_heads:
+        Hi, di = cfg.indexer_heads, cfg.indexer_head_dim
+        full.update(ix_wq=((d, Hi, di), "normal", None),
+                    ix_wk=((d, di), "normal", None),
+                    ix_kn_g=((di,), "ones", None),
+                    ix_kn_b=((di,), "zeros", None),
+                    ix_ww=((d, Hi), "normal", None))
+    Hl, dk, dv, K = (cfg.lin_heads, cfg.linear_key_dim, cfg.linear_value_dim,
+                     cfg.linear_conv)
+    linear = {"la_ln_g": ((d,), "ones", None),
+              "la_wq": ((d, Hl, dk), "normal", 1),
+              "la_wk": ((d, Hl, dk), "normal", 1),
+              "la_wv": ((d, Hl, dv), "normal", 1),
+              "la_wz": ((d, Hl, dv), "normal", 1),
+              "la_wb": ((d, Hl), "normal", 1), "la_wa": ((d, Hl), "normal", 1),
+              "la_cq": ((K, Hl, dk), "normal", 1),
+              "la_ck": ((K, Hl, dk), "normal", 1),
+              "la_cv": ((K, Hl, dv), "normal", 1),
+              "la_a_log": ((Hl,), "a_log", 0),
+              "la_dt_bias": ((Hl,), "dt_bias", 0),
+              "la_gn_g": ((dv,), "ones", None),
+              "la_wo": ((Hl, dv, d), "normal", 0)}
+    if bias:
+        linear["la_ln_b"] = ((d,), "zeros", None)
+    every = {"ln2_g": ((d,), "ones", None)}
+    if bias:
+        every["ln2_b"] = ((d,), "zeros", None)
+    if cfg.n_experts:
+        E, f = cfg.held, cfg.expert_width
+        every.update(router=((d, cfg.n_experts), "normal", None),
+                     we_gate=((E, d, f), "normal", 0),
+                     we_up=((E, d, f), "normal", 0),
+                     we_down=((E, f, d), "normal", 0))
+    elif cfg.gated_width:
+        f = cfg.gated_width
+        every.update(w_gate=((d, f), "normal", 1), w_up=((d, f), "normal", 1),
+                     w_down=((f, d), "normal", 0))
+    else:
+        every.update(w1=((d, cfg.d_ff), "normal", 1),
+                     b1=((cfg.d_ff,), "zeros", 0),
+                     w2=((cfg.d_ff, d), "normal", 0), b2=((d,), "zeros", None))
+    pattern = cfg.pattern or "F"
+    return {kind: leaves for kind, leaves in (
+        ("F", full), ("L", linear), ("*", every))
+        if kind == "*" or kind in pattern}
+
+
+def _stacking(cfg: TxConfig, kind: str) -> tuple:
+    """The axes a layer leaf of ``kind`` is stacked on, leading: the
+    layers where every layer is the same layer; with a pattern the
+    periods, then that kind's layers within a period."""
+    if not cfg.pattern:
+        return (cfg.n_layers,)
+    count = len(cfg.pattern) if kind == "*" else cfg.pattern.count(kind)
+    return (cfg.n_layers // len(cfg.pattern), count)
+
+
 def _leaf_shapes(cfg: TxConfig) -> Dict[str, Any]:
     """``{name: (shape, init)}`` top-level and ``{"layers": {...}}`` with
-    the layer axis leading; ``init`` is "normal", "ones" or "zeros"."""
-    L, d, H, hd = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.hd
+    the stacking axes leading (``_stacking``)."""
+    d = cfg.d_model
     top = {"embed": ((cfg.vocab, d), "normal")}
-    if not cfg.rope_theta:
+    if not (cfg.rope_theta or cfg.no_positions):
         top["pos"] = ((cfg.max_len, d), "normal")
     if cfg.lm_head:
         top["lnf_g"] = ((d,), "ones")
@@ -202,34 +385,9 @@ def _leaf_shapes(cfg: TxConfig) -> Dict[str, Any]:
     else:
         top["head_w"] = ((d, cfg.n_classes), "normal")
         top["head_b"] = ((cfg.n_classes,), "zeros")
-    lay = {"ln1_g": ((L, d), "ones"), "ln2_g": ((L, d), "ones")}
-    if not cfg.rms_norm:
-        lay["ln1_b"] = ((L, d), "zeros")
-        lay["ln2_b"] = ((L, d), "zeros")
-    if cfg.n_kv_heads:
-        G = cfg.n_kv_heads
-        lay.update(wq=((L, d, H, hd), "normal"), wk=((L, d, G, hd), "normal"),
-                   wv=((L, d, G, hd), "normal"), wo=((L, H, hd, d), "normal"))
-        if cfg.qk_norm:
-            lay.update(q_norm=((L, hd), "ones"), k_norm=((L, hd), "ones"))
-    else:
-        lay.update(wqkv=((L, d, 3, H, hd), "normal"),
-                   wo=((L, H, hd, d), "normal"))
-    if cfg.indexer_heads:
-        Hi, di = cfg.indexer_heads, cfg.indexer_head_dim
-        lay.update(ix_wq=((L, d, Hi, di), "normal"),
-                   ix_wk=((L, d, di), "normal"),
-                   ix_kn_g=((L, di), "ones"), ix_kn_b=((L, di), "zeros"),
-                   ix_ww=((L, d, Hi), "normal"))
-    if cfg.n_experts:
-        E, f = cfg.held, cfg.expert_width
-        lay.update(router=((L, d, cfg.n_experts), "normal"),
-                   we_gate=((L, E, d, f), "normal"),
-                   we_up=((L, E, d, f), "normal"),
-                   we_down=((L, E, f, d), "normal"))
-    else:
-        lay.update(w1=((L, d, cfg.d_ff), "normal"), b1=((L, cfg.d_ff), "zeros"),
-                   w2=((L, cfg.d_ff, d), "normal"), b2=((L, d), "zeros"))
+    lay = {name: (_stacking(cfg, kind) + shape, init)
+           for kind, leaves in _layer_leaves(cfg).items()
+           for name, (shape, init, _) in leaves.items()}
     return dict(top, layers=lay)
 
 
@@ -239,7 +397,7 @@ def has_options(cfg: TxConfig) -> bool:
     (a recipe a second implementation can follow without this file:
     perfbench's does), and predict takes a block of rows at a time."""
     return bool(cfg.rms_norm or cfg.n_kv_heads or cfg.n_experts
-                or cfg.lm_head)
+                or cfg.lm_head or cfg.layer_pattern or cfg.gated_width)
 
 
 def leaf_order(cfg: TxConfig) -> list:
@@ -254,9 +412,16 @@ def init_params(key, cfg: TxConfig) -> Dict[str, Any]:
     arch = has_options(cfg)
 
     def make(i, name, shape, init):
-        if init != "normal":
+        if init in ("ones", "zeros"):
             return (jnp.ones if init == "ones" else jnp.zeros)(
                 shape, jnp.float32)
+        k = jax.random.fold_in(key, i)
+        if init == "a_log":     # a head's decay rate, exp(a_log) in [1, 16)
+            return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
+        if init == "dt_bias":   # inverse softplus of a step in [1e-3, 1e-1)
+            dt = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+            return dt + jnp.log(-jnp.expm1(-dt))
         if arch:
             scale = cfg.init_std
         elif name in ("embed", "pos"):
@@ -266,8 +431,7 @@ def init_params(key, cfg: TxConfig) -> Dict[str, Any]:
         else:   # fan-in of the seed's block: the first non-layer dim
             scale = 1.0 / np.sqrt(shape[1] if name in (
                 "wqkv", "w1", "w2") else shape[0])
-        return jax.random.normal(jax.random.fold_in(key, i), shape,
-                                 jnp.float32) * scale
+        return jax.random.normal(k, shape, jnp.float32) * scale
 
     params: Dict[str, Any] = {"layers": {}}
     for i, path in enumerate(leaf_order(cfg)):
@@ -281,17 +445,14 @@ def init_params(key, cfg: TxConfig) -> Dict[str, Any]:
 
 def param_specs(cfg: TxConfig) -> Dict[str, Any]:
     """PartitionSpec per leaf: heads, FFN hidden and held experts on the
-    model axis, the rest replicated (layer axis first, never split)."""
-    M = MODEL_AXIS
-    split = {"wqkv": P(None, None, None, M, None), "wo": P(None, M, None, None),
-             "wq": P(None, None, M, None), "wk": P(None, None, M, None),
-             "wv": P(None, None, M, None),
-             "w1": P(None, None, M), "b1": P(None, M), "w2": P(None, M, None),
-             "we_gate": P(None, M, None, None), "we_up": P(None, M, None, None),
-             "we_down": P(None, M, None, None)}
-    shapes = _leaf_shapes(cfg)
-    specs = {k: P() for k in shapes if k != "layers"}
-    specs["layers"] = {k: split.get(k, P()) for k in shapes["layers"]}
+    model axis, the rest replicated (stacking axes first, never split)."""
+    specs = {k: P() for k in _leaf_shapes(cfg) if k != "layers"}
+    specs["layers"] = {}
+    for kind, leaves in _layer_leaves(cfg).items():
+        lead = (None,) * len(_stacking(cfg, kind))
+        for name, (shape, _, dim) in leaves.items():
+            specs["layers"][name] = P() if dim is None else P(*lead, *(
+                MODEL_AXIS if i == dim else None for i in range(len(shape))))
     return specs
 
 
@@ -299,6 +460,16 @@ def param_specs(cfg: TxConfig) -> Dict[str, Any]:
 
 def _rms(x, g, eps: float):
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+def _rms_whole(x, g, eps: float, ax: Axes):
+    """One RMSNorm over the whole projection x (B, T, heads, D), weight
+    ``g`` (heads, D): the mean of squares is over every channel HELD
+    (on a model axis, over the axis; what absent heads would add to it
+    is left out, as the deployment's one chip cannot know it)."""
+    ss = _psum((x * x).sum((-2, -1), keepdims=True), ax.model)
+    n = x.shape[-2] * x.shape[-1] * _axis_size(ax.model)
+    return x * jax.lax.rsqrt(ss / n + eps) * g
 
 
 def _norm(cfg: TxConfig, x, g, b=None):
@@ -475,8 +646,13 @@ def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
     if cfg.qk_norm:
         q = _rms(q, lyr["q_norm"], cfg.norm_eps)
         k = _rms(k, lyr["k_norm"], cfg.norm_eps)
+    if cfg.qk_norm_whole:
+        q = _rms_whole(q, lyr["q_norm"], cfg.norm_eps, ax)
+        k = _rms_whole(k, lyr["k_norm"], cfg.norm_eps, ax)
     if cfg.rope_theta:
         q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
+    if ax.seq is not None and _axis_size(ax.seq) == 1:
+        ax = ax._replace(seq=None)       # a row is whole here: no ring
     blocks = _query_blocks(cfg, ax, h.shape[1])
     if blocks is not None:
         ix = None
@@ -511,6 +687,132 @@ def _attention(cfg: TxConfig, ax: Axes, h, lyr, pos):
                                  causal=cfg.causal))
         stats = jnp.zeros(3)
     return jnp.einsum("bthe,hed->btd", o, lyr["wo"]), stats
+
+
+#: Tokens a pass of the linear mixer's loop holds (tiling only; whole
+#: chunks): 256 read 71.2 ms a layer forward + backward on the v5e
+#: against 75.3 at 512 and 81 at 1,024 (PERF.md section 5, PR 36).
+_LINEAR_BLOCK = 256
+
+#: Precision of the delta rule's products (the in-chunk transform and
+#: the products with the carried state); the state, the transform and
+#: every sum in them are float32 whatever this says.
+_DELTA_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _delta_block(q, k, v, g, beta, state, C: int):
+    """The gated delta rule over one block of whole chunks, chunk by
+    chunk. Per head, with ``alpha_t = exp(g_t)``, the recurrence is
+    ``S_t = alpha_t S_{t-1} + k_t (beta_t (v_t - (alpha_t S_{t-1})^T
+    k_t))^T``, ``o_t = S_t^T q_t``. A chunk of ``C`` tokens with
+    ``G_i = g_1 + .. + g_i`` turns it into matrix products: ``A = tril(
+    diag(beta) K K^T * exp(G_i - G_j), -1)``; ``[W, U] = (I + A)^-1
+    [beta * exp(G) * K, beta * V]`` (the in-chunk transform, a unit
+    lower-triangular solve); then with the state ``S`` the chunk starts
+    from, ``V' = U - W S``, ``O = (Q * exp(G)) S + tril(Q K^T * exp(G_i
+    - G_j)) V'``, ``S <- exp(G_C) S + (K * exp(G_C - G))^T V'``. The
+    transforms of the block's chunks are computed together; the walk
+    over its chunks is unrolled.
+
+    q, k (B, S, H, dk) (q scaled, both L2-normed); v (B, S, H, dv); g,
+    beta (B, S, H); state (B, H, dk, dv); ``S`` a multiple of ``C``.
+    Returns ``(o (B, S, H, dv), state after the block, largest |state|
+    a chunk of it ended on)``, all float32."""
+    B, S, H, dk = q.shape
+    n = S // C
+    dot = partial(jnp.einsum, precision=_DELTA_PRECISION)
+
+    def chunks(x):             # (B, S, H, ...) -> (B, H, n, C, ...)
+        x = x.reshape((B, n, C, H) + x.shape[3:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-1)                             # (B, H, n, C)
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    decay = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :],
+                              -jnp.inf))                   # i >= j, else 0
+    kk = dot("bhnik,bhnjk->bhnij", k, k)
+    A = jnp.where(jnp.tril(lower, -1), beta[..., None] * kk * decay, 0.0)
+    rhs = jnp.concatenate([(beta * jnp.exp(G))[..., None] * k,
+                           beta[..., None] * v], axis=-1)
+    wu = jax.lax.linalg.triangular_solve(
+        A + jnp.eye(C, dtype=A.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    W, U = wu[..., :dk], wu[..., dk:]
+    qk = dot("bhnik,bhnjk->bhnij", q, k) * decay
+    q_in = q * jnp.exp(G)[..., None]
+    k_out = k * jnp.exp(G[..., -1:] - G)[..., None]
+    out, peak = [], state[0, 0, 0, 0] * 0.0
+    for c in range(n):
+        v_new = U[:, :, c] - dot("bhik,bhkv->bhiv", W[:, :, c], state)
+        out.append(dot("bhik,bhkv->bhiv", q_in[:, :, c], state)
+                   + dot("bhij,bhjv->bhiv", qk[:, :, c], v_new))
+        state = (jnp.exp(G[:, :, c, -1])[..., None, None] * state
+                 + dot("bhik,bhiv->bhkv", k_out[:, :, c], v_new))
+        peak = jnp.maximum(peak, jnp.abs(jax.lax.stop_gradient(state)).max())
+    o = jnp.stack(out, axis=2)                             # (B, H, n, C, dv)
+    return jnp.moveaxis(o, 1, 3).reshape(B, S, H, -1), state, peak
+
+
+def _linear_attention(cfg: TxConfig, ax: Axes, h, lyr):
+    """The linear mixer on ``h`` (B, T, d): projections, then ONE loop
+    over blocks of ``_LINEAR_BLOCK`` tokens that carries the (B, heads,
+    key dim, value dim) float32 state and holds everything from the
+    depthwise conv to the gated norm (a trace tells the mixer's core by
+    that carried shape), then the output projection. The loop's body is
+    rematerialised in the backward pass, which differentiates through
+    it. Returns ``(out (B, T, d) before the model-axis reduce, the
+    largest |state| reached)``."""
+    B, T, _ = h.shape
+    dk, dv, K, C = (cfg.linear_key_dim, cfg.linear_value_dim,
+                    cfg.linear_conv, cfg.linear_chunk)
+    H = lyr["la_wq"].shape[1]                    # this model shard's heads
+    u = jnp.concatenate([jnp.einsum("btd,dhe->bthe", h, lyr[w])
+                         for w in ("la_wq", "la_wk", "la_wv")], axis=-1)
+    z = jnp.einsum("btd,dhe->bthe", h, lyr["la_wz"])
+    ba = jnp.stack([jnp.einsum("btd,dh->bth", h, lyr[w])
+                    for w in ("la_wb", "la_wa")], axis=-1)
+    # Whole chunks: the row padded with zero inputs at its end, which
+    # write nothing into a state that no later token reads.
+    n = -(-T // C)
+    S = C * _chunk(n, max(1, _LINEAR_BLOCK // C))
+    pad = [(0, 0), (0, n * C - T)]
+    u, z, ba = (jnp.pad(x, pad + [(0, 0)] * (x.ndim - 2)).reshape(
+        (B, n * C // S, S) + x.shape[2:]).swapaxes(0, 1) for x in (u, z, ba))
+    conv = jnp.concatenate([lyr["la_cq"], lyr["la_ck"], lyr["la_cv"]], -1)
+    rate = -jnp.exp(lyr["la_a_log"])
+
+    def l2(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    def block(carry, xs):
+        state, tail, peak = carry
+        u_b, z_b, ba_b = xs
+        seen = jnp.concatenate([tail, u_b], axis=1)       # K - 1 earlier
+        c = jax.nn.silu(sum(conv[j] * seen[:, j:j + S] for j in range(K)))
+        q = l2(c[..., :dk]) * dk ** -0.5
+        k, v = l2(c[..., dk:2 * dk]), c[..., 2 * dk:]
+        beta = jax.nn.sigmoid(ba_b[..., 0]) * (
+            2.0 if cfg.linear_neg_eigval else 1.0)
+        g = rate * jax.nn.softplus(ba_b[..., 1] + lyr["la_dt_bias"])
+        o, state, top = _delta_block(q, k, v, g, beta, state, C)
+        y = _rms(o, lyr["la_gn_g"], cfg.norm_eps) * jax.nn.silu(z_b)
+        return (state, seen[:, S:], jnp.maximum(peak, top)), y
+
+    zero = u[0, 0, 0, 0, 0] * 0.0     # varies over the mesh as the inputs do
+    start = (jnp.zeros((B, H, dk, dv), jnp.float32) + zero,
+             jnp.zeros((B, K - 1, H, 2 * dk + dv), jnp.float32) + zero,
+             zero)
+    (_, _, peak), y = jax.lax.scan(jax.checkpoint(block), start, (u, z, ba))
+    y = y.swapaxes(0, 1).reshape(B, n * C, H, dv)[:, :T]
+    return jnp.einsum("bthe,hed->btd", y, lyr["la_wo"]), peak
+
+
+def _gated_mlp(h, lyr):
+    """``(silu(h Wg) * (h Wu)) Wd`` before the model-axis reduce."""
+    a = jnp.einsum("btd,df->btf", h, lyr["w_gate"])
+    b = jnp.einsum("btd,df->btf", h, lyr["w_up"])
+    return jnp.einsum("btf,fd->btd", jax.nn.silu(a) * b, lyr["w_down"])
 
 
 def _experts(cfg: TxConfig, ax: Axes, h, lyr):
@@ -560,13 +862,19 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
     ``(x (B, T_local, d), aux)``; ``aux``: ``attn`` (3,) [index loss
     summed over queries and layers, keys kept, short queries], ``moe``
     (3,) [routed, absent, dropped] and ``experts`` (held_local,) counts,
-    all summed over layers and over this shard's rows."""
+    all summed over layers and over this shard's rows; with linear
+    layers also ``state_absmax``, the largest of theirs."""
     seq_size = _axis_size(ax.seq)
     if cfg.indexer_heads and seq_size > 1:
         raise ValueError(
             f"the indexer's top-{cfg.indexer_topk} selection does not run "
             f"across a sequence axis of {seq_size}: keys chosen per query "
             "cannot ride the ring; use a mesh whose seq axis is 1")
+    if "L" in cfg.pattern and seq_size > 1:
+        raise ValueError(
+            f"the linear layer's state does not run across a sequence axis "
+            f"of {seq_size}: a shard's state would have to be handed to the "
+            "next; use a mesh whose seq axis is 1")
     Tl = tokens.shape[1]
     if Tl * seq_size > cfg.max_len:
         # Caught at trace time (both values static): an out-of-range
@@ -576,29 +884,82 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
             f"{cfg.max_len}")
     pos = _axis_index(ax.seq) * Tl + jnp.arange(Tl)
     x = params["embed"][tokens]
-    if not cfg.rope_theta:
+    if "pos" in params:
         x = x + params["pos"][pos][None, :, :]
+    pre = not cfg.post_norm
 
-    def layer_fn(x, lyr):
-        h = _norm(cfg, x, lyr["ln1_g"], lyr.get("ln1_b"))
-        out, attn = _attention(cfg, ax, h, lyr, pos)
-        x = x + _psum(out, ax.model)               # row-parallel reduce
-        h = _norm(cfg, x, lyr["ln2_g"], lyr.get("ln2_b"))
+    def mixer(x, lyr, kind):
+        """``x`` after the layer's first sublayer, and its counters."""
+        g, b = ("la_ln_g", "la_ln_b") if kind == "L" else ("ln1_g", "ln1_b")
+        h = _norm(cfg, x, lyr[g], lyr.get(b)) if pre else x
+        if kind == "L":
+            out, peak = _linear_attention(cfg, ax, h, lyr)
+            aux = {"attn": jnp.zeros(3), "state_absmax": peak}
+        else:
+            out, attn = _attention(cfg, ax, h, lyr, pos)
+            aux = {"attn": attn}
+            if "L" in cfg.pattern:       # every layer reports the same keys
+                aux["state_absmax"] = jnp.zeros(())
+        if pre:
+            return x + _psum(out, ax.model), aux       # row-parallel reduce
+        return x + _norm(cfg, _psum(out, ax.model), lyr[g], lyr.get(b)), aux
+
+    def layer_fn(x, lyr, kind="F"):
+        x, aux = mixer(x, lyr, kind)
+        h = _norm(cfg, x, lyr["ln2_g"], lyr.get("ln2_b")) if pre else x
         if cfg.n_experts:
             out, counts, moe = _experts(cfg, ax, h, lyr)
-            x = x + _psum(out, ax.model)
+        elif cfg.gated_width:
+            out = _gated_mlp(h, lyr)
         else:
             ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lyr["w1"])
                              + lyr["b1"])
-            x = x + _psum(jnp.einsum("btf,fd->btd", ff, lyr["w2"]),
-                          ax.model) + lyr["b2"]
+            out = jnp.einsum("btf,fd->btd", ff, lyr["w2"])
+        out = _psum(out, ax.model)
+        if not pre:
+            out = _norm(cfg, out + lyr.get("b2", 0.0), lyr["ln2_g"],
+                        lyr.get("ln2_b"))
+        x = x + out
+        if pre and "b2" in lyr:
+            x = x + lyr["b2"]
+        if not cfg.n_experts:
             counts, moe = jnp.zeros(1), jnp.zeros(3)
-        return x, {"attn": attn, "moe": moe, "experts": counts}
+        return x, dict(aux, moe=moe, experts=counts)
 
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
-    x, aux = jax.lax.scan(layer_fn, x, params["layers"])
-    return x, jax.tree.map(lambda a: a.sum(0), aux)
+    if not cfg.pattern:              # every layer is the same layer
+        if cfg.remat:
+            layer_fn = jax.checkpoint(layer_fn)
+        x, aux = jax.lax.scan(layer_fn, x, params["layers"])
+        return x, _fold(aux)
+
+    kinds = _layer_leaves(cfg)
+    layer = jax.checkpoint(layer_fn, static_argnums=(2,)) if cfg.remat \
+        else layer_fn
+
+    def period_fn(x, per):
+        """One period, its layers one after another: each takes its
+        slice of its kind's leaves and of every layer's. (A scan over a
+        run of one kind would compile that kind once, but it slices the
+        stacked MLP leaves into copies: 4 GB more at the hybrid cell's
+        size, which then does not fit the chip.)"""
+        auxes, nth = [], {"F": 0, "L": 0}
+        for j, kind in enumerate(cfg.pattern):
+            lyr = {k: per[k][nth[kind]] for k in kinds[kind]}
+            lyr.update({k: per[k][j] for k in kinds["*"]})
+            nth[kind] += 1
+            x, aux = layer(x, lyr, kind)
+            auxes.append(aux)
+        return x, _fold(jax.tree.map(lambda *a: jnp.stack(a), *auxes))
+
+    x, aux = jax.lax.scan(period_fn, x, params["layers"])
+    return x, _fold(aux)
+
+
+def _fold(aux: Dict[str, Any]) -> Dict[str, Any]:
+    """Layers' counters, stacked on a leading axis, into one: sums, and
+    the largest ``state_absmax`` of the linear layers."""
+    return {k: a.max(0) if k == "state_absmax" else a.sum(0)
+            for k, a in aux.items()}
 
 
 def _class_logits(params, x, cfg: TxConfig, ax: Axes):
@@ -677,11 +1038,16 @@ def make_loss_fn(cfg: TxConfig, mesh: Mesh, with_aux: bool = False):
                "keys_kept": attn[1], "queries_short": attn[2],
                "moe": jax.lax.psum(aux["moe"], both),
                "experts": jax.lax.psum(aux["experts"], both)}
+        if "state_absmax" in aux:
+            out["state_absmax"] = jax.lax.pmax(
+                jax.lax.stop_gradient(aux["state_absmax"]), ax)
         return main + index, out
 
     aux_specs = {"loss_main": P(), "loss_index": P(), "keys_kept": P(),
                  "queries_short": P(), "moe": P(),
                  "experts": P(MODEL_AXIS) if cfg.n_experts else P()}
+    if "L" in cfg.pattern:
+        aux_specs["state_absmax"] = P()
 
     def loss_fn(params, tokens, labels):
         loss, aux = jax.shard_map(
@@ -706,11 +1072,13 @@ def make_train_step(cfg: TxConfig, mesh: Mesh, opt: optax.GradientTransformation
 
 
 def group_norms(grads) -> Dict[str, Any]:
-    """L2 norm of the gradient per ``GRAD_GROUPS`` group."""
+    """L2 norm of the gradient per ``GRAD_GROUPS`` group; the second
+    sublayer's norm counts with the sublayer it norms."""
+    ln2 = "mlp" if "w_gate" in grads["layers"] else "experts"
     sq: Dict[str, Any] = {}
     for name, g in list(grads["layers"].items()) + [
             (k, v) for k, v in grads.items() if k != "layers"]:
-        grp = GRAD_GROUPS[name]
+        grp = ln2 if name in ("ln2_g", "ln2_b") else GRAD_GROUPS[name]
         sq[grp] = sq.get(grp, 0.0) + jnp.sum(jnp.square(g))
     return {k: jnp.sqrt(v) for k, v in sq.items()}
 

@@ -13,7 +13,11 @@ the attention kernels of the ``tx`` family's query blocks at
 Keye-VL-2.0's widths (rows of 8,192 tokens, 32 query / 4 key-value heads
 of 128, blocks of 128 queries), forward and backward, and at the default
 block of 512 queries with 8 and with 16 heads a key-value head, where
-the kernels' shape rule sizes the key block to VMEM.
+the kernels' shape rule sizes the key block to VMEM, and at
+Olmo-Hybrid's full layer (15 held key-value heads of 128 with one query
+head each). And one whole program: the training step of the benchmark's
+``olmo-hybrid-7b`` configuration (one period, rows of 8,192 tokens, 15
+heads held), whose state and temporaries have to fit the chip.
 Between them the histogram cases take every form of the bin one-hot the
 kernel's shape rule can choose (``pk._tree_hist_kernel``): one compare a
 128-column group with four features a group (32 bins), with one feature
@@ -139,14 +143,14 @@ def _tsne_rows():
 
 
 def _chosen_attention(backward: bool, heads: int = H_TX, chunk: int = C_TX,
-                      key_block: int = 512):
+                      key_block: int = 512, groups: int = G_TX):
     """One query block of ``transformer._chosen_attention`` on the
     kernels; the backward as the block's rematerialised forward and its
     transpose, as the step runs it. ``key_block``: what the shape rule
-    is to choose for ``heads`` over ``G_TX`` key-value heads and a block
-    of ``chunk`` queries."""
+    is to choose for ``heads`` over ``groups`` key-value heads and a
+    block of ``chunk`` queries."""
     assert pk.chosen_attn_key_block(T_TX, chunk, D_TX,
-                                    heads // G_TX) == key_block
+                                    heads // groups) == key_block
 
     def grads(q, k, v, chosen, block):
         return jax.grad(lambda q, k, v: pk.chosen_attention(
@@ -154,8 +158,8 @@ def _chosen_attention(backward: bool, heads: int = H_TX, chunk: int = C_TX,
 
     return (grads if backward else pk.chosen_attention,
             [((chunk, heads, D_TX), jnp.float32),
-             ((T_TX, G_TX * D_TX), jnp.float32),
-             ((T_TX, G_TX * D_TX), jnp.float32),
+             ((T_TX, groups * D_TX), jnp.float32),
+             ((T_TX, groups * D_TX), jnp.float32),
              ((chunk, T_TX), jnp.bool_), ((), jnp.int32)])
 
 
@@ -173,6 +177,12 @@ CASES = {
         lambda: _chosen_attention(False, 64, 512, 128),
     "chosen_attention-backward-16heads-a-group-512queries":
         lambda: _chosen_attention(True, 64, 512, 128),
+    # Olmo-Hybrid's full layer on one chip of two: multi-head attention,
+    # R = 1, 15 of the 30 heads held, blocks of 1,024 queries.
+    "chosen_attention-forward-1head-a-group-15groups-1024queries":
+        lambda: _chosen_attention(False, 15, 1024, 512, 15),
+    "chosen_attention-backward-1head-a-group-15groups-1024queries":
+        lambda: _chosen_attention(True, 15, 1024, 512, 15),
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
     "tree_histogram-48bins-straddling": lambda: _hist(48),
@@ -319,3 +329,74 @@ def test_tree_predict_compiles_on_four_chips(family, topo, chip_compiler):
     compiled = fn.program.lower(params, X, max_depth=DEPTH,
                                 mesh=mesh).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
+    """The training step of the benchmark's ``olmo-hybrid-7b``
+    configuration as the cell POSTs it (one period LLLF, rows of 8,192
+    tokens, 15 of 30 heads held, MLP 11,008 whole, 12,544 vocabulary
+    rows), compiled for the described v5e: its state and its temporaries
+    together are under the device's memory, and its loops are named as
+    the cell's device-trace metrics expect them (the linear mixers'
+    block loops by the carried state, three a linear layer: forward,
+    rematerialised forward, backward; the full layer's query-block
+    loops), none matched by the other's pattern."""
+    import json
+    import os
+    import re
+
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from learningorchestra_tpu.config import Settings
+    from learningorchestra_tpu.models import transformer as tx
+    from learningorchestra_tpu.parallel.mesh import local_mesh
+
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench")
+    with open(os.path.join(bench, "configs", "olmo-hybrid-7b.json")) as fh:
+        conf = json.load(fh)
+    with open(os.path.join(bench, "peaks.json")) as fh:
+        hbm = json.load(fh)["devices"]["TPU v5 lite"]["hbm_bytes"]
+    hp, rows = conf["families"]["tx"], conf["data"]["seq_len"]
+    cfg = tx.TxConfig(
+        vocab=hp["vocab"], d_model=hp["d_model"], n_heads=hp["n_heads"],
+        n_layers=hp["n_layers"], n_classes=conf["data"]["num_classes"],
+        max_len=rows, causal=hp["causal"], remat=hp["remat"], **hp["arch"])
+    settings = Settings()
+    settings.mesh_shape = "1,1,1"
+    mesh = local_mesh(settings, devices=topo.devices[:1])
+    init, step = tx.make_fit_programs(cfg, mesh, optax.adam(hp["lr"]),
+                                      hp["batch"])
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    held = sum(a.size for a in jax.tree.leaves(state[0]))
+    assert held == conf["state"]["parameters"]
+    n = conf["data"]["n_train"]
+    compiled = step.lower(
+        placed(state), placed(jax.eval_shape(jax.random.PRNGKey, 0)),
+        jax.ShapeDtypeStruct((n, rows), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rep)).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
+    assert total < hbm, (total, mem.temp_size_in_bytes)
+    loops = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " while(" in ln]
+
+    def matched(metric):
+        with open(os.path.join(bench, "layer_metrics",
+                               metric + ".json")) as fh:
+            patterns = json.load(fh)["ops"]
+        return [ln for ln in loops if any(re.search(p, ln) for p in patterns)]
+
+    core = matched("linear_attn_s.hybridfit")
+    full = matched("full_attn_s.hybridfit")
+    assert len(core) == 3 * cfg.pattern.count("L")
+    assert len(full) >= 2 and not set(core) & set(full)
+    assert "tpu_custom_call" in compiled.as_text()      # the full layer's
