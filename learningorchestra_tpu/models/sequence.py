@@ -46,8 +46,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.transformer import (
-    MESH_AXES, NO_AXES, TxConfig, attention_path, forward_reference,
-    has_options, make_fit_programs)
+    MESH_AXES, NO_AXES, TxConfig, attention_path, delta_path,
+    forward_reference, has_options, make_fit_programs)
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, MeshRuntime)
 from learningorchestra_tpu.utils import tracing
@@ -192,7 +192,8 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
                                  MESH_AXES._replace(seq=None), T_pad // S)}
     if cfg.pattern:
         attrs.update(layer_pattern=cfg.pattern, heads_held=cfg.heads,
-                     linear_chunk=cfg.linear_chunk)
+                     linear_chunk=cfg.linear_chunk,
+                     **delta_path(cfg, MESH_AXES))
     with tracing.span("fit.tx.steps", attrs):
         reports = []
         for _ in range(int(train_steps)):
@@ -245,7 +246,8 @@ def predictor(hparams: dict):
 
     def timed(params, X):
         with tracing.span("fit.tx.predict", rows=int(X.shape[0]),
-                          **attention_path(cfg, NO_AXES, cfg.max_len)):
+                          **attention_path(cfg, NO_AXES, cfg.max_len),
+                          **delta_path(cfg, NO_AXES)):
             return jax.block_until_ready(proba(params, X))
 
     return timed

@@ -41,7 +41,16 @@ and not a second model file:
   ``linear_chunk`` tokens at a time (within a chunk the ``(I +
   tril(diag(beta) K K^T * decay))^-1`` transform, across chunks a loop
   that carries the state); the benchmark's reference runs the
-  recurrence token by token;
+  recurrence token by token. The transform's inverse is BUILT, in
+  float32 and by exact algebra, not handed to XLA's serial solver:
+  for a chunk of whole groups of 8 rows up to 64 by
+  ``ops/pallas_kernels.py:delta_transform`` (blocked forward
+  substitution, every system of a block in the lanes of one kernel
+  call), for any other length by block recursion in plain
+  ``jax.numpy`` (``_block_inverse``: diagonal blocks of 16 by forward
+  substitution, merged 16 -> 32 -> 64 by batched products, the
+  kernel's oracle); then one product with the right-hand side, and in
+  the backward two (``_chunk_transform``);
 - ``gated_width``: a gated MLP without bias, ``(silu(h Wg) * (h Wu))
   Wd``; ``post_norm``: the norm sits on a sublayer's OUTPUT, ``x +
   norm(f(x))`` (OLMo 2's order); ``qk_norm_whole``: one RMSNorm over the
@@ -700,6 +709,99 @@ _LINEAR_BLOCK = 256
 _DELTA_PRECISION = jax.lax.Precision.HIGHEST
 
 
+#: Side of the diagonal blocks the chunk transform inverts by forward
+#: substitution; larger blocks are merged from these on the MXU.
+_TRANSFORM_BASE = 16
+
+
+def _diagonal_blocks(A, s: int):
+    """The (s, s) diagonal blocks of A (..., C, C): (..., C // s, s, s)."""
+    m = A.shape[-1] // s
+    A = A.reshape(A.shape[:-2] + (m, s, m, s))
+    return jnp.stack([A[..., r, :, r, :] for r in range(m)], axis=-3)
+
+
+def _block_inverse(A):
+    """``(I + A)^-1`` of strictly lower-triangular ``A`` (..., C, C) by
+    block recursion, exact algebra in float32 (entries of ``A`` on or
+    above the diagonal are not read). The diagonal blocks of
+    ``min(C, _TRANSFORM_BASE)`` are inverted by forward substitution,
+    row ``i`` of a block's inverse being ``e_i - sum_{j<i} A[i, j] *
+    row_j``, all blocks of all systems at once; then pairs of
+    neighbouring blocks are merged, 16 -> 32 -> 64 -> .., with ``inv([[P,
+    0], [R, Q]]) = [[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]`` as batched
+    products at ``_DELTA_PRECISION``. A ``C`` that is not the base times
+    a power of two is padded to the next such size with identity rows,
+    which invert to themselves."""
+    C = A.shape[-1]
+    s = size = min(C, _TRANSFORM_BASE)
+    while size < C:
+        size *= 2
+    if size > C:
+        A = jnp.pad(A, [(0, 0)] * (A.ndim - 2) + [(0, size - C)] * 2)
+    dot = partial(jnp.matmul, precision=_DELTA_PRECISION)
+    D = _diagonal_blocks(A, s)
+    eye = jnp.eye(s, dtype=A.dtype)
+    rows = []
+    for i in range(s):
+        rows.append(sum((-D[..., i, j, None] * rows[j] for j in range(i)),
+                        jnp.broadcast_to(eye[i], D.shape[:-1])))
+    T = jnp.stack(rows, axis=-2)                       # (..., size // s, s, s)
+    while s < size:
+        R = _diagonal_blocks(A, 2 * s)[..., s:, :s]
+        P, Q = T[..., 0::2, :, :], T[..., 1::2, :, :]
+        X = -dot(dot(Q, R), P)
+        T = jnp.concatenate([
+            jnp.concatenate([P, jnp.zeros_like(P)], axis=-1),
+            jnp.concatenate([X, Q], axis=-1)], axis=-2)
+        s *= 2
+    return T[..., 0, :C, :C]
+
+
+def _inverse(A):
+    """``(I + A)^-1`` of the chunks' ``A`` (..., C, C) by what the static
+    chunk length admits: ``pk.delta_transform``'s one kernel call, else
+    the plain recursion, the kernel's oracle."""
+    if pk.delta_transform_fits(A.shape[-1], bool(jax.typeof(A).vma)):
+        return pk.delta_transform(A)
+    return _block_inverse(A)
+
+
+@jax.custom_vjp
+def _chunk_transform(A):
+    """``T = (I + A)^-1`` of the chunks' strictly lower-triangular ``A``
+    (``_inverse``). Differentiated as the inverse it is, ``dA = -tril(
+    T^T dT T^T, -1)``: two batched products, no pass back through the
+    substitution."""
+    return _inverse(A)
+
+
+def _chunk_transform_fwd(A):
+    T = _inverse(A)
+    return T, T
+
+
+def _chunk_transform_bwd(T, dT):
+    dot = partial(jnp.matmul, precision=_DELTA_PRECISION)
+    Tt = jnp.swapaxes(T, -1, -2)
+    return (-jnp.tril(dot(dot(Tt, dT), Tt), -1),)
+
+
+_chunk_transform.defvjp(_chunk_transform_fwd, _chunk_transform_bwd)
+
+
+def delta_path(cfg: TxConfig, ax: Axes) -> Dict[str, Any]:
+    """``_inverse``'s choice as a span attribute: ``delta_transform`` =
+    ``"block_inverse_kernel"`` where the linear layers' chunk transform
+    runs ``pk.delta_transform``, ``"block_inverse"`` where the plain
+    recursion. Empty without a linear layer."""
+    if "L" not in cfg.pattern:
+        return {}
+    kernel = pk.delta_transform_fits(cfg.linear_chunk, any(ax))
+    return {"delta_transform":
+            "block_inverse_kernel" if kernel else "block_inverse"}
+
+
 def _delta_block(q, k, v, g, beta, state, C: int):
     """The gated delta rule over one block of whole chunks, chunk by
     chunk. Per head, with ``alpha_t = exp(g_t)``, the recurrence is
@@ -707,12 +809,13 @@ def _delta_block(q, k, v, g, beta, state, C: int):
     k_t))^T``, ``o_t = S_t^T q_t``. A chunk of ``C`` tokens with
     ``G_i = g_1 + .. + g_i`` turns it into matrix products: ``A = tril(
     diag(beta) K K^T * exp(G_i - G_j), -1)``; ``[W, U] = (I + A)^-1
-    [beta * exp(G) * K, beta * V]`` (the in-chunk transform, a unit
-    lower-triangular solve); then with the state ``S`` the chunk starts
-    from, ``V' = U - W S``, ``O = (Q * exp(G)) S + tril(Q K^T * exp(G_i
-    - G_j)) V'``, ``S <- exp(G_C) S + (K * exp(G_C - G))^T V'``. The
-    transforms of the block's chunks are computed together; the walk
-    over its chunks is unrolled.
+    [beta * exp(G) * K, beta * V]`` (the in-chunk transform: ``T = (I +
+    A)^-1`` is built by blocks, ``_chunk_transform``, then one product
+    with the right-hand side; no call to XLA's solver); then with the
+    state ``S`` the chunk starts from, ``V' = U - W S``, ``O = (Q *
+    exp(G)) S + tril(Q K^T * exp(G_i - G_j)) V'``, ``S <- exp(G_C) S +
+    (K * exp(G_C - G))^T V'``. The transforms of the block's chunks are
+    computed together; the walk over its chunks is unrolled.
 
     q, k (B, S, H, dk) (q scaled, both L2-normed); v (B, S, H, dv); g,
     beta (B, S, H); state (B, H, dk, dv); ``S`` a multiple of ``C``.
@@ -735,9 +838,7 @@ def _delta_block(q, k, v, g, beta, state, C: int):
     A = jnp.where(jnp.tril(lower, -1), beta[..., None] * kk * decay, 0.0)
     rhs = jnp.concatenate([(beta * jnp.exp(G))[..., None] * k,
                            beta[..., None] * v], axis=-1)
-    wu = jax.lax.linalg.triangular_solve(
-        A + jnp.eye(C, dtype=A.dtype), rhs, left_side=True, lower=True,
-        unit_diagonal=True)
+    wu = dot("bhnij,bhnjd->bhnid", _chunk_transform(A), rhs)
     W, U = wu[..., :dk], wu[..., dk:]
     qk = dot("bhnik,bhnjk->bhnij", q, k) * decay
     q_in = q * jnp.exp(G)[..., None]

@@ -59,13 +59,24 @@ ops XLA alone schedules sub-optimally. Residents:
   working set is the scoped VMEM each call asks for; a group too tall
   for the smallest runs the plain body.
 
+- **The delta rule's chunk transform** (models/transformer.py
+  `_chunk_transform`: `T = (I + A)^-1` of the unit-lower-triangular
+  systems of a block of the gated delta-rule mixer, 60 of 64x64 a
+  256-token block at Olmo-Hybrid's widths). XLA's triangular solve
+  inverts them in a serial custom call, 400 us a block and pass on the
+  v5e; the plain block recursion is exact but two dozen small fusions.
+  `delta_transform` puts the systems in lanes (row, column, system) and
+  runs blocked forward substitution on the VPU in one call: groups of 8
+  rows, a group finished among its own rows and then taken out of every
+  later row, rows read only as far as they are non-zero.
+
 On non-TPU backends every `pallas_call` runs in interpreter mode, so the
 same code path is unit-tested on the CPU mesh (tests/conftest.py) and
 cross-checked against the pure-XLA reference implementation.
 
 Each `pallas_call` carries a `name=` (`tsne_repulsion`, `tree_hist`,
 `tree_hist_stacked`, `tree_route`, `tree_descend`, `chosen_attn_fwd`,
-`chosen_attn_probs`, `chosen_attn_bwd`): it becomes the
+`chosen_attn_probs`, `chosen_attn_bwd`, `delta_transform`): it becomes the
 innermost name scope, XLA names the custom-call instruction after it
 (`%tree_hist.3 = ... custom_call_target="tpu_custom_call"`), and that
 instruction text is the event's name on a device profile's `XLA Ops` line — which is all the
@@ -1011,3 +1022,94 @@ def chosen_attention(q, k, v, chosen, block):
     o, probs = _chosen_attn(qg, k, v, chosen.astype(jnp.int8), last)
     return (o.reshape(G, R, C, D).transpose(2, 0, 1, 3).reshape(C, H, D),
             probs)
+
+
+# --- the delta rule's chunk transform ---------------------------------------
+
+#: Longest chunk ``delta_transform`` takes: a (C, C, 128) float32 block
+#: in and one out, each double-buffered, are 8 MiB at C 64, inside the
+#: 16 MiB of scoped VMEM a call has without asking.
+_DELTA_MAX_CHUNK = 64
+
+
+def delta_transform_fits(C: int, on_mesh: bool = False) -> bool:
+    """Whether ``delta_transform`` takes chunks of ``C`` tokens: whole
+    sublane groups of 8 rows, at most ``_DELTA_MAX_CHUNK``. Off the TPU
+    the kernel runs in interpret mode, which cannot type a broadcast of
+    values that vary over mesh axes: an operand ``on_mesh`` (inside
+    ``shard_map``) is the plain form's there."""
+    return (C % 8 == 0 and 8 <= C <= _DELTA_MAX_CHUNK
+            and not (on_mesh and _interpret()))
+
+
+def _delta_transform_kernel(a_ref, t_ref):
+    """``T = (I + A)^-1`` of 128 unit-lower-triangular systems at once,
+    one system a lane: ``a_ref``, ``t_ref`` (C, C, 128), row, column,
+    system. Blocked forward substitution over groups of 8 rows, all of
+    it float32 on the VPU: ``T`` starts as ``I``; a group's rows are
+    finished among themselves (row ``i`` less ``A[i, j] * row_j`` for
+    the group's earlier ``j``), then taken out of every later row. Row
+    ``j`` of ``T`` is zero past column ``j``, so a group's rows are
+    read and updated only as far as the group's last column. Entries of
+    ``A`` on or above the diagonal are never read."""
+    C, _, L = a_ref.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C, L), 1)
+    t_ref[...] = (row == col).astype(jnp.float32)
+
+    def less(i, first, count, width, live=None):
+        """Row ``i`` less ``A[i, j] * row_j`` for the ``count`` rows from
+        ``first`` (static), the first ``live`` of them where given (a
+        traced count), over the first ``width`` sublane groups."""
+        acc = [t_ref[i, 8 * p:8 * p + 8, :] for p in range(width)]
+        for n, j in enumerate(range(first, first + count)):
+            a = a_ref[i, j:j + 1, :]
+            if live is not None:
+                a = jnp.where(n < live, a, 0.0)
+            a = jnp.broadcast_to(a, (8, L))
+            for p in range(width):
+                acc[p] = acc[p] - a * t_ref[j, 8 * p:8 * p + 8, :]
+        for p in range(width):
+            t_ref[i, 8 * p:8 * p + 8, :] = acc[p]
+
+    for g in range(C // 8):
+        def own(r, carry, g=g):        # rows 1..7 of the group, in turn
+            less(8 * g + r, 8 * g, 7, g + 1, live=r)
+            return carry
+
+        def later(i, carry, g=g):
+            less(i, 8 * g, 8, g + 1)
+            return carry
+
+        jax.lax.fori_loop(1, 8, own, 0)
+        jax.lax.fori_loop(8 * (g + 1), C, later, 0)
+
+
+def delta_transform(A):
+    """``(I + A)^-1`` of strictly lower-triangular ``A`` (..., C, C),
+    float32, for chunk lengths ``delta_transform_fits`` admits: the
+    systems (a 256-token block of Olmo-Hybrid's mixer has 60 of 64x64)
+    ride the lanes of ONE kernel call, which replaces the fifteen
+    substitution steps, four batched products and the slicing between
+    them that the plain recursion (``models/transformer.py:
+    _block_inverse``, this kernel's oracle) costs XLA a block and pass.
+    Not differentiable here: the caller holds the ``custom_vjp``."""
+    lead, C = A.shape[:-2], A.shape[-1]
+    N = A.size // (C * C)
+    Np = -(-N // _LANES) * _LANES
+    a = jnp.pad(jnp.moveaxis(A.reshape(N, C, C), 0, -1),
+                [(0, 0), (0, 0), (0, Np - N)])
+    spec = pl.BlockSpec((C, C, _LANES), lambda n: (0, 0, n))
+    t = pl.pallas_call(
+        _delta_transform_kernel,
+        grid=(Np // _LANES,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((C, C, Np), jnp.float32,
+                                       vma=jax.typeof(A).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret(),
+        name="delta_transform",
+    )(a)
+    return jnp.moveaxis(t[:, :, :N], -1, 0).reshape(lead + (C, C))

@@ -163,6 +163,12 @@ def _chosen_attention(backward: bool, heads: int = H_TX, chunk: int = C_TX,
              ((chunk, T_TX), jnp.bool_), ((), jnp.int32)])
 
 
+def _delta_transform():
+    # One 256-token block of Olmo-Hybrid's linear mixer on this chip: 15
+    # held heads x 4 chunks of 64 tokens, 60 systems a call.
+    return pk.delta_transform, [((1, 15, 4, 64, 64), jnp.float32)]
+
+
 CASES = {
     "chosen_attention-forward": lambda: _chosen_attention(False),
     "chosen_attention-backward": lambda: _chosen_attention(True),
@@ -183,6 +189,7 @@ CASES = {
         lambda: _chosen_attention(False, 15, 1024, 512, 15),
     "chosen_attention-backward-1head-a-group-15groups-1024queries":
         lambda: _chosen_attention(True, 15, 1024, 512, 15),
+    "delta_transform": _delta_transform,
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
     "tree_histogram-48bins-straddling": lambda: _hist(48),
@@ -211,6 +218,7 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
 #: The name each case's kernel carries on a device profile's ``XLA Ops``
 #: line (``tree_leaf_stats`` shares the histogram's ``pallas_call``).
 KERNEL_NAMES = {
+    "delta_transform": "delta_transform",
     "tree_histogram-32bins": "tree_hist",
     "tree_histogram-256bins": "tree_hist",
     "tree_histogram-48bins-straddling": "tree_hist",
@@ -261,17 +269,24 @@ def test_kernel_is_named_in_the_compiled_module(case, one_chip,
         assert all(re.search(pattern, ln) for ln in calls)
 
 
-@pytest.mark.parametrize("case,kernels", [
-    ("chosen_attention-forward", ["chosen_attn_fwd", "chosen_attn_probs"]),
-    ("chosen_attention-backward", ["chosen_attn_fwd", "chosen_attn_bwd"]),
+@pytest.mark.parametrize("case,kernels,metrics", [
+    ("chosen_attention-forward", ["chosen_attn_fwd", "chosen_attn_probs"],
+     ("sparse_attn_s.txfit", "sparse_attn_roofline.txfit", "moe_s.txfit")),
+    ("chosen_attention-backward", ["chosen_attn_fwd", "chosen_attn_bwd"],
+     ("sparse_attn_s.txfit", "sparse_attn_roofline.txfit", "moe_s.txfit")),
+    ("delta_transform", ["delta_transform"],
+     ("linear_attn_s.hybridfit", "linear_attn_roofline.hybridfit",
+      "full_attn_s.hybridfit")),
 ])
-def test_attention_kernels_are_counted_once(case, kernels, one_chip,
+def test_attention_kernels_are_counted_once(case, kernels, metrics, one_chip,
                                             chip_compiler):
     """The attention kernels run INSIDE the query-block loops that
     ``sparse_attn_s.txfit``'s first pattern matches, and the reader sums
     its matches: a kernel there whose own name held ``sparse_attn``
     would be counted twice. Each is named for what it is, and neither
-    of the metric's patterns (nor the expert layer's) finds it."""
+    of the metric's patterns (nor the expert layer's) finds it. The
+    same holds for the chunk transform's kernel inside the linear
+    mixers' block loops and ``linear_attn_s.hybridfit``'s patterns."""
     import json
     import os
     import re
@@ -285,8 +300,7 @@ def test_attention_kernels_are_counted_once(case, kernels, one_chip,
     assert len(calls) == len(kernels)
     for ln, kernel in zip(calls, kernels):
         assert re.match(r"(ROOT )?%[^ ]*" + kernel + r"[^ ]* = ", ln), ln[:80]
-    for metric in ("sparse_attn_s.txfit", "sparse_attn_roofline.txfit",
-                   "moe_s.txfit"):
+    for metric in metrics:
         with open(os.path.join(os.path.dirname(__file__), os.pardir,
                                "perfbench", "layer_metrics",
                                metric + ".json")) as fh:
@@ -386,8 +400,12 @@ def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
     assert total < hbm, (total, mem.temp_size_in_bytes)
-    loops = [ln.strip() for ln in compiled.as_text().splitlines()
-             if " while(" in ln]
+    text = compiled.as_text()
+    # PR 37: the chunk transform is built by blocks; XLA's triangular
+    # solve (a serial ``InvertDiagBlocksLowerTriangular`` custom call a
+    # block and pass) is in the program no more.
+    assert not re.search(r"InvertDiagBlocks|triangular[-_]solve", text)
+    loops = [ln.strip() for ln in text.splitlines() if " while(" in ln]
 
     def matched(metric):
         with open(os.path.join(bench, "layer_metrics",
@@ -397,6 +415,12 @@ def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
 
     core = matched("linear_attn_s.hybridfit")
     full = matched("full_attn_s.hybridfit")
+    # The transform's kernel: once in each of a linear layer's loops
+    # (the backward loop rematerialises its block's forward, then takes
+    # the transform's cotangent through two products).
+    assert len(re.findall(r"%[^ ]*delta_transform[^ ]* = [^\n]*"
+                          r'custom_call_target="tpu_custom_call"', text)) \
+        == len(core)
     assert len(core) == 3 * cfg.pattern.count("L")
     assert len(full) >= 2 and not set(core) & set(full)
-    assert "tpu_custom_call" in compiled.as_text()      # the full layer's
+    assert "tpu_custom_call" in text                    # the full layer's

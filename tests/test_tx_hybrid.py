@@ -15,6 +15,7 @@ import pytest
 from learningorchestra_tpu.config import Settings
 from learningorchestra_tpu.models import transformer as tx
 from learningorchestra_tpu.models.registry import validate_hparams
+from learningorchestra_tpu.ops import pallas_kernels as pk
 from learningorchestra_tpu.parallel.mesh import local_mesh
 from perfbench import reference_hybrid as R
 
@@ -166,6 +167,92 @@ def test_delta_block_carries_its_state(neg):
             tops.append(float(top))
     assert close(jnp.concatenate(got), want, 1e-5)
     assert 0 < max(tops) <= float(peak) * (1 + 1e-5)
+
+
+def oracle_transform(A):
+    """``(I + A)^-1`` by XLA's triangular solve: what the program called
+    until PR 37, and the block-built transform's oracle since."""
+    eye = jnp.eye(A.shape[-1], dtype=A.dtype)
+    return jax.lax.linalg.triangular_solve(
+        A + eye, jnp.broadcast_to(eye, A.shape), left_side=True,
+        lower=True, unit_diagonal=True)
+
+
+@pytest.mark.parametrize("form", ["as-run", "plain"])
+@pytest.mark.parametrize("keys", ["random", "nearly-equal"])
+@pytest.mark.parametrize("chunk", [16, 24, 32, 64, 128])
+def test_chunk_transform_is_the_triangular_solve(chunk, keys, form):
+    """The in-chunk transform alone, ``T = (I + A)^-1``, and its
+    gradient with respect to what ``A`` is made of (k, g, beta; beta up
+    to 2), against the triangular solve and autodiff through it. As the
+    program runs it (``_chunk_transform``: the kernel's blocked
+    substitution up to chunks of 64, here in interpret mode, the plain
+    recursion at 128; the backward two products), and the plain block
+    recursion itself under autodiff (24 is padded to 32 there). The hard
+    case: a chunk of nearly equal keys that hardly decays, so that ``A``
+    is nearly ``beta_i`` everywhere under the diagonal and a column of
+    ``T`` does not die out below it."""
+    assert pk.delta_transform_fits(chunk) == (chunk <= 64)
+    transform = tx._chunk_transform if form == "as-run" else tx._block_inverse
+    rng = np.random.default_rng(chunk)
+    H, n, dk = 3, 2, 8
+    if keys == "random":
+        k = rng.normal(size=(H, n, chunk, dk))
+        g = -rng.uniform(0.001, 1.5, (H, n, chunk))
+        beta = rng.uniform(0, 2, (H, n, chunk))
+    else:
+        k = rng.normal(size=(H, n, 1, dk)) + 0.01 * rng.normal(
+            size=(H, n, chunk, dk))
+        g = -rng.uniform(1e-4, 1e-3, (H, n, chunk))
+        beta = rng.uniform(1.0, 2.0, (H, n, chunk))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    k, g, beta = (jnp.asarray(a, jnp.float32) for a in (k, g, beta))
+    cot = jnp.asarray(rng.normal(size=(H, n, chunk, chunk)), jnp.float32)
+    under = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+
+    def through(transform):
+        def f(k, g, beta):
+            G = jnp.cumsum(g, axis=-1)
+            A = jnp.where(under, beta[..., None] * jnp.einsum(
+                "hnik,hnjk->hnij", k, k) * jnp.exp(jnp.where(
+                    under, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+            T = transform(A)
+            return (T * cot).sum(), T
+        return jax.jit(jax.value_and_grad(f, (0, 1, 2), has_aux=True))
+
+    with jax.default_matmul_precision("highest"):
+        (_, T_p), g_p = through(transform)(k, g, beta)
+        (_, T_o), g_o = through(oracle_transform)(k, g, beta)
+    assert T_p.shape == T_o.shape == (H, n, chunk, chunk)
+    assert close(T_p, T_o, 1e-5)
+    assert float(jnp.abs(jnp.triu(T_p, 1)).max()) == 0.0
+    np.testing.assert_array_equal(
+        np.asarray(jnp.diagonal(T_p, axis1=-2, axis2=-1)), 1.0)
+    if keys == "nearly-equal":      # the last row still feels the first
+        assert float(jnp.abs(T_o[..., -1, 0]).min()) > 0.0
+    for got, want in zip(g_p, g_o):
+        assert close(got, want, 1e-4)
+        assert float(jnp.abs(want).max()) > 0
+
+
+@pytest.mark.parametrize("chunk,fits", [
+    (64, True), (8, True), (24, True), (128, False), (12, False), (4, False)])
+def test_which_chunk_lengths_ride_the_transform_kernel(monkeypatch, chunk,
+                                                       fits):
+    """Whole groups of 8 rows up to 64; off the TPU (interpret mode) not
+    where the operand varies over a mesh. The span attribute says what
+    ``_inverse`` chose."""
+    cfg = config("L", 1, linear_chunk=chunk)
+    name = {True: "block_inverse_kernel", False: "block_inverse"}
+    assert pk.delta_transform_fits(chunk) == fits
+    assert not pk.delta_transform_fits(chunk, on_mesh=True)
+    assert tx.delta_path(cfg, tx.NO_AXES) == {"delta_transform": name[fits]}
+    assert tx.delta_path(cfg, tx.MESH_AXES) == {
+        "delta_transform": "block_inverse"}
+    monkeypatch.setattr(pk, "_interpret", lambda: False)     # as on the TPU
+    assert pk.delta_transform_fits(chunk, on_mesh=True) == fits
+    assert tx.delta_path(cfg, tx.MESH_AXES) == {"delta_transform": name[fits]}
+    assert tx.delta_path(config("F", 1), tx.MESH_AXES) == {}
 
 
 # --- the whole model against the reference ----------------------------------
@@ -443,6 +530,12 @@ def test_rest_fit_with_the_hybrid_block(served):
     assert steps["layer_pattern"] == "LLLF" and steps["heads_held"] == 2
     assert steps["linear_chunk"] == 8 and steps["state_absmax"] > 0
     assert steps["attn_kernel"] == 0.0          # heads of 8: the plain body
+    # Off the TPU a fit's transform (inside the mesh program) is the
+    # plain recursion; the unsharded predict pass rides the kernel.
+    assert steps["delta_transform"] == "block_inverse"
+    predict = next(d for d in tracing.recent_span_docs()
+                   if d["name"] == "fit.tx.predict")["attrs"]
+    assert predict["delta_transform"] == "block_inverse_kernel"
     assert app._metrics_doc()["tx"]["state_absmax"] > 0
 
 
