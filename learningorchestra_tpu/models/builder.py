@@ -339,10 +339,12 @@ class ModelBuilder:
                     # an otherwise successful fit's predictions; surface it
                     # in the persisted metrics instead.
                     try:
-                        with tracing.span(f"fit.{c}.finish.model"):
+                        phase = f"fit.{c}.finish.model"
+                        with tracing.span(phase):
                             self.registry.save(
                                 f"{prediction_name}_{c}", model,
-                                metrics=report.metrics, preprocess=pp_meta)
+                                metrics=report.metrics, preprocess=pp_meta,
+                                phase=phase)
                     except Exception as exc:  # noqa: BLE001 — isolation
                         report.metrics["persist_error"] = (
                             f"{type(exc).__name__}: {exc}")
@@ -464,9 +466,10 @@ class ModelBuilder:
                             # (a gate >1 admits concurrent families) —
                             # overlapped windows record peaks only,
                             # never a double-counted compile_s.
+                            phase: Dict[str, Any] = {}
                             with Timer() as td, \
-                                    resources.family_phase(c) as phase, \
-                                    tracing.span(f"fit.{c}.dispatch", phase):
+                                    tracing.span(f"fit.{c}.dispatch", phase), \
+                                    resources.family_phase(c, phase):
                                 model = dispatch_fit(c, extra)
                             pre_s = prep_s + td.elapsed
                             probs, device_s = collect_fit(c, model, pre_s)
@@ -540,8 +543,9 @@ class ModelBuilder:
                     # pass's via collect_fit's device_span. This loop is
                     # sequential, so these windows never overlap and
                     # always attribute.
-                    with resources.family_phase(c) as phase, \
-                            tracing.span(f"fit.{c}.dispatch", phase):
+                    phase: Dict[str, Any] = {}
+                    with tracing.span(f"fit.{c}.dispatch", phase), \
+                            resources.family_phase(c, phase):
                         model = dispatch_fit(c, extra)
                         # No-op on TPU (stream order keeps back-to-back
                         # programs aligned); fences the CPU test rig,
@@ -707,12 +711,13 @@ class ModelBuilder:
                                 num_classes, **dict(hp, **extra))
             if self.cfg.persist_models:
                 try:
-                    self.registry.save(
-                        out_name, model,
-                        metrics={"mean_score":
-                                 board["winner"]["mean_score"],
-                                 "tuned": True},
-                        preprocess=pp_meta)
+                    with tracing.span("tune.promote.model"):
+                        self.registry.save(
+                            out_name, model,
+                            metrics={"mean_score":
+                                     board["winner"]["mean_score"],
+                                     "tuned": True},
+                            preprocess=pp_meta, phase="tune.promote.model")
                     board["promoted"] = out_name
                 except Exception as exc:  # noqa: BLE001 — best-effort
                     board["promote_error"] = (

@@ -37,6 +37,7 @@ from learningorchestra_tpu.catalog.store import validate_name
 from learningorchestra_tpu.config import Settings
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.registry import predictor_for
+from learningorchestra_tpu.utils import tracing
 
 
 #: Parameter trees at least this large are written flat (module doc).
@@ -69,12 +70,19 @@ def _flat_leaves(tree: Any, prefix: str = "") -> Optional[List[Tuple[str, Any]]]
     return out
 
 
-def _write_flat(d: str, leaves: List[Tuple[str, Any]]) -> None:
+def _write_flat(d: str, leaves: List[Tuple[str, Any]], phase: str) -> None:
+    """Each leaf's wait, write and sync is a span under ``phase``:
+    ``.fetch`` the part of its device-to-host copy not yet done,
+    ``.write`` its copy into the page cache, ``.sync`` each
+    ``fdatasync`` and the last ``fsync``."""
+    fetch, write, sync = (f"{phase}.{p}" for p in ("fetch", "write", "sync"))
     index, offset = [], 0
     with open(os.path.join(d, "params.bin"), "wb") as f:
         for path, leaf in leaves:
-            arr = np.ascontiguousarray(np.asarray(leaf))
-            f.write(arr.reshape(-1).view(np.uint8).data)
+            with tracing.span(fetch):
+                arr = np.ascontiguousarray(np.asarray(leaf))
+            with tracing.span(write):
+                f.write(arr.reshape(-1).view(np.uint8).data)
             index.append({"path": path, "dtype": arr.dtype.name,
                           "shape": list(arr.shape), "offset": offset})
             offset += arr.nbytes
@@ -82,10 +90,12 @@ def _write_flat(d: str, leaves: List[Tuple[str, Any]]) -> None:
                 # The disk takes this leaf while the next ones are
                 # still on their way from the device: the save costs
                 # the slower of the two, not their sum at the end.
-                f.flush()
-                os.fdatasync(f.fileno())
-        f.flush()
-        os.fsync(f.fileno())
+                with tracing.span(sync):
+                    f.flush()
+                    os.fdatasync(f.fileno())
+        with tracing.span(sync):
+            f.flush()
+            os.fsync(f.fileno())
     with open(os.path.join(d, "params.json"), "w") as f:
         json.dump({"leaves": index}, f)
 
@@ -160,7 +170,13 @@ class ModelRegistry:
 
     def save(self, name: str, model: TrainedModel,
              metrics: Optional[Dict[str, float]] = None,
-             preprocess: Optional[Dict[str, Any]] = None) -> None:
+             preprocess: Optional[Dict[str, Any]] = None,
+             phase: str = "model.save") -> None:
+        """Persist ``model`` under ``name``. ``phase`` is the caller's
+        span around the save: the waits for the device, the writes and
+        the syncs are its ``.fetch`` / ``.write`` / ``.sync`` children
+        (``_write_flat``; on the checkpoint layer's path one ``.fetch``
+        and one ``.write``)."""
         import orbax.checkpoint as ocp
 
         d = self._dir(name)
@@ -176,7 +192,8 @@ class ModelRegistry:
                 if total >= FLAT_BYTES and isinstance(model.params, dict)
                 else None)
         if flat is None:
-            params = jax.tree.map(np.asarray, model.params)
+            with tracing.span(f"{phase}.fetch"):
+                params = jax.tree.map(np.asarray, model.params)
         else:
             # Every leaf's device-to-host copy is started before the
             # first is waited for: gigabytes overlap instead of queueing
@@ -202,10 +219,11 @@ class ModelRegistry:
                     shutil.rmtree(p)
             os.makedirs(tmp)
             if flat is not None:
-                _write_flat(tmp, flat)
+                _write_flat(tmp, flat, phase)
             else:
-                ocp.PyTreeCheckpointer().save(
-                    os.path.join(tmp, "params"), params)
+                with tracing.span(f"{phase}.write"):
+                    ocp.PyTreeCheckpointer().save(
+                        os.path.join(tmp, "params"), params)
             manifest = {
                 "name": name,
                 "kind": model.kind,

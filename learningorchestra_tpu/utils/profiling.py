@@ -28,7 +28,7 @@ import bisect
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 from learningorchestra_tpu.utils import tracing
 
@@ -180,14 +180,16 @@ def device_span(fn, name: str):
 
     from learningorchestra_tpu.utils import resources
 
-    with resources.device_phase(name) as phase:
-        # The span sits INSIDE the sampling window so its extent stays
-        # the pure dispatch-to-completion figure (the sampling reads at
-        # window exit never inflate device_s); the phase's figures land
-        # in its attributes after it closed (recorded by reference).
-        with tracing.span(name, phase) as sp:
-            t0 = time.time()
+    # The span holds the sampling window, so the window's exit read of
+    # device bytes (a chip's memory_stats; on the CPU rig a walk of
+    # every live array) is this phase's time and not a gap between a
+    # family's phases; its figures land in the span's attributes
+    # (recorded by reference).
+    phase: Dict[str, Any] = {}
+    with tracing.span(name, phase) as sp:
+        t0 = time.time()
+        with resources.device_phase(name, phase):
             out = jax.block_until_ready(fn())
-            dur = time.time() - t0
-            _pin(sp, dur)
+        dur = time.time() - t0
+        _pin(sp, dur)
     return out, dur

@@ -420,7 +420,8 @@ _overlapped_phases: set = set()
 
 
 @contextmanager
-def device_phase(name: Optional[str]):
+def device_phase(name: Optional[str],
+                 phase: Optional[Dict[str, Any]] = None):
     """The one device-phase sampling window, shared by ``family_phase``
     and ``profiling.device_span``: compile-seconds delta (None when the
     window overlapped another phase — attribution would double-count)
@@ -428,10 +429,14 @@ def device_phase(name: Optional[str]):
     :func:`observe_device_phase`. Exception-transparent — a failing
     phase still records what it consumed before dying.
 
-    Yields a dict that gains ``compiles`` and ``compile_s`` at exit
-    (both None for an overlapped window): the builder hands it to the
-    phase's span as its attributes, which are recorded by reference, so
-    a trace of a warm-up sweep says which step compiled."""
+    Yields ``phase`` (a new dict when None), which gains ``compiles``
+    and ``compile_s`` at exit (both None for an overlapped window): the
+    caller opens the phase's span around the window with that dict as
+    its attributes, recorded by reference, so a trace of a warm-up sweep
+    says which step compiled, and the exit's sample is the span's own
+    time (on the CPU rig it walks every live array: milliseconds in a
+    process that holds thousands) and not a gap between a family's
+    phases."""
     ensure_listener()
     token = object()
     with _lock:
@@ -440,7 +445,7 @@ def device_phase(name: Optional[str]):
             _overlapped_phases.add(token)
         _open_phases.add(token)
         n0, c0 = _compile["compiles"], _compile["compile_s"]
-    phase: Dict[str, Any] = {}
+    phase = {} if phase is None else phase
     try:
         yield phase
     finally:
@@ -459,10 +464,10 @@ def device_phase(name: Optional[str]):
             pass
 
 
-def family_phase(family: str):
+def family_phase(family: str, phase: Optional[Dict[str, Any]] = None):
     """Wrap one classifier family's dispatch region (models/builder.py);
     see :func:`device_phase` for the attribution rules."""
-    return device_phase(f"fit.{family}.device")
+    return device_phase(f"fit.{family}.device", phase)
 
 
 @contextmanager

@@ -40,9 +40,9 @@ Design (stdlib-only, like lolint):
 Recording is cheap by construction: one ``os.urandom`` id + a dict and
 a deque-append under a short lock per span, no I/O, no serialization
 until a ``/traces`` read. The serving hot path adds ~4 spans per traced
-request, a four-family sweep 47; PERF.md section 6 (PR 27) has
-the cost measured on the chip: nanoseconds per span with and without a
-running profile, and the traced sweep against the untraced one.
+request, a four-family sweep 55 (eight of them the saves' waits and
+writes), a ``tx`` fit of Keye's cell 49 more for its save; PERF.md
+section 6 has the cost measured on the chip (PR 27, PR 38).
 """
 
 from __future__ import annotations

@@ -203,3 +203,142 @@ def test_models_wait_only_for_their_own_name(built, monkeypatch):
     for new, old in zip(jax.tree.leaves(model.params), old_leaves):
         np.testing.assert_array_equal(new, np.asarray(old) + 1.0)
     assert reg.version("ptm_lr") > old_version
+
+
+# -- the save's spans (ISSUE 38) ---------------------------------------------
+
+class _NumpyWatch:
+    """``numpy`` as ``persistence`` sees it, with each ``asarray`` (the
+    wait for a leaf) noted in ``events``."""
+
+    def __init__(self, events):
+        self._events = events
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kwargs):
+        self._events.append(("wait",))
+        return np.asarray(a, *args, **kwargs)
+
+
+@pytest.fixture()
+def save_events(monkeypatch):
+    """Small flat saves (every tree flat, leaves of 2 KiB or more synced
+    one by one); the device-to-host copies started, the waits, and each
+    sync call with the file's size at that moment, in order."""
+    import os
+
+    import jax.numpy as jnp
+
+    from learningorchestra_tpu.models import persistence
+
+    monkeypatch.setattr(persistence, "FLAT_BYTES", 1)
+    monkeypatch.setattr(persistence, "_SYNC_BYTES", 2048)
+    events = []
+    monkeypatch.setattr(persistence, "np", _NumpyWatch(events))
+    array_cls = type(jnp.zeros(1))
+    copy = array_cls.copy_to_host_async
+
+    def noted_copy(self):
+        events.append(("copy",))
+        return copy(self)
+
+    monkeypatch.setattr(array_cls, "copy_to_host_async", noted_copy)
+    for call in ("fdatasync", "fsync"):
+        def noted_sync(fd, _call=call, _real=getattr(os, call)):
+            events.append((_call, os.fstat(fd).st_size))
+            return _real(fd)
+
+        monkeypatch.setattr(os, call, noted_sync)
+    return events
+
+
+def _flat_model():
+    import jax.numpy as jnp
+
+    from learningorchestra_tpu.models.base import TrainedModel
+
+    # Two leaves at or over the sync size (4,096 and 2,048 bytes), two
+    # under it, one of them already on the host.
+    params = {"a": {"w": jnp.arange(1024, dtype=jnp.float32).reshape(32, 32),
+                    "b": jnp.ones(8, jnp.float32)},
+              "c": jnp.full((16, 64), 2, jnp.bfloat16),
+              "d": np.arange(6, dtype=np.int32)}
+    return TrainedModel(kind="tx", params=params, predict_proba_fn=None,
+                        num_classes=2)
+
+
+def test_flat_save_spans_nest_under_the_callers_phase(cfg, save_events):
+    """Under a trace, one ``.fetch`` and one ``.write`` a leaf and one
+    ``.sync`` a sync call, all inside the caller's span; the save itself
+    unchanged: the same files byte for byte, the same syncs in the same
+    order, every copy started before the first wait. With no trace the
+    same save records nothing."""
+    import os
+
+    from learningorchestra_tpu.models import persistence
+    from learningorchestra_tpu.utils import tracing
+
+    reg = ModelRegistry(cfg)
+    phase = "fit.tx.finish.model"
+    with tracing.trace("root", sampled=True) as root, tracing.span(phase):
+        reg.save("sp_traced", _flat_model(), phase=phase)
+    traced = list(save_events)
+    save_events.clear()
+    recorded = tracing.counters_snapshot()["spans_recorded"]
+    reg.save("sp_plain", _flat_model(), phase=phase)
+    assert tracing.counters_snapshot()["spans_recorded"] == recorded
+    assert save_events == traced
+
+    syncs = [e for e in traced if e[0] in ("fdatasync", "fsync")]
+    assert [e[0] for e in syncs] == ["fdatasync", "fdatasync", "fsync"]
+    waits = [i for i, e in enumerate(traced) if e == ("wait",)]
+    copies = [i for i, e in enumerate(traced) if e == ("copy",)]
+    assert len(copies) == 3 and len(waits) == 4 and max(copies) < min(waits)
+
+    by_name = {}
+    for s in tracing.spans_for(root.trace_id):
+        by_name.setdefault(s["name"], []).append(s)
+    (parent,) = by_name[phase]
+    parts = {p: by_name.get(f"{phase}.{p}", [])
+             for p in ("fetch", "write", "sync")}
+    assert (len(parts["fetch"]), len(parts["write"]),
+            len(parts["sync"])) == (4, 4, len(syncs))
+    children = [s for group in parts.values() for s in group]
+    assert all(s["parent_id"] == parent["span_id"] for s in children)
+    # Docs round each span to the microsecond.
+    assert sum(s["duration_ms"] for s in children) <= \
+        parent["duration_ms"] + 1e-3 * len(children)
+
+    for f in ("params.bin", "params.json"):
+        with open(os.path.join(reg.root, "sp_traced", f), "rb") as a, \
+                open(os.path.join(reg.root, "sp_plain", f), "rb") as b:
+            assert a.read() == b.read(), f
+    back = persistence._read_flat(os.path.join(reg.root, "sp_traced"))
+    assert np.array_equal(back["a"]["w"], np.arange(
+        1024, dtype=np.float32).reshape(32, 32))
+
+
+def test_checkpoint_layer_save_is_one_fetch_and_one_write(cfg):
+    """A tree under ``FLAT_BYTES`` goes through the checkpoint layer:
+    one ``.fetch`` around bringing it to the host, one ``.write`` around
+    the checkpoint's save (its own syncs inside), no ``.sync``."""
+    import jax.numpy as jnp
+
+    from learningorchestra_tpu.models.base import TrainedModel
+    from learningorchestra_tpu.utils import tracing
+
+    model = TrainedModel(kind="nb", params={"m": jnp.ones((3, 2)),
+                                            "v": jnp.zeros(3)},
+                         predict_proba_fn=None, num_classes=2)
+    reg = ModelRegistry(cfg)
+    phase = "fit.nb.finish.model"
+    with tracing.trace("root", sampled=True) as root, tracing.span(phase):
+        reg.save("sp_orbax", model, phase=phase)
+    spans = tracing.spans_for(root.trace_id)
+    (parent,) = [s for s in spans if s["name"] == phase]
+    children = [s for s in spans if s["name"].startswith(phase + ".")]
+    assert sorted(s["name"] for s in children) == [
+        phase + ".fetch", phase + ".write"]
+    assert all(s["parent_id"] == parent["span_id"] for s in children)
