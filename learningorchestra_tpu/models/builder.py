@@ -39,9 +39,11 @@ span tree, each span opened around its work (so it also lies on a
 running device profile's timeline, utils/tracing.py): ``build`` >
 ``design.build``, ``fit.<c>`` > ``host_prep`` / ``gate_wait`` /
 ``dispatch`` / ``device`` / ``finish`` > ``score`` / ``model`` /
-``rows`` / ``store``, and ``build.tail``: the finishing left when the
-last family's device phase has ended, which the chip has to wait for
-(docs/observability.md has the table). Output
+``rows`` / ``store`` (a flat-written tree's leaves are staged under
+``fit.<c>.finish.model.stage`` while its ``device`` phase runs), and
+``build.tail``: the finishing left when the last family's device phase
+has ended, which the chip has to wait for (docs/observability.md has
+the table). Output
 contract is preserved: dataset ``<name>_<classifier>`` per classifier,
 metrics in its metadata.
 """
@@ -315,8 +317,18 @@ class ModelBuilder:
             jobs.heartbeat()
             return probs, device_s
 
+        def stage_model(c: str, model):
+            """Start writing the fitted weights to disk while the chip
+            runs the probability pass (``ModelRegistry.stage``: a tree
+            written flat, None for any other); ``finish_host`` commits
+            them."""
+            if not self.cfg.persist_models:
+                return None
+            return self.registry.stage(f"{prediction_name}_{c}", model,
+                                       phase=f"fit.{c}.finish.model")
+
         def finish_host(c: str, model, probs, fit_time: float,
-                        device_s: float) -> FitReport:
+                        device_s: float, staged=None) -> FitReport:
             """Metrics, model persistence, prediction dataset — everything
             host-side after the device programs complete. ``fit_time`` is
             the family's per-fit time: on the single-process pipeline,
@@ -324,7 +336,8 @@ class ModelBuilder:
             so the sum estimates the serialized sweep); on the pod
             batched round, the family's prep-to-probabilities wall span
             (spans overlap across families, so build wall-clock below
-            their sum is the overlap evidence)."""
+            their sum is the overlap evidence). ``staged``:
+            ``stage_model``'s handle, whose leaves the save commits."""
             with tracing.span(f"fit.{c}.finish"):
                 with tracing.span(f"fit.{c}.finish.score"):
                     preds = np.argmax(probs, axis=1)
@@ -337,14 +350,19 @@ class ModelBuilder:
                 if self.cfg.persist_models:
                     # Best-effort: a persistence failure must not discard
                     # an otherwise successful fit's predictions; surface it
-                    # in the persisted metrics instead.
+                    # in the persisted metrics instead. The span holds
+                    # what the staging left: the wait for its writer, the
+                    # manifest and the swap.
                     try:
                         phase = f"fit.{c}.finish.model"
-                        with tracing.span(phase):
+                        ahead = 0.0 if staged is None else staged.ahead_s()
+                        with tracing.span(
+                                phase, save_staged=staged is not None,
+                                save_ahead_s=round(ahead, 6)):
                             self.registry.save(
                                 f"{prediction_name}_{c}", model,
                                 metrics=report.metrics, preprocess=pp_meta,
-                                phase=phase)
+                                phase=phase, staged=staged)
                     except Exception as exc:  # noqa: BLE001 — isolation
                         report.metrics["persist_error"] = (
                             f"{type(exc).__name__}: {exc}")
@@ -376,7 +394,8 @@ class ModelBuilder:
                 hparams, X_train, X_test, state, feature_fields, streamed,
                 *stages)
         else:
-            reports = self._build_pipelined(classifiers, *stages)
+            reports = self._build_pipelined(classifiers, *stages,
+                                            stage_model)
         device_s = {r.kind: r.metrics["device_s"] for r in reports
                     if "device_s" in r.metrics}
         rp1 = readpipe.snapshot()
@@ -401,8 +420,8 @@ class ModelBuilder:
         return reports
 
     def _build_pipelined(self, classifiers, prep_fit, dispatch_fit,
-                         collect_fit, finish_host,
-                         fail_report) -> List[FitReport]:
+                         collect_fit, finish_host, fail_report,
+                         stage_model) -> List[FitReport]:
         """Single-process pipelined sweep (reference: 5-way
         ThreadPoolExecutor + FAIR pool, model_builder.py:95,160-176).
 
@@ -423,7 +442,11 @@ class ModelBuilder:
         compute, which is where the overlap win lives; on a single
         device (the production single-chip path) programs carry no
         cross-device rendezvous and up to ``max_concurrent_fits`` may
-        dispatch concurrently to keep the device queue fed."""
+        dispatch concurrently to keep the device queue fed.
+
+        A fit's weights start on their way to disk (``stage_model``) as
+        soon as its trainer returns, while the probability pass runs;
+        a family that fails after that drops what was staged."""
         n_dev = int(np.prod(list(self.runtime.mesh.shape.values())))
         gate = threading.BoundedSemaphore(
             max(1, int(self.cfg.max_concurrent_fits)) if n_dev == 1 else 1)
@@ -444,6 +467,7 @@ class ModelBuilder:
 
         def fit_guarded(c: str) -> FitReport:
             nonlocal device_done
+            staged = None
             with tracing.attach(parent_ctx), \
                     jobs.attach_job_record(job_rec):
                 try:
@@ -471,6 +495,7 @@ class ModelBuilder:
                                     tracing.span(f"fit.{c}.dispatch", phase), \
                                     resources.family_phase(c, phase):
                                 model = dispatch_fit(c, extra)
+                            staged = stage_model(c, model)
                             pre_s = prep_s + td.elapsed
                             probs, device_s = collect_fit(c, model, pre_s)
                         finally:
@@ -481,8 +506,11 @@ class ModelBuilder:
                         # the serialized sweep, and the gap to build
                         # wall-clock IS the overlap won.
                         return finish_host(c, model, probs,
-                                           pre_s + device_s, device_s)
+                                           pre_s + device_s, device_s,
+                                           staged)
                 except Exception as exc:  # noqa: BLE001 — per-model bound
+                    if staged is not None:
+                        staged.discard()
                     return fail_report(c, exc)
 
         with ThreadPoolExecutor(

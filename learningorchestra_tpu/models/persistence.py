@@ -15,6 +15,11 @@ bytes, ``params.bin``, beside an index ``params.json`` (each leaf's path,
 dtype, shape and offset): every byte is written once, uncompressed, and
 synced, where the checkpoint layer's chunked and compressed store took
 seconds that varied run to run. Any reader with numpy can read it back.
+Such a tree can be written ahead of its save: ``ModelRegistry.stage``
+writes the leaves to a staging directory of the version's own on a
+writer thread while the caller goes on (the builder: while the chip
+runs the probability pass), and ``save(..., staged=)`` waits for it,
+then writes the manifest and swaps the version in.
 
 ``ModelRegistry.load`` rebuilds a ``TrainedModel`` whose predictor comes
 from ``registry.predictor_for`` — so a persisted model predicts on any
@@ -70,6 +75,31 @@ def _flat_leaves(tree: Any, prefix: str = "") -> Optional[List[Tuple[str, Any]]]
     return out
 
 
+def _flat_of(params: Any) -> Optional[List[Tuple[str, Any]]]:
+    """The leaves of a tree the save writes flat (module doc): a dict
+    tree of ``FLAT_BYTES`` or more; None for any other."""
+    import jax
+
+    if not isinstance(params, dict):
+        return None
+    total = sum(int(getattr(leaf, "nbytes", 0))
+                for leaf in jax.tree.leaves(params))
+    return _flat_leaves(params) if total >= FLAT_BYTES else None
+
+
+def _start_copies(leaves: List[Tuple[str, Any]]) -> None:
+    """Every leaf's device-to-host copy is started before the first is
+    waited for: gigabytes overlap instead of queueing behind one
+    np.asarray after another. A small tree keeps its leaf-by-leaf
+    copies in ``save``: kilobytes, for which the chip showed no
+    difference either way."""
+    import jax
+
+    for _, leaf in leaves:
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+
+
 def _write_flat(d: str, leaves: List[Tuple[str, Any]], phase: str) -> None:
     """Each leaf's wait, write and sync is a span under ``phase``:
     ``.fetch`` the part of its device-to-host copy not yet done,
@@ -117,6 +147,61 @@ def _read_flat(d: str) -> Dict[str, Any]:
     return tree
 
 
+class StagedSave:
+    """One version of a model's leaves on its way to disk
+    (``ModelRegistry.stage``): every device-to-host copy started, then
+    ``_write_flat`` on a writer thread into ``.tmp.<name>.<unique>``,
+    a staging directory no other save shares, so the writer takes no
+    lock. Under the caller's trace its work is one
+    ``<phase>.stage`` span holding the ``.fetch`` / ``.write`` /
+    ``.sync`` spans. ``ModelRegistry.save(..., staged=)`` commits it;
+    ``discard`` drops it."""
+
+    def __init__(self, registry: "ModelRegistry", name: str,
+                 leaves: List[Tuple[str, Any]], phase: str):
+        self.dir: Optional[str] = None
+        self._error: Optional[Exception] = None
+        self._t0 = time.monotonic()
+        self._t1: Optional[float] = None
+        _start_copies(leaves)
+        ctx = tracing.current()
+
+        def write() -> None:
+            try:
+                with tracing.attach(ctx), tracing.span(f"{phase}.stage"):
+                    self.dir = registry._staging_dir(name)
+                    _write_flat(self.dir, leaves, phase)
+            except Exception as exc:  # noqa: BLE001 — join() re-raises it
+                self._error = exc
+            finally:
+                self._t1 = time.monotonic()
+
+        # thread-lifecycle: owner=StagedSave; exits when the leaves are
+        # written or the write failed (caught; join() re-raises it);
+        # joined by ModelRegistry.save(staged=) or discard().
+        self._writer = threading.Thread(target=write, daemon=True,
+                                        name=f"lo-model-stage-{name}")
+        self._writer.start()
+
+    def ahead_s(self) -> float:
+        """Seconds the staging has run so far, all of it once done."""
+        end = self._t1 if self._t1 is not None else time.monotonic()
+        return end - self._t0
+
+    def join(self) -> None:
+        """Wait for the writer; raise what it raised."""
+        self._writer.join()
+        if self._error is not None:
+            raise self._error
+
+    def discard(self) -> None:
+        """Wait for the writer and remove what it wrote. Nothing is left
+        to remove once ``save`` has swapped the version in."""
+        self._writer.join()
+        if self.dir is not None and os.path.isdir(self.dir):
+            shutil.rmtree(self.dir)
+
+
 class ModelRegistry:
     """Disk-backed registry of fitted models under ``store_root/_models``."""
 
@@ -160,6 +245,15 @@ class ModelRegistry:
         validate_name(name)
         return os.path.join(self.root, name)
 
+    def _staging_dir(self, name: str) -> str:
+        """A new empty ``.tmp.<name>.<unique>`` beside the live
+        directory: no two saves of a name share one. The leading dot
+        keeps it out of ``list()``, and a restart removes it."""
+        self._dir(name)
+        d = os.path.join(self.root, f".tmp.{name}.{tracing.new_id()}")
+        os.makedirs(d)
+        return d
+
     def _lock_of(self, name: str) -> threading.Lock:
         """Bound to a local called ``name_lock`` at every use: lolint's
         lock-blocking rule knows a held lock by its name."""
@@ -168,101 +262,120 @@ class ModelRegistry:
 
     # -- write ---------------------------------------------------------------
 
+    def stage(self, name: str, model: TrainedModel,
+              phase: str = "model.save") -> Optional[StagedSave]:
+        """Start writing ``model``'s leaves for a later
+        ``save(name, model, ..., staged=)``, which then only waits for
+        them, writes the manifest and swaps the version in. Only a tree
+        that is written flat is staged; for any other this returns None
+        and the caller saves as usual."""
+        leaves = _flat_of(model.params)
+        if leaves is None:
+            return None
+        return StagedSave(self, name, leaves, phase)
+
     def save(self, name: str, model: TrainedModel,
              metrics: Optional[Dict[str, float]] = None,
              preprocess: Optional[Dict[str, Any]] = None,
-             phase: str = "model.save") -> None:
+             phase: str = "model.save",
+             staged: Optional[StagedSave] = None) -> None:
         """Persist ``model`` under ``name``. ``phase`` is the caller's
         span around the save: the waits for the device, the writes and
         the syncs are its ``.fetch`` / ``.write`` / ``.sync`` children
         (``_write_flat``; on the checkpoint layer's path one ``.fetch``
-        and one ``.write``)."""
+        and one ``.write``). With ``staged`` (``stage``'s handle for
+        this name) the leaves are its, and a failed staging is raised
+        here: the previous version stays live."""
         import orbax.checkpoint as ocp
 
         d = self._dir(name)
-        # Replicated params → host numpy before checkpointing: keeps the
-        # save a process-local write under multi-process operation (orbax
-        # would otherwise coordinate a distributed save that only process 0
-        # participates in).
-        import jax
+        flat = params = None
+        if staged is None:
+            flat = _flat_of(model.params)
+            if flat is None:
+                # Replicated params → host numpy before checkpointing:
+                # keeps the save a process-local write under
+                # multi-process operation (orbax would otherwise
+                # coordinate a distributed save that only process 0
+                # participates in).
+                import jax
 
-        total = sum(int(getattr(leaf, "nbytes", 0))
-                    for leaf in jax.tree.leaves(model.params))
-        flat = (_flat_leaves(model.params)
-                if total >= FLAT_BYTES and isinstance(model.params, dict)
-                else None)
-        if flat is None:
-            with tracing.span(f"{phase}.fetch"):
-                params = jax.tree.map(np.asarray, model.params)
-        else:
-            # Every leaf's device-to-host copy is started before the
-            # first is waited for: gigabytes overlap instead of queueing
-            # behind one np.asarray after another. A small tree keeps
-            # its leaf-by-leaf copies above: kilobytes, for which the
-            # chip showed no difference either way.
-            for _, leaf in flat:
-                if isinstance(leaf, jax.Array):
-                    leaf.copy_to_host_async()
-        # Stage the whole new version in a sibling temp dir, then swap by
-        # rename: a re-save (hot-swap) must never leave a window where
-        # the model is missing — the online tier's version()/load() run
-        # concurrently with live /predict traffic, and a transient
-        # ModelNotFound maps to a terminal 404 at the client. Leading
-        # dot keeps stray dirs (crash mid-save) out of list(), which
-        # rejects names not starting with a letter or digit.
-        tmp = os.path.join(self.root, f".tmp.{name}")
-        old = os.path.join(self.root, f".old.{name}")
-        name_lock = self._lock_of(name)
-        with name_lock:
-            for p in (tmp, old):
-                if os.path.isdir(p):
-                    shutil.rmtree(p)
-            os.makedirs(tmp)
-            if flat is not None:
-                _write_flat(tmp, flat, phase)
+                with tracing.span(f"{phase}.fetch"):
+                    params = jax.tree.map(np.asarray, model.params)
             else:
-                with tracing.span(f"{phase}.write"):
-                    ocp.PyTreeCheckpointer().save(
-                        os.path.join(tmp, "params"), params)
-            manifest = {
-                "name": name,
-                "kind": model.kind,
-                "num_classes": model.num_classes,
-                "hparams": model.hparams,
-                "metrics": metrics or {},
-                "preprocess": preprocess,
-                "time_created": time.strftime("%Y-%m-%d %H:%M:%S"),
-            }
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                json.dump(manifest, f, indent=1)
-            # The swap itself: readers take the same name's lock, so the brief
-            # old→aside / tmp→live two-step is invisible to them.
-            man_path = os.path.join(d, "manifest.json")
-            prev = None
-            if os.path.isdir(d):
+                _start_copies(flat)
+        # The whole new version is staged in a sibling temp dir, then
+        # swapped in by rename: a re-save (hot-swap) must never leave a
+        # window where the model is missing — the online tier's
+        # version()/load() run concurrently with live /predict traffic,
+        # and a transient ModelNotFound maps to a terminal 404 at the
+        # client.
+        tmp: Optional[str] = None
+        old = os.path.join(self.root, f".old.{name}")
+        try:
+            if staged is not None:
+                staged.join()
+                tmp = staged.dir
+            name_lock = self._lock_of(name)
+            with name_lock:
+                if tmp is None:
+                    tmp = self._staging_dir(name)
+                    if flat is not None:
+                        _write_flat(tmp, flat, phase)
+                    else:
+                        with tracing.span(f"{phase}.write"):
+                            ocp.PyTreeCheckpointer().save(
+                                os.path.join(tmp, "params"), params)
+                if os.path.isdir(old):
+                    shutil.rmtree(old)
+                manifest = {
+                    "name": name,
+                    "kind": model.kind,
+                    "num_classes": model.num_classes,
+                    "hparams": model.hparams,
+                    "metrics": metrics or {},
+                    "preprocess": preprocess,
+                    "time_created": time.strftime("%Y-%m-%d %H:%M:%S"),
+                }
+                with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                    json.dump(manifest, f, indent=1)
+                # The swap itself: readers take the same name's lock, so
+                # the brief old→aside / tmp→live two-step is invisible
+                # to them.
+                man_path = os.path.join(d, "manifest.json")
+                prev = None
+                if os.path.isdir(d):
+                    try:
+                        pst = os.stat(man_path)
+                        prev = (pst.st_mtime_ns, pst.st_size)
+                    except OSError:
+                        pass
+                    os.rename(d, old)
+                os.rename(tmp, d)
+                if os.path.isdir(old):
+                    shutil.rmtree(old)
+                # version() tokens on (mtime_ns, size); on filesystems
+                # with coarse timestamps a fast re-save can land the
+                # same token and the online tier would silently keep
+                # serving the OLD params. Enforce strictly-INCREASING
+                # mtime across saves (not mere inequality with the
+                # previous token — that allows an ABA collision where
+                # save3 lands save1's token while the cache still holds
+                # save1's params).
                 try:
-                    pst = os.stat(man_path)
-                    prev = (pst.st_mtime_ns, pst.st_size)
+                    st = os.stat(man_path)
+                    if prev is not None and st.st_mtime_ns <= prev[0]:
+                        os.utime(man_path,
+                                 ns=(st.st_atime_ns, prev[0] + 1))
                 except OSError:
                     pass
-                os.rename(d, old)
-            os.rename(tmp, d)
-            if os.path.isdir(old):
-                shutil.rmtree(old)
-            # version() tokens on (mtime_ns, size); on filesystems with
-            # coarse timestamps a fast re-save can land the same token
-            # and the online tier would silently keep serving the OLD
-            # params. Enforce strictly-INCREASING mtime across saves
-            # (not mere inequality with the previous token — that
-            # allows an ABA collision where save3 lands save1's token
-            # while the cache still holds save1's params).
-            try:
-                st = os.stat(man_path)
-                if prev is not None and st.st_mtime_ns <= prev[0]:
-                    os.utime(man_path,
-                             ns=(st.st_atime_ns, prev[0] + 1))
-            except OSError:
-                pass
+        finally:
+            # A failed save leaves no staging behind; a saved one has
+            # none left.
+            if staged is not None:
+                staged.discard()
+            elif tmp is not None and os.path.isdir(tmp):
+                shutil.rmtree(tmp)
 
     # -- read ----------------------------------------------------------------
 

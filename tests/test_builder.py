@@ -244,3 +244,96 @@ def test_fillna_fits_on_train_only():
     _, state = apply_steps(train, steps)
     out, _ = apply_steps(test, steps, state=state)
     np.testing.assert_allclose(out["a"], [2.0, 10.0, 2.0])
+
+
+# -- the save staged while the probability pass runs (ISSUE 39) --------------
+
+TX_HP = {"tx": {"train_steps": 2, "batch": 16, "d_model": 16, "d_ff": 32,
+                "n_heads": 2}}
+
+
+def _tokens(store, name, n, seed, T=8, vocab=8):
+    rng = np.random.default_rng(seed)
+    cols = {f"t{j}": rng.integers(0, vocab, n).astype(np.int64)
+            for j in range(T)}
+    cols["label"] = (cols["t0"] < vocab // 2).astype(np.int64)
+    store.create(name, columns=cols, finished=True)
+
+
+@pytest.fixture()
+def staging_build(store, runtime, cfg, monkeypatch):
+    """A tx + nb build with model persistence on, where a tree of 4 KiB
+    or more (the tiny tx model's 18 KB, not nb's 136 bytes) is written
+    flat, and so staged."""
+    from learningorchestra_tpu.models import persistence
+
+    monkeypatch.setattr(persistence, "FLAT_BYTES", 4 << 10)
+    cfg.persist_models = True
+    _tokens(store, "sg_train", 64, 0)
+    _tokens(store, "sg_test", 16, 1)
+    return ModelBuilder(store, runtime, cfg)
+
+
+def test_large_tree_is_staged_during_the_probability_pass(staging_build):
+    """The tx model's leaves start for the disk before the probability
+    pass ends, and ``fit.tx.finish.model`` says so; nb's small tree
+    saves as before. Both models are saved whole."""
+    import os
+
+    from learningorchestra_tpu.utils import tracing
+
+    mb = staging_build
+    with tracing.trace("root", sampled=True) as root:
+        reports = mb.build("sg_train", "sg_test", "sg", ["tx", "nb"],
+                           "label", hparams=TX_HP)
+    assert all("error" not in r.metrics and "persist_error" not in r.metrics
+               for r in reports), [r.metrics for r in reports]
+    by_name = {}
+    for s in tracing.spans_for(root.trace_id):
+        by_name.setdefault(s["name"], []).append(s)
+    (tx_save,) = by_name["fit.tx.finish.model"]
+    assert tx_save["attrs"]["save_staged"] is True
+    assert tx_save["attrs"]["save_ahead_s"] > 0
+    (stage,) = by_name["fit.tx.finish.model.stage"]
+    (device,) = by_name["fit.tx.device"]
+    (fit,) = by_name["fit.tx"]
+    assert stage["parent_id"] == fit["span_id"]
+    assert stage["start"] < device["start"] + device["duration_ms"] / 1e3
+    assert all(s["parent_id"] == stage["span_id"]
+               for p in ("fetch", "write", "sync")
+               for s in by_name[f"fit.tx.finish.model.{p}"])
+    (nb_save,) = by_name["fit.nb.finish.model"]
+    assert nb_save["attrs"] == {"save_staged": False, "save_ahead_s": 0.0}
+    assert "fit.nb.finish.model.stage" not in by_name
+    assert sorted(m["name"] for m in mb.registry.list()) == ["sg_nb", "sg_tx"]
+    man, model = mb.registry.load("sg_tx")
+    assert man["metrics"]["accuracy"] == next(
+        r.metrics["accuracy"] for r in reports if r.kind == "tx")
+    assert sorted(os.listdir(mb.registry.root)) == ["sg_nb", "sg_tx"]
+
+
+def test_failed_probability_pass_leaves_no_model_and_no_staging(
+        staging_build, monkeypatch):
+    """A probability pass that raises after the staging started fails
+    the family as before: no persisted model, no staging directory;
+    the other family is untouched."""
+    import os
+
+    from learningorchestra_tpu.models import base
+
+    real = base.TrainedModel.predict_proba
+
+    def broken(self, runtime, X):
+        if self.kind == "tx":
+            raise RuntimeError("predict pass lost the device")
+        return real(self, runtime, X)
+
+    monkeypatch.setattr(base.TrainedModel, "predict_proba", broken)
+    mb = staging_build
+    reports = {r.kind: r for r in mb.build(
+        "sg_train", "sg_test", "sf", ["tx", "nb"], "label", hparams=TX_HP)}
+    assert "predict pass lost the device" in reports["tx"].metrics["error"]
+    assert mb.store.get("sf_tx").metadata.error is not None
+    assert not mb.registry.exists("sf_tx")
+    assert mb.registry.exists("sf_nb")
+    assert sorted(os.listdir(mb.registry.root)) == ["sf_nb"]

@@ -342,3 +342,164 @@ def test_checkpoint_layer_save_is_one_fetch_and_one_write(cfg):
     assert sorted(s["name"] for s in children) == [
         phase + ".fetch", phase + ".write"]
     assert all(s["parent_id"] == parent["span_id"] for s in children)
+
+
+# -- a save staged ahead of its commit (ISSUE 39) -----------------------------
+
+def _entries(reg):
+    import os
+
+    return sorted(os.listdir(reg.root)) if os.path.isdir(reg.root) else []
+
+
+def _files(reg, name):
+    import json
+    import os
+
+    d = os.path.join(reg.root, name)
+    out = {}
+    for f in ("params.bin", "params.json"):
+        with open(os.path.join(d, f), "rb") as fh:
+            out[f] = fh.read()
+    with open(os.path.join(d, "manifest.json")) as fh:
+        man = json.load(fh)
+    man.pop("time_created")
+    man.pop("name")
+    return out, man
+
+
+@pytest.fixture()
+def flat_small(monkeypatch):
+    """Every tree flat, leaves of 2 KiB or more synced one by one."""
+    from learningorchestra_tpu.models import persistence
+
+    monkeypatch.setattr(persistence, "FLAT_BYTES", 1)
+    monkeypatch.setattr(persistence, "_SYNC_BYTES", 2048)
+
+
+def test_staged_save_writes_what_the_one_call_save_writes(cfg, save_events):
+    """Stage, then commit: ``params.bin``, ``params.json`` and the
+    manifest are the one-call save's byte for byte, with the same syncs
+    in the same order; the staging directory is gone."""
+    reg = ModelRegistry(cfg)
+    meta = {"metrics": {"accuracy": 0.5}, "preprocess": {"label": "y"}}
+    reg.save("st_plain", _flat_model(), phase="p", **meta)
+    plain_events = list(save_events)
+    save_events.clear()
+    staged = reg.stage("st_staged", _flat_model(), phase="p")
+    assert staged is not None
+    reg.save("st_staged", _flat_model(), phase="p", staged=staged, **meta)
+    # The commit's model was never read: the leaves are the staging's.
+    assert save_events == plain_events
+    assert _files(reg, "st_staged") == _files(reg, "st_plain")
+    assert _entries(reg) == ["st_plain", "st_staged"]
+    assert reg.manifest("st_staged")["metrics"] == {"accuracy": 0.5}
+
+
+def test_discarded_stage_leaves_no_model_and_no_staging(cfg, flat_small):
+    reg = ModelRegistry(cfg)
+    staged = reg.stage("st_gone", _flat_model())
+    staged.discard()
+    assert _entries(reg) == []
+    assert not reg.exists("st_gone")
+    staged.discard()                  # twice: nothing left to remove
+
+
+def test_small_tree_is_not_staged(cfg):
+    """A tree under ``FLAT_BYTES`` takes the checkpoint layer's path in
+    ``save`` and is never staged."""
+    reg = ModelRegistry(cfg)
+    assert reg.stage("st_small", _flat_model()) is None
+    assert _entries(reg) == []
+
+
+def test_failed_stage_raises_at_save_and_keeps_the_live_version(
+        cfg, flat_small, monkeypatch):
+    """A writer that fails (here after writing ``params.bin``) is raised
+    by ``save(staged=...)``; the name's previous version stays live and
+    whole, and no staging is left."""
+    from learningorchestra_tpu.models import persistence
+
+    reg = ModelRegistry(cfg)
+    reg.save("st_live", _flat_model(), metrics={"version": 1})
+    before = _files(reg, "st_live")
+    real = persistence._write_flat
+
+    def full_disk(d, leaves, phase):
+        real(d, leaves, phase)
+        raise OSError(28, "No space left on device")
+
+    monkeypatch.setattr(persistence, "_write_flat", full_disk)
+    bigger = _flat_model()
+    bigger.params["d"] = np.arange(60, dtype=np.int32)
+    staged = reg.stage("st_live", bigger)
+    with pytest.raises(OSError, match="No space left"):
+        reg.save("st_live", bigger, metrics={"version": 2}, staged=staged)
+    assert _files(reg, "st_live") == before
+    assert reg.manifest("st_live")["metrics"] == {"version": 1}
+    assert _entries(reg) == ["st_live"]
+
+
+def test_two_stages_of_one_name_write_apart(cfg, flat_small, monkeypatch):
+    """Two stagings of one name at once (their writers meet inside the
+    write) each keep their own files; each commit swaps in its own."""
+    import os
+    import threading
+
+    from learningorchestra_tpu.models import persistence
+
+    both = threading.Barrier(2, timeout=60)
+    real = persistence._write_flat
+
+    def meet(d, leaves, phase):
+        both.wait()
+        real(d, leaves, phase)
+
+    monkeypatch.setattr(persistence, "_write_flat", meet)
+    reg = ModelRegistry(cfg)
+    one, two = _flat_model(), _flat_model()
+    two.params["d"] = np.arange(6, dtype=np.int32) + 100
+    s1 = reg.stage("st_same", one)
+    s2 = reg.stage("st_same", two)
+    s1.join()
+    s2.join()
+    assert s1.dir != s2.dir
+    assert all(os.path.basename(d).startswith(".tmp.st_same.")
+               for d in (s1.dir, s2.dir))
+    live = os.path.join(reg.root, "st_same")
+    reg.save("st_same", two, staged=s2)
+    assert np.array_equal(persistence._read_flat(live)["d"],
+                          np.arange(6) + 100)
+    reg.save("st_same", one, staged=s1)
+    assert np.array_equal(persistence._read_flat(live)["d"], np.arange(6))
+    assert _entries(reg) == ["st_same"]
+
+
+def test_staged_parts_keep_their_names_under_the_stage_span(cfg, flat_small):
+    """The writer's spans: ``<phase>.stage`` under the context that
+    staged, holding ``<phase>.fetch`` / ``.write`` / ``.sync`` under
+    their PR 38 names; the commit's ``<phase>`` span holds none."""
+    from learningorchestra_tpu.utils import tracing
+
+    reg = ModelRegistry(cfg)
+    phase = "fit.tx.finish.model"
+    with tracing.trace("root", sampled=True) as root:
+        with tracing.span("fit.tx") as fit:
+            staged = reg.stage("st_spans", _flat_model(), phase=phase)
+            staged.join()
+            assert staged.ahead_s() > 0
+            with tracing.span(phase):
+                reg.save("st_spans", _flat_model(), phase=phase,
+                         staged=staged)
+    by_name = {}
+    for s in tracing.spans_for(root.trace_id):
+        by_name.setdefault(s["name"], []).append(s)
+    (stage,) = by_name[phase + ".stage"]
+    (commit,) = by_name[phase]
+    assert stage["parent_id"] == fit.span_id
+    assert commit["parent_id"] == fit.span_id
+    parts = [s for p in ("fetch", "write", "sync")
+             for s in by_name[f"{phase}.{p}"]]
+    assert len(by_name[phase + ".fetch"]) == 4
+    assert len(by_name[phase + ".sync"]) == 3
+    assert all(s["parent_id"] == stage["span_id"] for s in parts)
