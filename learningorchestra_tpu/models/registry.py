@@ -107,11 +107,12 @@ HPARAM_SPECS: Dict[str, Dict[str, Tuple[Callable, str]]] = {
                "norm_topk_prob": _boolean(), "lm_head": _boolean(),
                "init_std": _positive(),
                # A period of layer kinds (F full attention, L gated
-               # delta-rule linear attention) and the linear mixer.
+               # delta-rule linear attention, M Mamba-2, E experts
+               # alone), the linear and the Mamba-2 mixer.
                "layer_pattern": (
                    lambda v: isinstance(v, str) and 0 < len(v) <= 64
-                   and not set(v) - set("FL"),
-                   "a string of F and L, at most 64 long"),
+                   and not set(v) - set("FLME"),
+                   "a string of F, L, M and E, at most 64 long"),
                "linear_heads": _int_range(1, 256),
                "linear_key_dim": _int_range(1, 1024),
                "linear_value_dim": _int_range(1, 1024),
@@ -121,7 +122,17 @@ HPARAM_SPECS: Dict[str, Dict[str, Tuple[Callable, str]]] = {
                "gated_width": _int_range(1, 1 << 18),
                "post_norm": _boolean(), "qk_norm_whole": _boolean(),
                "no_positions": _boolean(),
-               "heads_held": _int_range(1, 64)}},
+               "heads_held": _int_range(1, 64),
+               "ssm_heads": _int_range(1, 1024),
+               "ssm_head_dim": _int_range(1, 1024),
+               "ssm_state": _int_range(1, 1024),
+               "ssm_groups": _int_range(1, 1024),
+               "ssm_conv": _int_range(1, 16),
+               "ssm_chunk": _int_range(1, 4096),
+               # The expert layer's router, scaling and expert forms.
+               "router_sigmoid": _boolean(), "routed_scale": _positive(),
+               "relu2_experts": _boolean(),
+               "shared_width": _int_range(1, 1 << 18)}},
 }
 
 

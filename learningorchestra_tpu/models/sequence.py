@@ -20,10 +20,11 @@ With an ``arch`` block in its hyperparameters the same family is one of
 today's language-model blocks (RMSNorm, RoPE, grouped-query attention,
 the sparse-attention indexer, routed experts of which this holder was
 told its share, a layer pattern with gated delta-rule linear-attention
-layers, attention heads of which this holder was told its share, a
-next-token loss with the label-token readout: models/transformer.py).
-What it is held to then is the benchmark's plain references
-(``perfbench/reference_tx.py``, ``perfbench/reference_hybrid.py``); the
+layers or Mamba-2 layers and expert layers of their own, attention heads
+of which this holder was told its share, a next-token loss with the
+label-token readout: models/transformer.py). What it is held to then is
+the benchmark's plain references (``perfbench/reference_tx.py``,
+``perfbench/reference_hybrid.py``, ``perfbench/reference_ssm.py``); the
 small block without ``arch`` has no published model to match.
 
 The step loop touches the host once a fit: the token table is placed on
@@ -47,7 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.transformer import (
     MESH_AXES, NO_AXES, TxConfig, attention_path, delta_path,
-    forward_reference, has_options, make_fit_programs)
+    forward_reference, has_options, make_fit_programs, ssm_path)
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, MeshRuntime)
 from learningorchestra_tpu.utils import tracing
@@ -93,8 +94,8 @@ def _fit_metrics(reports: list, cfg: TxConfig, tokens_per_step: int) -> dict:
     if cfg.indexer_heads or cfg.lm_head:   # 0.0 where there is no indexer
         out["loss_index"] = [float(r["loss_index"]) for r in reports]
     if "state_absmax" in reports[0]:
-        # The largest |S| a linear layer's state held at the end of a
-        # chunk, over the fit: says the recurrence stayed bounded.
+        # The largest |S| a linear or Mamba-2 layer's state held at the
+        # end of a chunk, over the fit: says the recurrence stayed bounded.
         out["state_absmax"] = max(float(r["state_absmax"]) for r in reports)
     if cfg.n_kv_heads and cfg.causal:
         out["keys_kept_mean"] = sum(
@@ -168,7 +169,10 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
                           ("n_kv_heads (held)", cfg.kv_heads),
                           ("linear_heads (held)", cfg.lin_heads),
                           ("experts_held", cfg.held),
-                          ("gated_width", cfg.gated_width)):
+                          ("gated_width", cfg.gated_width),
+                          ("shared_width", cfg.shared_width),
+                          ("ssm_groups (M layers)",
+                           cfg.ssm_groups * ("M" in cfg.pattern))):
             if size % M:
                 raise ValueError(f"{key} {size} does not divide over a "
                                  f"model axis of {M}")
@@ -192,8 +196,9 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
                                  MESH_AXES._replace(seq=None), T_pad // S)}
     if cfg.pattern:
         attrs.update(layer_pattern=cfg.pattern, heads_held=cfg.heads,
-                     linear_chunk=cfg.linear_chunk,
-                     **delta_path(cfg, MESH_AXES))
+                     **delta_path(cfg, MESH_AXES), **ssm_path(cfg))
+    if "L" in cfg.pattern:
+        attrs["linear_chunk"] = cfg.linear_chunk
     with tracing.span("fit.tx.steps", attrs):
         reports = []
         for _ in range(int(train_steps)):
@@ -247,7 +252,7 @@ def predictor(hparams: dict):
     def timed(params, X):
         with tracing.span("fit.tx.predict", rows=int(X.shape[0]),
                           **attention_path(cfg, NO_AXES, cfg.max_len),
-                          **delta_path(cfg, NO_AXES)):
+                          **delta_path(cfg, NO_AXES), **ssm_path(cfg)):
             return jax.block_until_ready(proba(params, X))
 
     return timed

@@ -62,6 +62,23 @@ and not a second model file:
   computes before the reduce. (Which heads they are changes no
   computation, so no option names them: a holder's leaves are its own
   heads'.)
+- ``M`` in ``layer_pattern`` is Mamba-2's mixer (Dao and Gu,
+  arXiv:2405.21060) as Nemotron-H configures it (``ssm_heads``,
+  ``ssm_head_dim``, ``ssm_state``, ``ssm_groups``, ``ssm_conv``): a
+  depthwise causal conv with a bias, and per head a (head dim, state)
+  float32 state that decays by ``exp(dt A)`` and takes ``dt x B^T`` a
+  token, read out through ``C``; gate, then a norm over groups. The
+  program runs the scan a chunk of ``ssm_chunk`` tokens at a time (the
+  state-space duality's matrix form within a chunk, the carried state
+  across chunks); the benchmark's reference runs it token by token;
+- ``E`` in ``layer_pattern``: the expert sublayer is a layer of its own,
+  and then EVERY layer is one sublayer (``M``, ``E``, or ``F`` attention
+  alone). The expert layer's options: ``router_sigmoid`` (sigmoid scores
+  in float32, the top-k chosen by score plus a correction bias that no
+  gradient trains), ``routed_scale`` (the gates' scaling factor),
+  ``relu2_experts`` (``relu(h U)^2 D``, not gated), ``shared_width``
+  (with relu^2 experts, one shared expert of that form on every token,
+  riding the routed experts' token-block loop).
 
 The reference has no sequence models (SURVEY.md §5); the plain
 reference these options are held to is the benchmark's
@@ -155,6 +172,16 @@ class TxConfig:
     qk_norm_whole: bool = False   # one RMSNorm over the whole q / k
     no_positions: bool = False    # neither learned nor rotary positions
     heads_held: int = 0           # heads of each mixer held here; 0: all
+    ssm_heads: int = 0            # the Mamba-2 mixer's heads (M layers)
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1           # B / C groups; a group's heads share them
+    ssm_conv: int = 4             # depthwise causal conv width
+    ssm_chunk: int = 128          # tokens a chunk of the scan holds (tiling)
+    router_sigmoid: bool = False  # sigmoid scores + correction bias
+    routed_scale: float = 1.0     # the routed gates' scaling factor
+    relu2_experts: bool = False   # relu(h U)^2 D instead of gated SiLU
+    shared_width: int = 0         # > 0: one shared expert, this wide
 
     def __post_init__(self):
         if (self.rope_theta or self.qk_norm or self.indexer_heads
@@ -168,10 +195,11 @@ class TxConfig:
         if self.no_positions and self.rope_theta:
             raise ValueError("no_positions and rope_theta exclude each "
                              "other")
-        if set(self.layer_pattern) - set("FL"):
+        if set(self.layer_pattern) - set("FLME"):
             raise ValueError(f"layer_pattern {self.layer_pattern!r}: a "
-                             "period is made of F (full attention) and L "
-                             "(linear attention)")
+                             "period is made of F (full attention), L "
+                             "(linear attention), M (Mamba-2) and E "
+                             "(experts)")
         period = len(self.layer_pattern)
         if period and self.n_layers > period and self.n_layers % period:
             raise ValueError(f"n_layers {self.n_layers} is neither a "
@@ -186,6 +214,25 @@ class TxConfig:
             if self.linear_chunk < 1 or self.linear_conv < 1:
                 raise ValueError("linear_chunk and linear_conv are at "
                                  "least 1")
+        if "M" in self.pattern:
+            if not self.ssm_heads or self.ssm_heads % self.ssm_groups:
+                raise ValueError("an M layer needs ssm_heads, a multiple "
+                                 f"of ssm_groups {self.ssm_groups}")
+            if not self.causal:
+                raise ValueError("the Mamba-2 state runs forward over the "
+                                 "row: it needs causal attention")
+            if self.ssm_chunk < 1 or self.ssm_conv < 1:
+                raise ValueError("ssm_chunk and ssm_conv are at least 1")
+        if "E" in self.pattern and not self.n_experts:
+            raise ValueError("an E layer needs n_experts")
+        if (self.shared_width or self.router_sigmoid or self.relu2_experts
+                or self.routed_scale != 1.0) and not self.n_experts:
+            raise ValueError("shared_width, router_sigmoid, relu2_experts "
+                             "and routed_scale are options of the expert "
+                             "layer: set n_experts")
+        if self.shared_width and not self.relu2_experts:
+            raise ValueError("the shared expert has the relu^2 form: set "
+                             "relu2_experts")
         if not 0 <= self.heads_held <= self.n_heads:
             raise ValueError(f"heads_held {self.heads_held} is more than "
                              f"the {self.n_heads} heads")
@@ -245,6 +292,18 @@ class TxConfig:
         return self.linear_heads * self.heads // self.n_heads
 
     @property
+    def one_sublayer(self) -> bool:
+        """Every layer is one sublayer: a period that names E holds the
+        expert sublayer as a layer of its own, and its F, L and M layers
+        have no MLP."""
+        return "E" in self.pattern
+
+    @property
+    def has_state(self) -> bool:
+        """A layer carries a recurrent state (L or M)."""
+        return bool(set(self.pattern) & set("LM"))
+
+    @property
     def n_full(self) -> int:
         """Full-attention layers in the model."""
         if not self.pattern:
@@ -275,14 +334,18 @@ GRAD_GROUPS = {
     "wo": "attention", "q_norm": "attention", "k_norm": "attention",
     "ix_wq": "indexer", "ix_wk": "indexer", "ix_kn_g": "indexer",
     "ix_kn_b": "indexer", "ix_ww": "indexer",
-    "router": "router",
+    "router": "router", "router_bias": "router",
     "ln2_g": "experts", "ln2_b": "experts", "we_gate": "experts",
     "we_up": "experts", "we_down": "experts",
+    "sh_up": "shared_expert", "sh_down": "shared_expert",
     "w1": "experts", "b1": "experts", "w2": "experts", "b2": "experts",
     "w_gate": "mlp", "w_up": "mlp", "w_down": "mlp",
     **{"la_" + k: "linear_attention" for k in (
         "ln_g", "ln_b", "wq", "wk", "wv", "wz", "wb", "wa", "cq", "ck",
         "cv", "a_log", "dt_bias", "gn_g", "wo")},
+    **{"ssm_" + k: "ssm" for k in (
+        "ln_g", "ln_b", "wz", "wx", "wbc", "wdt", "cx", "cbc", "cx_b",
+        "cbc_b", "a_log", "dt_bias", "d", "gn_g", "wo")},
 }
 
 def _psum(x, axis):
@@ -302,8 +365,11 @@ def _axis_index(axis):
 def _layer_leaves(cfg: TxConfig) -> Dict[str, Dict[str, Any]]:
     """One layer's leaves by the kind of layer that has them, ``{kind:
     {name: (shape, init, model dim)}}``: ``F`` the full-attention
-    sublayer's, ``L`` the linear mixer's, ``*`` every layer's (the MLP
-    or expert sublayer). ``init`` is "normal", "ones", "zeros", "a_log"
+    sublayer's, ``L`` the linear mixer's, ``M`` the Mamba-2 mixer's,
+    ``*`` every layer's (the MLP or expert sublayer), or ``E``'s where
+    the expert sublayer is a layer of its own. The mixers' heads, and
+    Mamba-2's B / C groups, lie on the model axis. ``init`` is "normal",
+    "ones", "zeros", "a_log"
     or "dt_bias"; ``model dim`` the dim split over the model axis
     (heads, FFN hidden, held experts), ``None``: replicated."""
     d, H, hd = cfg.d_model, cfg.heads, cfg.hd
@@ -348,15 +414,39 @@ def _layer_leaves(cfg: TxConfig) -> Dict[str, Dict[str, Any]]:
               "la_wo": ((Hl, dv, d), "normal", 0)}
     if bias:
         linear["la_ln_b"] = ((d,), "zeros", None)
+    Hs, P, N, Gs = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
+        cfg.ssm_groups
+    ssm = {"ssm_ln_g": ((d,), "ones", None),
+           "ssm_wz": ((d, Hs, P), "normal", 1),
+           "ssm_wx": ((d, Hs, P), "normal", 1),
+           "ssm_wbc": ((d, 2, Gs, N), "normal", 2),
+           "ssm_wdt": ((d, Hs), "normal", 1),
+           "ssm_cx": ((cfg.ssm_conv, Hs, P), "normal", 1),
+           "ssm_cbc": ((cfg.ssm_conv, 2, Gs, N), "normal", 2),
+           "ssm_cx_b": ((Hs, P), "zeros", 0),
+           "ssm_cbc_b": ((2, Gs, N), "zeros", 1),
+           "ssm_a_log": ((Hs,), "a_log", 0),
+           "ssm_dt_bias": ((Hs,), "dt_bias", 0),
+           "ssm_d": ((Hs,), "ones", 0),
+           "ssm_gn_g": ((Hs, P), "ones", 0),
+           "ssm_wo": ((Hs, P, d), "normal", 0)}
+    if bias:
+        ssm["ssm_ln_b"] = ((d,), "zeros", None)
     every = {"ln2_g": ((d,), "ones", None)}
     if bias:
         every["ln2_b"] = ((d,), "zeros", None)
     if cfg.n_experts:
         E, f = cfg.held, cfg.expert_width
-        every.update(router=((d, cfg.n_experts), "normal", None),
-                     we_gate=((E, d, f), "normal", 0),
-                     we_up=((E, d, f), "normal", 0),
+        every["router"] = ((d, cfg.n_experts), "normal", None)
+        if cfg.router_sigmoid:
+            every["router_bias"] = ((cfg.n_experts,), "zeros", None)
+        if not cfg.relu2_experts:
+            every["we_gate"] = ((E, d, f), "normal", 0)
+        every.update(we_up=((E, d, f), "normal", 0),
                      we_down=((E, f, d), "normal", 0))
+        if cfg.shared_width:
+            every.update(sh_up=((d, cfg.shared_width), "normal", 1),
+                         sh_down=((cfg.shared_width, d), "normal", 0))
     elif cfg.gated_width:
         f = cfg.gated_width
         every.update(w_gate=((d, f), "normal", 1), w_up=((d, f), "normal", 1),
@@ -367,7 +457,8 @@ def _layer_leaves(cfg: TxConfig) -> Dict[str, Dict[str, Any]]:
                      w2=((cfg.d_ff, d), "normal", 0), b2=((d,), "zeros", None))
     pattern = cfg.pattern or "F"
     return {kind: leaves for kind, leaves in (
-        ("F", full), ("L", linear), ("*", every))
+        ("F", full), ("L", linear), ("M", ssm),
+        ("E" if cfg.one_sublayer else "*", every))
         if kind == "*" or kind in pattern}
 
 
@@ -909,6 +1000,135 @@ def _linear_attention(cfg: TxConfig, ax: Axes, h, lyr):
     return jnp.einsum("bthe,hed->btd", y, lyr["la_wo"]), peak
 
 
+#: Tokens a pass of the Mamba-2 mixer's loop holds (tiling only; whole
+#: chunks).
+_SSM_BLOCK = 256
+
+#: Precision of the scan's products (C B^T, the scores with dt x, C with
+#: the carried state, the state's update): the TPU's default for float32,
+#: bfloat16 operands and float32 sums, as every large product of the
+#: block. The state, the decay and its cumulative sums are float32
+#: whatever this says.
+_SSM_PRECISION = jax.lax.Precision.DEFAULT
+
+
+def _ssm_dot(spec: str, a, b):
+    """One product of the scan (``_SSM_PRECISION``)."""
+    return jnp.einsum(spec, a, b, precision=_SSM_PRECISION)
+
+
+def ssm_path(cfg: TxConfig) -> Dict[str, Any]:
+    """The scan the M layers run, as span attributes: ``ssm_path``
+    ``"chunked"`` (the program has no other; the benchmark's reference
+    runs the recurrence token by token) and ``ssm_chunk``. Empty without
+    an M layer."""
+    if "M" not in cfg.pattern:
+        return {}
+    return {"ssm_path": "chunked", "ssm_chunk": cfg.ssm_chunk}
+
+
+def _ssd_block(x, dt, a, b, c, state, C: int):
+    """Mamba-2's scan over one block of whole chunks. Per head ``h`` of
+    group ``g`` the recurrence is ``S_t = exp(a_t) S_{t-1} + dt_t x_t
+    b_t^T``, ``y_t = S_t c_t`` (``a_t = dt_t A_h <= 0``). A chunk of ``C``
+    tokens with ``A_i = a_1 + .. + a_i`` (float32) is matrix products:
+    ``L_ij = exp(A_i - A_j)`` for ``i >= j``, else 0; ``y = (L * (c
+    b^T)) (dt x) + exp(A) (c S^T)`` with ``S`` the state the chunk starts
+    from; ``S <- exp(A_C) S + (exp(A_C - A) dt x)^T b``. The chunks' own
+    parts are computed together; the walk over a block's chunks is
+    unrolled.
+
+    x (B, S, H, P); dt, a (B, S, H); b, c (B, S, G, N); state (B, H, P,
+    N); ``S`` a multiple of ``C``. Returns ``(y (B, S, H, P), state after
+    the block, largest |state| a chunk of it ended on)``."""
+    Bsz, S, H, P = x.shape
+    G, N = b.shape[2:]
+    n, r = S // C, H // G
+
+    def chunks(t):             # (B, S, ...) -> (B, n, C, ...)
+        return t.reshape((Bsz, n, C) + t.shape[2:])
+
+    xdt = chunks((x * dt[..., None]).reshape(Bsz, S, G, r, P))
+    b, c = chunks(b), chunks(c)
+    A = jnp.cumsum(jnp.moveaxis(chunks(a.reshape(Bsz, S, G, r)), 2, -1),
+                   axis=-1)                                # (B, n, G, r, C)
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    L = jnp.exp(jnp.where(lower, A[..., :, None] - A[..., None, :],
+                          -jnp.inf))                       # i >= j, else 0
+    cb = _ssm_dot("bnigs,bnjgs->bngij", c, b)
+    y = _ssm_dot("bngrij,bnjgrp->bnigrp", cb[:, :, :, None] * L, xdt)
+    into = jnp.exp(A[..., -1:] - A)                        # to the chunk end
+    dS = _ssm_dot("bnjgrp,bnjgs->bngrps",
+                  xdt * jnp.moveaxis(into, -1, 2)[..., None], b)
+    state = state.reshape(Bsz, G, r, P, N)
+    out, peak = [], state[0, 0, 0, 0, 0] * 0.0
+    for k in range(n):
+        read = _ssm_dot("bigs,bgrps->bigrp", c[:, k], state)
+        out.append(y[:, k] + read * jnp.moveaxis(jnp.exp(A[:, k]), -1, 1)[
+            ..., None])
+        state = jnp.exp(A[:, k, :, :, -1])[..., None, None] * state + dS[:, k]
+        peak = jnp.maximum(peak, jnp.abs(jax.lax.stop_gradient(state)).max())
+    y = jnp.stack(out, axis=1)                             # (B, n, C, G, r, P)
+    return y.reshape(Bsz, S, H, P), state.reshape(Bsz, H, P, N), peak
+
+
+def _ssm_mixer(cfg: TxConfig, ax: Axes, h, lyr):
+    """The Mamba-2 mixer on ``h`` (B, T, d): the input projections
+    (``z``, ``x``, ``B`` / ``C``, ``dt``), then ONE loop over blocks of
+    ``_SSM_BLOCK`` tokens that carries the (B, heads, head dim, state)
+    float32 state and the conv's last ``ssm_conv - 1`` inputs and holds
+    everything from the conv to the gated norm (a trace tells the
+    mixer's core by that carried shape), then the output projection. The
+    loop's body is rematerialised in the backward pass, which
+    differentiates through it. Returns ``(out (B, T, d) before the
+    model-axis reduce, the largest |state| reached)``."""
+    B, T, _ = h.shape
+    P, N, K, C = cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_chunk
+    H, G = lyr["ssm_wx"].shape[1], lyr["ssm_wbc"].shape[2]   # held here
+    u = jnp.concatenate([
+        jnp.einsum("btd,dhp->bthp", h, lyr["ssm_wx"]).reshape(B, T, H * P),
+        jnp.einsum("btd,dkgn->btkgn", h, lyr["ssm_wbc"]).reshape(
+            B, T, 2 * G * N)], axis=-1)
+    z = jnp.einsum("btd,dhp->bthp", h, lyr["ssm_wz"])
+    dt = jnp.einsum("btd,dh->bth", h, lyr["ssm_wdt"])
+    # Whole chunks: the row padded with zero inputs at its end, which no
+    # earlier token reads.
+    n = -(-T // C)
+    S = C * _chunk(n, max(1, _SSM_BLOCK // C))
+    pad = [(0, 0), (0, n * C - T)]
+    u, z, dt = (jnp.pad(x, pad + [(0, 0)] * (x.ndim - 2)).reshape(
+        (B, n * C // S, S) + x.shape[2:]).swapaxes(0, 1) for x in (u, z, dt))
+    conv = jnp.concatenate([lyr["ssm_cx"].reshape(K, H * P),
+                            lyr["ssm_cbc"].reshape(K, 2 * G * N)], -1)
+    conv_b = jnp.concatenate([lyr["ssm_cx_b"].reshape(-1),
+                              lyr["ssm_cbc_b"].reshape(-1)])
+    rate = -jnp.exp(lyr["ssm_a_log"])
+
+    def block(carry, xs):
+        state, tail, peak = carry
+        u_b, z_b, dt_b = xs
+        seen = jnp.concatenate([tail, u_b], axis=1)       # K - 1 earlier
+        c = jax.nn.silu(sum(conv[j] * seen[:, j:j + S] for j in range(K))
+                        + conv_b)
+        x = c[..., :H * P].reshape(B, S, H, P)
+        bc = c[..., H * P:].reshape(B, S, 2, G, N)
+        step = jax.nn.softplus(dt_b + lyr["ssm_dt_bias"])
+        y, state, top = _ssd_block(x, step, step * rate, bc[:, :, 0],
+                                   bc[:, :, 1], state, C)
+        y = (y + lyr["ssm_d"][:, None] * x) * jax.nn.silu(z_b)
+        # the gated norm: over groups of H / G heads, gate first
+        y = _rms(y.reshape(B, S, G, -1), 1.0, cfg.norm_eps).reshape(
+            B, S, H, P) * lyr["ssm_gn_g"]
+        return (state, seen[:, S:], jnp.maximum(peak, top)), y
+
+    zero = u[0, 0, 0, 0] * 0.0        # varies over the mesh as the inputs do
+    start = (jnp.zeros((B, H, P, N), jnp.float32) + zero,
+             jnp.zeros((B, K - 1, u.shape[-1]), jnp.float32) + zero, zero)
+    (_, _, peak), y = jax.lax.scan(jax.checkpoint(block), start, (u, z, dt))
+    y = y.swapaxes(0, 1).reshape(B, n * C, H, P)[:, :T]
+    return jnp.einsum("bthp,hpd->btd", y, lyr["ssm_wo"]), peak
+
+
 def _gated_mlp(h, lyr):
     """``(silu(h Wg) * (h Wu)) Wd`` before the model-axis reduce."""
     a = jnp.einsum("btd,df->btf", h, lyr["w_gate"])
@@ -918,23 +1138,35 @@ def _gated_mlp(h, lyr):
 
 def _experts(cfg: TxConfig, ax: Axes, h, lyr):
     """The routed expert layer on the normed input ``h`` (B, T, d). Routes
-    over all ``n_experts`` (softmax, top-k, renormalised), and computes
-    the part of the result that the experts held HERE give, for every
-    token routed to them: the held experts run as one wide gated FFN
-    whose hidden blocks are scaled by the token's gate for that expert
-    (0 where it was not routed), so no capacity exists and no token can
-    be dropped. Returns ``(out before the model-axis reduce, counts
-    (held_local,) assignments per held expert, [assignments routed,
-    assignments to absent experts, dropped])``."""
+    over all ``n_experts`` (softmax, top-k, renormalised; or with
+    ``router_sigmoid`` sigmoid scores, the top-k by score plus the
+    correction bias, the chosen scores renormalised; then times
+    ``routed_scale``), and computes the part of the result that the
+    experts held HERE give, for every token routed to them: the held
+    experts run as one wide FFN whose hidden blocks are scaled by the
+    token's gate for that expert (0 where it was not routed), so no
+    capacity exists and no token can be dropped. A shared expert runs on
+    every token in the same token-block loop. Returns ``(out before the
+    model-axis reduce, counts (held_local,) assignments per held expert,
+    [assignments routed, assignments to absent experts, dropped])``."""
     B, T, d = h.shape
     x = h.reshape(B * T, d)
     logits = jnp.einsum("nd,de->ne", x, lyr["router"],
                         precision=jax.lax.Precision.HIGHEST)
-    top_g, top_e = jax.lax.top_k(jax.nn.softmax(logits, -1),
-                                 cfg.experts_per_token)
+    if cfg.router_sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(lyr["router_bias"]),
+            cfg.experts_per_token)
+        top_g = jnp.take_along_axis(scores, top_e, axis=-1)
+    else:
+        top_g, top_e = jax.lax.top_k(jax.nn.softmax(logits, -1),
+                                     cfg.experts_per_token)
     if cfg.norm_topk_prob:
         top_g = top_g / top_g.sum(-1, keepdims=True)
-    e_loc = lyr["we_gate"].shape[0]           # this model shard's experts
+    if cfg.routed_scale != 1.0:
+        top_g = top_g * cfg.routed_scale
+    e_loc = lyr["we_up"].shape[0]             # this model shard's experts
     ids = cfg.experts_first + _axis_index(ax.model) * e_loc + jnp.arange(e_loc)
     hit = top_e[:, :, None] == ids[None, None, :]              # (N, K, e)
     gate = (hit * top_g[:, :, None]).sum(1)                    # (N, e)
@@ -943,10 +1175,18 @@ def _experts(cfg: TxConfig, ax: Axes, h, lyr):
 
     def part(args):
         xc, gc = args
-        a = jnp.einsum("nd,edf->nef", xc, lyr["we_gate"])
-        b = jnp.einsum("nd,edf->nef", xc, lyr["we_up"])
-        return jnp.einsum("nef,efd->nd", jax.nn.silu(a) * b * gc[:, :, None],
-                   lyr["we_down"])
+        if cfg.relu2_experts:
+            act = jnp.square(jax.nn.relu(jnp.einsum("nd,edf->nef", xc,
+                                                    lyr["we_up"])))
+        else:
+            a = jnp.einsum("nd,edf->nef", xc, lyr["we_gate"])
+            b = jnp.einsum("nd,edf->nef", xc, lyr["we_up"])
+            act = jax.nn.silu(a) * b
+        out = jnp.einsum("nef,efd->nd", act * gc[:, :, None], lyr["we_down"])
+        if cfg.shared_width:        # relu^2, as the routed experts
+            shared = jnp.square(jax.nn.relu(xc @ lyr["sh_up"]))
+            out = out + shared @ lyr["sh_down"]
+        return out
 
     out = jax.lax.map(jax.checkpoint(part), (
         x.reshape(N // C, C, d), gate.reshape(N // C, C, e_loc)))
@@ -963,17 +1203,18 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
     ``(x (B, T_local, d), aux)``; ``aux``: ``attn`` (3,) [index loss
     summed over queries and layers, keys kept, short queries], ``moe``
     (3,) [routed, absent, dropped] and ``experts`` (held_local,) counts,
-    all summed over layers and over this shard's rows; with linear
-    layers also ``state_absmax``, the largest of theirs."""
+    all summed over layers and over this shard's rows; with linear or
+    Mamba-2 layers also ``state_absmax``, the largest of theirs."""
     seq_size = _axis_size(ax.seq)
     if cfg.indexer_heads and seq_size > 1:
         raise ValueError(
             f"the indexer's top-{cfg.indexer_topk} selection does not run "
             f"across a sequence axis of {seq_size}: keys chosen per query "
             "cannot ride the ring; use a mesh whose seq axis is 1")
-    if "L" in cfg.pattern and seq_size > 1:
+    if cfg.has_state and seq_size > 1:
+        layer = "linear layer" if "L" in cfg.pattern else "Mamba-2 layer"
         raise ValueError(
-            f"the linear layer's state does not run across a sequence axis "
+            f"the {layer}'s state does not run across a sequence axis "
             f"of {seq_size}: a shard's state would have to be handed to the "
             "next; use a mesh whose seq axis is 1")
     Tl = tokens.shape[1]
@@ -991,22 +1232,34 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
 
     def mixer(x, lyr, kind):
         """``x`` after the layer's first sublayer, and its counters."""
-        g, b = ("la_ln_g", "la_ln_b") if kind == "L" else ("ln1_g", "ln1_b")
+        g, b = {"L": ("la_ln_g", "la_ln_b"), "M": ("ssm_ln_g", "ssm_ln_b")
+                }.get(kind, ("ln1_g", "ln1_b"))
         h = _norm(cfg, x, lyr[g], lyr.get(b)) if pre else x
-        if kind == "L":
-            out, peak = _linear_attention(cfg, ax, h, lyr)
+        if kind in ("L", "M"):
+            out, peak = (_linear_attention if kind == "L" else _ssm_mixer)(
+                cfg, ax, h, lyr)
             aux = {"attn": jnp.zeros(3), "state_absmax": peak}
         else:
             out, attn = _attention(cfg, ax, h, lyr, pos)
             aux = {"attn": attn}
-            if "L" in cfg.pattern:       # every layer reports the same keys
+            if cfg.has_state:            # every layer reports the same keys
                 aux["state_absmax"] = jnp.zeros(())
         if pre:
             return x + _psum(out, ax.model), aux       # row-parallel reduce
         return x + _norm(cfg, _psum(out, ax.model), lyr[g], lyr.get(b)), aux
 
     def layer_fn(x, lyr, kind="F"):
+        if kind == "E":              # the expert sublayer, a layer alone
+            x, aux = sublayer(x, lyr)
+            return x, dict(aux, attn=jnp.zeros(3))
         x, aux = mixer(x, lyr, kind)
+        if cfg.one_sublayer:
+            return x, aux
+        x, more = sublayer(x, lyr)
+        return x, dict(aux, **more)
+
+    def sublayer(x, lyr):
+        """``x`` after the MLP or expert sublayer, and its counters."""
         h = _norm(cfg, x, lyr["ln2_g"], lyr.get("ln2_b")) if pre else x
         if cfg.n_experts:
             out, counts, moe = _experts(cfg, ax, h, lyr)
@@ -1025,7 +1278,7 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
             x = x + lyr["b2"]
         if not cfg.n_experts:
             counts, moe = jnp.zeros(1), jnp.zeros(3)
-        return x, dict(aux, moe=moe, experts=counts)
+        return x, {"moe": moe, "experts": counts}
 
     if not cfg.pattern:              # every layer is the same layer
         if cfg.remat:
@@ -1042,15 +1295,17 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
         slice of its kind's leaves and of every layer's. (A scan over a
         run of one kind would compile that kind once, but it slices the
         stacked MLP leaves into copies: 4 GB more at the hybrid cell's
-        size, which then does not fit the chip.)"""
-        auxes, nth = [], {"F": 0, "L": 0}
+        size, which then does not fit the chip.) A counter is folded over
+        the layers that report it."""
+        auxes, nth = [], dict.fromkeys(kinds, 0)
         for j, kind in enumerate(cfg.pattern):
             lyr = {k: per[k][nth[kind]] for k in kinds[kind]}
-            lyr.update({k: per[k][j] for k in kinds["*"]})
+            lyr.update({k: per[k][j] for k in kinds.get("*", ())})
             nth[kind] += 1
             x, aux = layer(x, lyr, kind)
             auxes.append(aux)
-        return x, _fold(jax.tree.map(lambda *a: jnp.stack(a), *auxes))
+        return x, _fold({k: jnp.stack([a[k] for a in auxes if k in a])
+                         for k in sorted(set().union(*auxes))})
 
     x, aux = jax.lax.scan(period_fn, x, params["layers"])
     return x, _fold(aux)
@@ -1058,7 +1313,7 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
 
 def _fold(aux: Dict[str, Any]) -> Dict[str, Any]:
     """Layers' counters, stacked on a leading axis, into one: sums, and
-    the largest ``state_absmax`` of the linear layers."""
+    the largest ``state_absmax`` of the linear and Mamba-2 layers."""
     return {k: a.max(0) if k == "state_absmax" else a.sum(0)
             for k, a in aux.items()}
 
@@ -1147,7 +1402,7 @@ def make_loss_fn(cfg: TxConfig, mesh: Mesh, with_aux: bool = False):
     aux_specs = {"loss_main": P(), "loss_index": P(), "keys_kept": P(),
                  "queries_short": P(), "moe": P(),
                  "experts": P(MODEL_AXIS) if cfg.n_experts else P()}
-    if "L" in cfg.pattern:
+    if cfg.has_state:
         aux_specs["state_absmax"] = P()
 
     def loss_fn(params, tokens, labels):
@@ -1174,13 +1429,19 @@ def make_train_step(cfg: TxConfig, mesh: Mesh, opt: optax.GradientTransformation
 
 def group_norms(grads) -> Dict[str, Any]:
     """L2 norm of the gradient per ``GRAD_GROUPS`` group; the second
-    sublayer's norm counts with the sublayer it norms."""
+    sublayer's norm counts with the sublayer it norms. Each Mamba-2 leaf
+    is also reported on its own, under its name: the leaves that only
+    the scan feeds (B, C, dt, A) are a small part of their group's
+    norm."""
     ln2 = "mlp" if "w_gate" in grads["layers"] else "experts"
     sq: Dict[str, Any] = {}
     for name, g in list(grads["layers"].items()) + [
             (k, v) for k, v in grads.items() if k != "layers"]:
         grp = ln2 if name in ("ln2_g", "ln2_b") else GRAD_GROUPS[name]
-        sq[grp] = sq.get(grp, 0.0) + jnp.sum(jnp.square(g))
+        part = jnp.sum(jnp.square(g))
+        sq[grp] = sq.get(grp, 0.0) + part
+        if grp == "ssm":
+            sq[name] = part
     return {k: jnp.sqrt(v) for k, v in sq.items()}
 
 

@@ -424,3 +424,80 @@ def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
     assert len(core) == 3 * cfg.pattern.count("L")
     assert len(full) >= 2 and not set(core) & set(full)
     assert "tpu_custom_call" in text                    # the full layer's
+
+
+def test_ssm_step_compiles_and_fits_the_chip(topo, chip_compiler):
+    """The training step of the benchmark's ``nemotron-labs-twotower-
+    30b-a3b`` configuration as the cell POSTs it (one period MEMEMFEME,
+    two rows of 8,192 tokens a step, 8 of 128 experts held, 16,384
+    vocabulary rows), compiled for the described v5e: its peak, state
+    and temporaries by the compiler's heap simulation, is under the
+    device's memory (15.85 GB; the sum of its temporary allocations
+    beside its arguments, 19.2 GB, counts buffers that are never live
+    together, and the chip runs the step), and its loops
+    are named as the cell's device-trace metrics expect them (the
+    Mamba-2 mixers' block loops by the carried state, three an M layer:
+    forward, rematerialised forward, backward; the expert layers'
+    token-block loops by the held experts' weights; the attention
+    layer's query-block loops by their stacked outputs), none matched by
+    another's pattern."""
+    import json
+    import os
+    import re
+
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from learningorchestra_tpu.config import Settings
+    from learningorchestra_tpu.models import transformer as tx
+    from learningorchestra_tpu.parallel.mesh import local_mesh
+
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench")
+    with open(os.path.join(bench, "configs",
+                           "nemotron-labs-twotower-30b-a3b.json")) as fh:
+        conf = json.load(fh)
+    with open(os.path.join(bench, "peaks.json")) as fh:
+        hbm = json.load(fh)["devices"]["TPU v5 lite"]["hbm_bytes"]
+    hp, rows = conf["families"]["tx"], conf["data"]["seq_len"]
+    cfg = tx.TxConfig(
+        vocab=hp["vocab"], d_model=hp["d_model"], n_heads=hp["n_heads"],
+        n_layers=hp["n_layers"], n_classes=conf["data"]["num_classes"],
+        max_len=rows, causal=hp["causal"], remat=hp["remat"], **hp["arch"])
+    settings = Settings()
+    settings.mesh_shape = "1,1,1"
+    mesh = local_mesh(settings, devices=topo.devices[:1])
+    init, step = tx.make_fit_programs(cfg, mesh, optax.adam(hp["lr"]),
+                                      hp["batch"])
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    held = sum(a.size for a in jax.tree.leaves(state[0]))
+    # the parameters, and the 4 x 128 correction bias (a buffer)
+    assert held == conf["state"]["parameters"] + 4 * 128
+    n = conf["data"]["n_train"]
+    compiled = step.lower(
+        placed(state), placed(jax.eval_shape(jax.random.PRNGKey, 0)),
+        jax.ShapeDtypeStruct((n, rows), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rep)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
+    assert mem.argument_size_in_bytes < mem.peak_memory_in_bytes < hbm, (
+        mem.peak_memory_in_bytes, mem.temp_size_in_bytes)
+    loops = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " while(" in ln]
+
+    def matched(metric):
+        with open(os.path.join(bench, "layer_metrics",
+                               metric + ".json")) as fh:
+            patterns = json.load(fh)["ops"]
+        return [ln for ln in loops if any(re.search(p, ln) for p in patterns)]
+
+    core, moe = matched("ssm_s.ssmfit"), matched("moe_s.ssmfit")
+    attn = matched("full_attn_s.ssmfit")
+    assert len(core) == 3 * cfg.pattern.count("M")
+    assert len(moe) >= cfg.pattern.count("E") and not set(core) & set(moe)
+    assert len(attn) >= 2 and not set(attn) & (set(core) | set(moe))
