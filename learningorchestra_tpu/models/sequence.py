@@ -48,7 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from learningorchestra_tpu.models.base import TrainedModel
 from learningorchestra_tpu.models.transformer import (
     MESH_AXES, NO_AXES, TxConfig, attention_path, delta_path,
-    forward_reference, has_options, make_fit_programs, ssm_path)
+    forward_reference, has_options, make_fit_programs, moe_path, ssm_path)
 from learningorchestra_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, MeshRuntime)
 from learningorchestra_tpu.utils import tracing
@@ -56,7 +56,7 @@ from learningorchestra_tpu.utils import tracing
 #: A fit's routing and selection readings: span attributes of
 #: ``fit.tx.steps`` and, of the last fit, counters on ``GET /metrics``.
 _READINGS = ("keys_kept_mean", "queries_short_share", "absent_share",
-             "moe_imbalance", "state_absmax")
+             "moe_imbalance", "moe_tile_rows_share", "state_absmax")
 
 _counters_lock = threading.Lock()
 _counters: Dict[str, Any] = {"fits": 0, "steps": 0, "tokens": 0,
@@ -111,6 +111,10 @@ def _fit_metrics(reports: list, cfg: TxConfig, tokens_per_step: int) -> dict:
         out["expert_tokens"] = [int(c) for c in per_expert]
         out["moe_imbalance"] = float(
             per_expert.max() / max(per_expert.mean(), 1e-9))
+        tiles = np.sum([np.asarray(r["moe_tiles"], np.float64)
+                        for r in reports], 0)
+        if tiles[1] > 0:      # the grouped products ran: their overhead
+            out["moe_tile_rows_share"] = float(tiles[0] / tiles[1])
     return out
 
 
@@ -197,6 +201,7 @@ def fit(runtime: MeshRuntime, X: np.ndarray, y: np.ndarray,
     if cfg.pattern:
         attrs.update(layer_pattern=cfg.pattern, heads_held=cfg.heads,
                      **delta_path(cfg, MESH_AXES), **ssm_path(cfg))
+    attrs.update(moe_path(cfg, MESH_AXES))
     if "L" in cfg.pattern:
         attrs["linear_chunk"] = cfg.linear_chunk
     with tracing.span("fit.tx.steps", attrs):
@@ -252,7 +257,8 @@ def predictor(hparams: dict):
     def timed(params, X):
         with tracing.span("fit.tx.predict", rows=int(X.shape[0]),
                           **attention_path(cfg, NO_AXES, cfg.max_len),
-                          **delta_path(cfg, NO_AXES), **ssm_path(cfg)):
+                          **delta_path(cfg, NO_AXES), **ssm_path(cfg),
+                          **moe_path(cfg, NO_AXES)):
             return jax.block_until_ready(proba(params, X))
 
     return timed
@@ -275,9 +281,32 @@ def _proba_program(cfg: TxConfig):
         else:
             # A block of rows at a time: a published-width forward of
             # every test row at once does not fit beside the weights.
-            logits = jax.lax.map(
-                lambda row: forward_reference(params, row[None], cfg=cfg)[0],
-                tokens, batch_size=max(1, 8192 // cfg.max_len))
+            b = max(1, 8192 // cfg.max_len)
+            if cfg.n_experts:       # (the others' programs stay as they were)
+                logits = _in_batches(
+                    lambda rows: forward_reference(params, rows, cfg=cfg),
+                    tokens, b)
+            else:
+                logits = jax.lax.map(
+                    lambda row: forward_reference(params, row[None],
+                                                  cfg=cfg)[0],
+                    tokens, batch_size=b)
         return jax.nn.softmax(logits, axis=-1)
 
     return proba
+
+
+def _in_batches(fn, x, b: int):
+    """``fn`` over ``x``'s rows ``b`` at a time, each block one batch
+    (``lax.map`` with ``batch_size`` would ``vmap`` single rows). The
+    expert layer skips the windows a batch's assignments leave empty;
+    under ``vmap`` that test becomes a select that runs every window."""
+    n = x.shape[0]
+    full = n - n % b
+    parts = []
+    if full:
+        y = jax.lax.map(fn, x[:full].reshape((full // b, b) + x.shape[1:]))
+        parts.append(y.reshape((full,) + y.shape[2:]))
+    if n % b:
+        parts.append(fn(x[full:]))
+    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]

@@ -25,7 +25,10 @@ and not a second model file:
 - ``n_experts``: a routed expert layer that is TOLD WHICH EXPERTS IT
   HOLDS (``experts_first``, ``experts_held``): it routes over all of
   them and adds only its own experts' part, which is what one chip of
-  an expert-parallel deployment computes. No token is dropped;
+  an expert-parallel deployment computes. No token is dropped. On the
+  TPU only the routed pairs are computed: the assignments sorted by
+  expert, through the grouped products of
+  ``ops/pallas_kernels.py:grouped_matmul`` (megablox's schedule);
 - ``lm_head``: a per-position next-token loss over an untied vocabulary
   head, with the label-token readout that keeps the family a classifier
   (class ``c`` is token id ``c``; a row's target at its last position is
@@ -1136,19 +1139,184 @@ def _gated_mlp(h, lyr):
     return jnp.einsum("btf,fd->btd", jax.nn.silu(a) * b, lyr["w_down"])
 
 
+def _vary(tree):
+    """The tree's arrays, each marked as varying over every mesh axis any
+    of them varies over (inside ``shard_map``). A custom rule's
+    cotangent varies as its output does; marked so, an input that was
+    broadcast over an axis takes its cotangent summed over that axis, as
+    autodiff does for the implicit broadcast of a plain product."""
+    every = pk._varying(*jax.tree.leaves(tree))
+
+    def mark(a):
+        more = tuple(sorted(every - jax.typeof(a).vma))
+        return jax.lax.pcast(a, more, to="varying") if more else a
+
+    return jax.tree.map(mark, tree)
+
+
+def _expert_act(cfg: TxConfig, h):
+    """The routed experts' activation of their up-projections ``h``:
+    ``relu(u)^2``, or ``silu(g) * u`` of the pair ``(g, u)``."""
+    if cfg.relu2_experts:
+        return jnp.square(jax.nn.relu(h[0]))
+    return jax.nn.silu(h[0]) * h[1]
+
+
+def _shared(x, up, down):
+    """The shared expert, relu^2 as the routed ones."""
+    return jnp.square(jax.nn.relu(x @ up)) @ down
+
+
+def _window_up(cfg: TxConfig, x, rows, valid, sizes, wo):
+    """A window's rows of ``x`` gathered in sorted order (in the
+    operands' type) and their up-projections, rows past the count set to
+    0 (the grouped products leave them undefined)."""
+    xs = x[rows].astype(wo["we_up"].dtype)
+    keys = ("we_up",) if cfg.relu2_experts else ("we_gate", "we_up")
+    return xs, keys, tuple(
+        jnp.where(valid[:, None], pk.grouped_matmul(xs, wo[k], sizes), 0.0)
+        for k in keys)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expert_loop(cfg: TxConfig, x, gates, w, rows, valid, sizes, active,
+                 wo):
+    """The expert layer's loop: ONE ``lax.scan`` of ``n`` steps over the
+    layer's token blocks and, beside them, windows of the layer's
+    assignments to held experts sorted by expert. Step ``i`` runs the
+    shared expert on token block ``i`` and, where window ``i`` holds
+    any assignment (``active``), the routed experts over the window's
+    rows: gather, grouped up-projection, activation times each row's
+    gate, grouped down-projection, each row added into its token's
+    output. A window past the count costs its step nothing: the turn is
+    a ``cond`` on it (a batch of rows, never a ``vmap`` of them, comes
+    here: under ``vmap`` the ``cond`` would run every window), so no
+    loop runs inside the window loop either.
+
+    x (N, d) float32; gates, rows, valid (n, R) a window's assignments
+    in sorted order (gate, token, real); sizes (n, held) int32 a
+    window's rows an expert; ``w`` the float32 leaves, ``wo`` the
+    routed experts' copies in ``pk.mxu_operand_dtype``, made once a
+    layer outside the loop. Differentiated by a loop of the same shape
+    that rematerialises a window's forward and accumulates the weight
+    gradients in float32 only in the steps whose window is active: the
+    transpose of a plain scan would add every step's full gradient of
+    the held experts, active or not."""
+    return _expert_loop_fwd(cfg, x, gates, w, rows, valid, sizes, active,
+                            wo)[0]
+
+
+def _expert_loop_fwd(cfg, x, gates, w, rows, valid, sizes, active, wo):
+    N = x.shape[0]
+    n = rows.shape[0]
+    C = N // n
+
+    def routed(acc, rr, gg, vv, zz):
+        _, _, h = _window_up(cfg, x, rr, vv, zz, wo)
+        act = (_expert_act(cfg, h) * gg[:, None]).astype(
+            wo["we_down"].dtype)
+        y = pk.grouped_matmul(act, wo["we_down"], zz)
+        return acc.at[jnp.where(vv, rr, N)].add(y, mode="drop")
+
+    def step(acc, xs):
+        i, rr, gg, vv, zz, aa = xs
+        acc = jax.lax.cond(aa, partial(routed, rr=rr, gg=gg, vv=vv, zz=zz),
+                           lambda a: a, acc)
+        if cfg.shared_width:
+            sh = _shared(jax.lax.dynamic_slice_in_dim(x, i * C, C),
+                         w["sh_up"], w["sh_down"])
+            acc = jax.lax.dynamic_update_slice_in_dim(
+                acc, jax.lax.dynamic_slice_in_dim(acc, i * C, C) + sh,
+                i * C, 0)
+        return acc, None
+
+    out, _ = jax.lax.scan(step, jnp.zeros_like(x), (
+        jnp.arange(n), rows, gates, valid, sizes, active))
+    return out, (x, gates, w, rows, valid, sizes, active, wo)
+
+
+def _expert_loop_bwd(cfg, res, ct):
+    x, gates, w, rows, valid, sizes, active, wo = res
+    N = x.shape[0]
+    n = rows.shape[0]
+    C = N // n
+    op = wo["we_down"].dtype
+
+    def routed(carry, rr, gg, vv, zz):
+        dw, dx = carry
+        xs, keys, h = _window_up(cfg, x, rr, vv, zz, wo)
+        act, act_vjp = jax.vjp(lambda *h: _expert_act(cfg, h), *h)
+        go = jnp.where(vv[:, None], ct[rr], 0.0).astype(op)
+        dga = pk.grouped_matmul(go, wo["we_down"], zz, transpose=True)
+        dw = dict(dw, we_down=dw["we_down"] + pk.grouped_matmul_t(
+            (act * gg[:, None]).astype(op), go, zz))
+        dg = jnp.where(vv, (dga * act).sum(-1), 0.0)
+        dh = act_vjp(jnp.where(vv[:, None], dga * gg[:, None], 0.0))
+        dxs = 0.0
+        for k, dk in zip(keys, dh):
+            dk = dk.astype(op)
+            dw[k] = dw[k] + pk.grouped_matmul_t(xs, dk, zz)
+            dxs = dxs + pk.grouped_matmul(dk, wo[k], zz, transpose=True)
+        dx = dx.at[jnp.where(vv, rr, N)].add(dxs, mode="drop")
+        return (dw, dx), dg
+
+    def step(carry, xs):
+        i, rr, gg, vv, zz, aa = xs
+        carry, dg = jax.lax.cond(
+            aa, partial(routed, rr=rr, gg=gg, vv=vv, zz=zz),
+            lambda c: (c, jnp.zeros_like(gg)), carry)
+        if cfg.shared_width:
+            dw, dx = carry
+            _, vjp = jax.vjp(_shared, jax.lax.dynamic_slice_in_dim(
+                x, i * C, C), w["sh_up"], w["sh_down"])
+            dxc, du, dd = vjp(jax.lax.dynamic_slice_in_dim(ct, i * C, C))
+            dw = dict(dw, sh_up=dw["sh_up"] + du, sh_down=dw["sh_down"] + dd)
+            dx = jax.lax.dynamic_update_slice_in_dim(
+                dx, jax.lax.dynamic_slice_in_dim(dx, i * C, C) + dxc,
+                i * C, 0)
+            carry = (dw, dx)
+        return carry, dg
+
+    (dw, dx), dgates = jax.lax.scan(
+        step, (jax.tree.map(jnp.zeros_like, w), jnp.zeros_like(x)),
+        (jnp.arange(n), rows, gates, valid, sizes, active))
+    return dx, dgates, dw, None, None, None, None, None
+
+
+_expert_loop.defvjp(_expert_loop_fwd, _expert_loop_bwd)
+
+
+def moe_path(cfg: TxConfig, ax: Axes) -> Dict[str, Any]:
+    """How ``_experts`` computes the routed experts, as a span
+    attribute: ``moe_path`` ``"grouped"`` (the routed pairs only, by
+    grouped products) or ``"dense"`` (every held expert over every
+    token, gate-scaled: off the TPU inside a mesh program). Empty
+    without experts."""
+    if not cfg.n_experts:
+        return {}
+    return {"moe_path": "grouped" if pk.grouped_fits(any(ax)) else "dense"}
+
+
 def _experts(cfg: TxConfig, ax: Axes, h, lyr):
     """The routed expert layer on the normed input ``h`` (B, T, d). Routes
     over all ``n_experts`` (softmax, top-k, renormalised; or with
     ``router_sigmoid`` sigmoid scores, the top-k by score plus the
     correction bias, the chosen scores renormalised; then times
     ``routed_scale``), and computes the part of the result that the
-    experts held HERE give, for every token routed to them: the held
-    experts run as one wide FFN whose hidden blocks are scaled by the
-    token's gate for that expert (0 where it was not routed), so no
-    capacity exists and no token can be dropped. A shared expert runs on
-    every token in the same token-block loop. Returns ``(out before the
-    model-axis reduce, counts (held_local,) assignments per held expert,
-    [assignments routed, assignments to absent experts, dropped])``."""
+    experts held HERE give, for every token routed to them: no capacity
+    exists and no token can be dropped. Where ``pk.grouped_fits``
+    (``moe_path``) the layer's assignments to held experts are sorted by
+    expert once, cut into windows of the most a token block of
+    ``token_chunk`` can have, and ``_expert_loop`` runs the routed pairs
+    only, through grouped products that visit only their rows' tiles.
+    Otherwise the held experts run a token block at a time as one wide
+    FFN whose hidden blocks are scaled by the token's gate for that
+    expert (0 where it was not routed), the grouped path's oracle. A
+    shared expert runs on every token block in the same loop. Returns
+    ``(out before the model-axis reduce, counts (held_local,)
+    assignments per held expert, [assignments routed, assignments to
+    absent experts, dropped], [tile rows the grouped products visited,
+    assignments they computed])``."""
     B, T, d = h.shape
     x = h.reshape(B * T, d)
     logits = jnp.einsum("nd,de->ne", x, lyr["router"],
@@ -1169,41 +1337,85 @@ def _experts(cfg: TxConfig, ax: Axes, h, lyr):
     e_loc = lyr["we_up"].shape[0]             # this model shard's experts
     ids = cfg.experts_first + _axis_index(ax.model) * e_loc + jnp.arange(e_loc)
     hit = top_e[:, :, None] == ids[None, None, :]              # (N, K, e)
-    gate = (hit * top_g[:, :, None]).sum(1)                    # (N, e)
-    N = B * T
+    N, K = B * T, cfg.experts_per_token
     C = _chunk(N, cfg.token_chunk)
+    nb = N // C
 
-    def part(args):
-        xc, gc = args
-        if cfg.relu2_experts:
-            act = jnp.square(jax.nn.relu(jnp.einsum("nd,edf->nef", xc,
-                                                    lyr["we_up"])))
-        else:
-            a = jnp.einsum("nd,edf->nef", xc, lyr["we_gate"])
-            b = jnp.einsum("nd,edf->nef", xc, lyr["we_up"])
-            act = jax.nn.silu(a) * b
-        out = jnp.einsum("nef,efd->nd", act * gc[:, :, None], lyr["we_down"])
-        if cfg.shared_width:        # relu^2, as the routed experts
-            shared = jnp.square(jax.nn.relu(xc @ lyr["sh_up"]))
-            out = out + shared @ lyr["sh_down"]
-        return out
+    if pk.grouped_fits(any(ax)):
+        # The layer's assignments to held experts, stably sorted by held
+        # expert (absent ones last, past the count), in n windows of the
+        # most a token block can have: the count fits whatever the
+        # routing.
+        R = -(-C * min(K, e_loc) // pk.GROUP_TILE) * pk.GROUP_TILE
+        local = jnp.where(hit.any(-1), top_e - ids[0], e_loc).reshape(N * K)
+        order = jnp.argsort(local, stable=True)
+        # argsort types its result as invariant over the mesh, whatever
+        # its keys vary over: typed here as they are (and the gates'
+        # source with it), the gather below takes each shard's gates
+        # apart and their cotangents are summed over the shards
+        top_g, order = _vary((top_g, local, order))[::2]
+        if nb * R > N * K:
+            order = jnp.pad(order, (0, nb * R - N * K))
+        order = order[:nb * R]
+        per = hit.sum((0, 1)).astype(jnp.int32)            # (e,)
+        count = per.sum()
+        valid = jnp.arange(nb * R) < count
+        rows = jnp.where(valid, order // K, 0).reshape(nb, R)
+        gates = jnp.where(valid, top_g.reshape(N * K)[
+            jnp.minimum(order, N * K - 1)], 0.0).reshape(nb, R)
+        valid = valid.reshape(nb, R)
+        # each expert's rows [start, end) cut by each window
+        end = jnp.cumsum(per)
+        lo = jnp.arange(nb)[:, None] * R
+        sizes = jnp.clip(jnp.minimum(end, lo + R)
+                         - jnp.maximum(end - per, lo), 0).astype(jnp.int32)
+        w = {k: lyr[k] for k in ("we_gate", "we_up", "we_down", "sh_up",
+                                 "sh_down") if k in lyr}
+        wo = {k: lyr[k].astype(pk.mxu_operand_dtype())
+              for k in ("we_gate", "we_up", "we_down") if k in lyr}
+        out = _expert_loop(cfg, *_vary((
+            x, gates, w, rows, valid, sizes, jnp.arange(nb) * R < count,
+            wo)))
+        # what the windows handed the grouped products: every assignment
+        applied = _psum(sizes.sum().astype(jnp.float32), ax.model)
+        tiles = _psum(jnp.stack([pk.grouped_tile_rows(sizes).sum(),
+                                 count]).astype(jnp.float32), ax.model)
+    else:
+        gate = (hit * top_g[:, :, None]).sum(1)                # (N, e)
 
-    out = jax.lax.map(jax.checkpoint(part), (
-        x.reshape(N // C, C, d), gate.reshape(N // C, C, e_loc)))
+        def part(args):
+            xc, gc = args
+            if cfg.relu2_experts:
+                act = jnp.square(jax.nn.relu(jnp.einsum(
+                    "nd,edf->nef", xc, lyr["we_up"])))
+            else:
+                a = jnp.einsum("nd,edf->nef", xc, lyr["we_gate"])
+                b = jnp.einsum("nd,edf->nef", xc, lyr["we_up"])
+                act = jax.nn.silu(a) * b
+            out = jnp.einsum("nef,efd->nd", act * gc[:, :, None],
+                             lyr["we_down"])
+            if cfg.shared_width:
+                out = out + _shared(xc, lyr["sh_up"], lyr["sh_down"])
+            return out
+
+        out = jax.lax.map(jax.checkpoint(part), (
+            x.reshape(nb, C, d), gate.reshape(nb, C, e_loc)))
+        applied = _psum((gate > 0).sum().astype(jnp.float32), ax.model)
+        tiles = jnp.zeros(2)
     counts = hit.sum((0, 1)).astype(jnp.float32)
     here = _psum(counts.sum(), ax.model)
     routed = jnp.float32(B * T * cfg.experts_per_token)
-    applied = _psum((gate > 0).sum().astype(jnp.float32), ax.model)
     return out.reshape(B, T, d), counts, jnp.stack(
-        [routed, routed - here, here - applied])
+        [routed, routed - here, here - applied]), tiles
 
 
 def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
     """Embedding and the stacked blocks. tokens (B, T_local) int32 →
     ``(x (B, T_local, d), aux)``; ``aux``: ``attn`` (3,) [index loss
     summed over queries and layers, keys kept, short queries], ``moe``
-    (3,) [routed, absent, dropped] and ``experts`` (held_local,) counts,
-    all summed over layers and over this shard's rows; with linear or
+    (3,) [routed, absent, dropped], ``experts`` (held_local,) counts and
+    ``moe_tiles`` (2,) [tile rows visited, assignments computed], all
+    summed over layers and over this shard's rows; with linear or
     Mamba-2 layers also ``state_absmax``, the largest of theirs."""
     seq_size = _axis_size(ax.seq)
     if cfg.indexer_heads and seq_size > 1:
@@ -1261,8 +1473,9 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
     def sublayer(x, lyr):
         """``x`` after the MLP or expert sublayer, and its counters."""
         h = _norm(cfg, x, lyr["ln2_g"], lyr.get("ln2_b")) if pre else x
+        more = {}
         if cfg.n_experts:
-            out, counts, moe = _experts(cfg, ax, h, lyr)
+            out, counts, moe, more["moe_tiles"] = _experts(cfg, ax, h, lyr)
         elif cfg.gated_width:
             out = _gated_mlp(h, lyr)
         else:
@@ -1278,7 +1491,7 @@ def _trunk(params, tokens, cfg: TxConfig, ax: Axes):
             x = x + lyr["b2"]
         if not cfg.n_experts:
             counts, moe = jnp.zeros(1), jnp.zeros(3)
-        return x, {"moe": moe, "experts": counts}
+        return x, {"moe": moe, "experts": counts, **more}
 
     if not cfg.pattern:              # every layer is the same layer
         if cfg.remat:
@@ -1394,6 +1607,8 @@ def make_loss_fn(cfg: TxConfig, mesh: Mesh, with_aux: bool = False):
                "keys_kept": attn[1], "queries_short": attn[2],
                "moe": jax.lax.psum(aux["moe"], both),
                "experts": jax.lax.psum(aux["experts"], both)}
+        if cfg.n_experts:
+            out["moe_tiles"] = jax.lax.psum(aux["moe_tiles"], both)
         if "state_absmax" in aux:
             out["state_absmax"] = jax.lax.pmax(
                 jax.lax.stop_gradient(aux["state_absmax"]), ax)
@@ -1404,6 +1619,8 @@ def make_loss_fn(cfg: TxConfig, mesh: Mesh, with_aux: bool = False):
                  "experts": P(MODEL_AXIS) if cfg.n_experts else P()}
     if cfg.has_state:
         aux_specs["state_absmax"] = P()
+    if cfg.n_experts:
+        aux_specs["moe_tiles"] = P()
 
     def loss_fn(params, tokens, labels):
         loss, aux = jax.shard_map(

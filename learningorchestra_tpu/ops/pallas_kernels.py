@@ -70,13 +70,26 @@ ops XLA alone schedules sub-optimally. Residents:
   rows, a group finished among its own rows and then taken out of every
   later row, rows read only as far as they are non-zero.
 
+- **The expert layer's grouped products** (models/transformer.py
+  `_expert_loop`: the routed pairs of a held-experts layer, sorted by
+  expert). `grouped_matmul` runs each row through its group's weights
+  a tile of `GROUP_TILE` rows at a time, and `grouped_matmul_t` sums a
+  group's rows' outer products into its weight gradient: megablox's
+  `gmm` / `tgmm` schedule (`jax.experimental.pallas.ops.tpu.megablox`),
+  whose grid is the number of tiles the groups cover, counted at run
+  time, so rows past the count cost nothing. Here the contraction is
+  one block (a group's weights stay in VMEM over its tiles), the
+  products and the weight gradient are float32 whatever the operands,
+  and the outputs are typed for `shard_map`.
+
 On non-TPU backends every `pallas_call` runs in interpreter mode, so the
 same code path is unit-tested on the CPU mesh (tests/conftest.py) and
 cross-checked against the pure-XLA reference implementation.
 
 Each `pallas_call` carries a `name=` (`tsne_repulsion`, `tree_hist`,
 `tree_hist_stacked`, `tree_route`, `tree_descend`, `chosen_attn_fwd`,
-`chosen_attn_probs`, `chosen_attn_bwd`, `delta_transform`): it becomes the
+`chosen_attn_probs`, `chosen_attn_bwd`, `delta_transform`, `grouped_mm`,
+`grouped_mm_t`): it becomes the
 innermost name scope, XLA names the custom-call instruction after it
 (`%tree_hist.3 = ... custom_call_target="tpu_custom_call"`), and that
 instruction text is the event's name on a device profile's `XLA Ops` line — which is all the
@@ -711,9 +724,11 @@ def chosen_attn_key_block(T: int, C: int, D: int, R: int) -> int:
                  and _attn_vmem_bytes(R * C, C, D, b) <= _ATTN_VMEM_BUDGET), 0)
 
 
-def _attn_operand_dtype():
+def mxu_operand_dtype():
     """What the MXU takes of a float32 product at default precision on
-    the chip; off it the plain body's products are float32 too."""
+    the chip; off it the plain body's products are float32 too. The
+    attention kernels and the grouped products round their operands to
+    it."""
     return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
@@ -912,7 +927,7 @@ def _attn_dims(qg, mask):
 def _chosen_attn_forward(qg, k2, v2, mask, last):
     """``(o (G, R·C, D), lse (G, R·C, 1))`` of one query block."""
     G, RC, D, C, T, tk, vmem = _attn_dims(qg, mask)
-    dt = _attn_operand_dtype()
+    dt = mxu_operand_dtype()
     per_head, keys, msk = _attn_specs(RC, D, C, tk, True)
     return _attn_call(
         partial(_chosen_fwd_kernel, scale=D ** -0.5, dt=dt),
@@ -934,7 +949,7 @@ def _chosen_attn_probs(qg, k2, mask, lse, last):
     per_head, keys, msk = _attn_specs(RC, D, C, tk, False)
     (probs,) = _attn_call(
         partial(_chosen_probs_kernel, scale=D ** -0.5,
-                dt=_attn_operand_dtype()),
+                dt=mxu_operand_dtype()),
         "chosen_attn_probs", last, (qg, k2, mask, lse),
         grid=(T // tk, G), in_specs=[per_head(D), keys, msk, per_head(1)],
         out_specs=[pl.BlockSpec((C, tk), lambda kb, g, last: (0, kb))],
@@ -946,7 +961,7 @@ def _chosen_attn_probs(qg, k2, mask, lse, last):
 def _chosen_attn_backward(qg, k2, v2, mask, last, lse, delta, do):
     """``(dq (G, R·C, D), dk, dv (T, G·D))`` of one query block."""
     G, RC, D, C, T, tk, vmem = _attn_dims(qg, mask)
-    dt = _attn_operand_dtype()
+    dt = mxu_operand_dtype()
     per_head, keys, msk = _attn_specs(RC, D, C, tk, True)
     grads = pl.BlockSpec((tk, D), lambda g, kb, last: (kb, g))
     return _attn_call(
@@ -1113,3 +1128,197 @@ def delta_transform(A):
         name="delta_transform",
     )(a)
     return jnp.moveaxis(t[:, :, :N], -1, 0).reshape(lead + (C, C))
+
+
+# --- the expert layer's grouped products ------------------------------------
+
+#: Rows a tile of the grouped products holds. A group's rows are computed
+#: a tile at a time; a tile that two groups share is visited once by each,
+#: each visit storing only its own group's rows.
+GROUP_TILE = 128
+
+#: Scoped VMEM a grouped product asks for, and the part of it its blocks
+#: (double-buffered operands and output, the accumulator) may take.
+_GROUP_VMEM = 48 * 2**20
+_GROUP_BLOCKS = 40 * 2**20
+
+
+def grouped_fits(on_mesh: bool = False) -> bool:
+    """Whether the expert layer's routed products run as grouped
+    products. Off the TPU, inside ``shard_map``, the dense form runs:
+    there it is the oracle the mesh tests hold the layer to (the
+    kernels would run in interpret mode; tests/test_tx_moe_grouped.py
+    forces them on a mesh against it)."""
+    return not (on_mesh and _interpret())
+
+
+def _widths(dim: int) -> list:
+    """Block widths along a lane dimension of ``dim``: the whole of it,
+    then the multiples of 128 that divide it, widest first."""
+    return [dim] + [w for w in range(dim - dim % _LANES, 0, -_LANES)
+                    if w != dim and dim % w == 0]
+
+
+def _varying(*arrays) -> frozenset:
+    """The mesh axes any of the arrays varies over (inside
+    ``shard_map``; empty outside it)."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
+
+
+def _group_schedule(sizes, m: int, tm: int, visit_empty: bool):
+    """Megablox's tile schedule for rows sorted by group (``jax.
+    experimental.pallas.ops.tpu.megablox.gmm.make_group_metadata``, in
+    fewer operations): grid step ``i`` works on row tile ``tile[i]``
+    for group ``group[i]``; a group's steps run from the tile its first
+    row lies in to the tile its last row lies in, so a tile two groups
+    share is visited once by each, consecutively. ``visit_empty``: an
+    empty group gets one step too (the weight gradient zeroes it).
+    Returns ``((offsets (G + 1,), group, tile), steps)``: ``steps``
+    sizes the grid at run time, and tiles past the groups' rows are
+    not visited."""
+    G = sizes.shape[0]
+    end = jnp.cumsum(sizes)
+    first = (end - sizes) // tm
+    tiles = jnp.where(sizes > 0, (end + tm - 1) // tm - first,
+                      int(visit_empty))
+    done = jnp.cumsum(tiles)                       # steps through group g
+    i = jnp.arange(m // tm + G - 1)
+    group = jnp.minimum((done[None, :] <= i[:, None]).sum(1), G - 1)
+    tile = jnp.clip(first[group] + i - (done[group] - tiles[group]), 0,
+                    m // tm - 1)
+    offsets = jnp.concatenate([jnp.zeros(1, end.dtype), end])
+    return (offsets.astype(jnp.int32), group.astype(jnp.int32),
+            tile.astype(jnp.int32)), done[-1]
+
+
+def grouped_tile_rows(sizes, tm: int = 0):
+    """Rows of the tiles ``grouped_matmul`` visits for groups of
+    ``sizes`` (..., G) laid one after another (``_group_schedule``):
+    over the rows themselves, the padding and straddle overhead of the
+    schedule. ``tm`` 0: ``GROUP_TILE``."""
+    tm = tm or GROUP_TILE
+    end = jnp.cumsum(sizes, -1)
+    tiles = (end + tm - 1) // tm - (end - sizes) // tm
+    return (jnp.where(sizes > 0, tiles, 0) * tm).sum(-1)
+
+
+def _gmm_kernel(offsets, gids, mids, x_ref, w_ref, out_ref, *, tm,
+                transpose):
+    i = pl.program_id(1)
+    g = gids[i]
+    dims = (((1,), (1,)) if transpose else ((1,), (0,)), ((), ()))
+    acc = jax.lax.dot_general(x_ref[...], w_ref[...], dims,
+                              preferred_element_type=jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0) + mids[i] * tm
+    keep = (row >= offsets[g]) & (row < offsets[g + 1])
+    out_ref[...] = jnp.where(keep, acc, out_ref[...])
+
+
+def grouped_matmul(x, w, sizes, *, transpose: bool = False):
+    """``out[r] = x[r] @ w[group of r]`` (``w[g].T`` with ``transpose``)
+    for rows sorted by group, ``sizes`` (G,) int32 rows a group, in
+    order from row 0; x (m, c) and w (G, c, n) (or (G, n, c)) in
+    ``mxu_operand_dtype``, ``m`` a multiple of ``GROUP_TILE``; float32
+    products. Rows past the groups' are neither read nor written: what
+    they hold is undefined. The whole contraction is one block, so a
+    group's weight block stays in VMEM over the group's tiles (blocks
+    sized for bfloat16 operands, double-buffered)."""
+    tm = GROUP_TILE
+    m, c = x.shape
+    n = w.shape[1] if transpose else w.shape[2]
+    fixed = 2 * tm * c * 2
+    tn = next((t for t in _widths(n)
+               if fixed + 2 * c * t * 2 + 3 * tm * t * 4 <= _GROUP_BLOCKS),
+              None)
+    if tn is None:
+        raise ValueError(f"a grouped product of width {c} x {n} does not "
+                         "fit VMEM")
+    meta, steps = _group_schedule(sizes, m, tm, visit_empty=False)
+    if transpose:
+        w_spec = pl.BlockSpec((None, tn, c),
+                              lambda j, i, off, gid, mid: (gid[i], j, 0))
+    else:
+        w_spec = pl.BlockSpec((None, c, tn),
+                              lambda j, i, off, gid, mid: (gid[i], 0, j))
+    return pl.pallas_call(
+        partial(_gmm_kernel, tm=tm, transpose=transpose),
+        out_shape=jax.ShapeDtypeStruct(
+            (m, n), jnp.float32, vma=_varying(x, w, sizes)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(n, tn), steps),
+            in_specs=[pl.BlockSpec((tm, c),
+                                   lambda j, i, off, gid, mid: (mid[i], 0)),
+                      w_spec],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, off, gid, mid: (mid[i], j))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUP_VMEM),
+        interpret=_interpret(),
+        name="grouped_mm",
+    )(*meta, x, w)
+
+
+def _tgmm_kernel(offsets, gids, mids, x_ref, g_ref, out_ref, acc_ref, *,
+                 tm):
+    i = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    grp = gids[i]
+    start, end = offsets[grp], offsets[grp + 1]
+
+    @pl.when((i == 0) | (gids[jnp.maximum(i - 1, 0)] != grp))
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(end > start)
+    def _accumulate():
+        def rows(ref):
+            r = jax.lax.broadcasted_iota(jnp.int32, ref.shape, 0) + mids[i] * tm
+            keep = (r >= start) & (r < end)
+            return jnp.where(keep, ref[...].astype(jnp.float32),
+                             0.0).astype(ref.dtype)
+        acc_ref[...] += jax.lax.dot(rows(x_ref).swapaxes(0, 1), rows(g_ref),
+                                    preferred_element_type=jnp.float32)
+
+    @pl.when((i == last) | (gids[jnp.minimum(i + 1, last)] != grp))
+    def _store():
+        out_ref[...] = acc_ref[...]
+
+
+def grouped_matmul_t(x, g, sizes):
+    """``out[e] = x[rows of e].T @ g[rows of e]`` (G, k, n) float32, for
+    rows sorted by group as ``grouped_matmul`` takes them; x (m, k), g
+    (m, n) in ``mxu_operand_dtype``. A group with no rows gets zeros; rows past the
+    groups' are not read."""
+    tm = GROUP_TILE
+    m, k = x.shape
+    n = g.shape[1]
+    G = sizes.shape[0]
+    fits = [(tk, tn) for tk in _widths(k) for tn in _widths(n)
+            if 2 * tm * (tk + tn) * 2 + 3 * tk * tn * 4 <= _GROUP_BLOCKS]
+    if not fits:
+        raise ValueError(f"a grouped product of width {k} x {n} does not "
+                         "fit VMEM")
+    tk, tn = max(fits, key=lambda t: t[0] * t[1])
+    meta, steps = _group_schedule(sizes, m, tm, visit_empty=True)
+    return pl.pallas_call(
+        partial(_tgmm_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct(
+            (G, k, n), jnp.float32, vma=_varying(x, g, sizes)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(n, tn), pl.cdiv(k, tk), steps),
+            in_specs=[pl.BlockSpec((tm, tk),
+                                   lambda j, l, i, off, gid, mid: (mid[i], l)),
+                      pl.BlockSpec((tm, tn),
+                                   lambda j, l, i, off, gid, mid: (mid[i], j))],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn), lambda j, l, i, off, gid, mid: (gid[i], l, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUP_VMEM),
+        interpret=_interpret(),
+        name="grouped_mm_t",
+    )(*meta, x, g)

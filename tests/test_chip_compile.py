@@ -75,7 +75,7 @@ def chip_compiler(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
-    monkeypatch.setattr(pk, "_attn_operand_dtype", lambda: HDT)
+    monkeypatch.setattr(pk, "mxu_operand_dtype", lambda: HDT)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -169,6 +169,20 @@ def _delta_transform():
     return pk.delta_transform, [((1, 15, 4, 64, 64), jnp.float32)]
 
 
+def _grouped(which: str, d: int, f: int, held: int, rows: int):
+    """One token block's grouped product of the expert layer: a block of
+    1,024 tokens has at most ``rows`` assignments to the ``held``
+    experts of width ``f`` on this chip, whatever of them are real."""
+    sizes = ((held,), jnp.int32)
+    if which == "up":
+        return (pk.grouped_matmul, [((rows, d), HDT), ((held, d, f), HDT),
+                                    sizes])
+    if which == "dx":
+        return (partial(pk.grouped_matmul, transpose=True),
+                [((rows, f), HDT), ((held, d, f), HDT), sizes])
+    return pk.grouped_matmul_t, [((rows, d), HDT), ((rows, f), HDT), sizes]
+
+
 CASES = {
     "chosen_attention-forward": lambda: _chosen_attention(False),
     "chosen_attention-backward": lambda: _chosen_attention(True),
@@ -190,6 +204,13 @@ CASES = {
     "chosen_attention-backward-1head-a-group-15groups-1024queries":
         lambda: _chosen_attention(True, 15, 1024, 512, 15),
     "delta_transform": _delta_transform,
+    # Keye-VL's experts (16 held of width 768, top-8) and Nemotron's (8
+    # held of width 1,856, top-6, a width no multiple of 128), hidden
+    # 2,048 / 2,688: up-projection, its input gradient, weight gradient.
+    **{f"grouped_matmul-{w}-{m}": partial(_grouped, w, *dims)
+       for m, dims in (("keye", (2048, 768, 16, 8192)),
+                       ("nemotron", (2688, 1856, 8, 6144)))
+       for w in ("up", "dx", "dw")},
     "tree_histogram-32bins": lambda: _hist(32),
     "tree_histogram-256bins": lambda: _hist(256),
     "tree_histogram-48bins-straddling": lambda: _hist(48),
@@ -219,6 +240,9 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_compiler):
 #: line (``tree_leaf_stats`` shares the histogram's ``pallas_call``).
 KERNEL_NAMES = {
     "delta_transform": "delta_transform",
+    "grouped_matmul-up-keye": "grouped_mm",
+    "grouped_matmul-dx-nemotron": "grouped_mm",
+    "grouped_matmul-dw-nemotron": "grouped_mm_t",
     "tree_histogram-32bins": "tree_hist",
     "tree_histogram-256bins": "tree_hist",
     "tree_histogram-48bins-straddling": "tree_hist",
@@ -277,6 +301,10 @@ def test_kernel_is_named_in_the_compiled_module(case, one_chip,
     ("delta_transform", ["delta_transform"],
      ("linear_attn_s.hybridfit", "linear_attn_roofline.hybridfit",
       "full_attn_s.hybridfit")),
+    ("grouped_matmul-up-keye", ["grouped_mm"],
+     ("moe_s.txfit", "moe_s.ssmfit")),
+    ("grouped_matmul-dw-keye", ["grouped_mm_t"],
+     ("moe_s.txfit", "moe_s.ssmfit")),
 ])
 def test_attention_kernels_are_counted_once(case, kernels, metrics, one_chip,
                                             chip_compiler):
@@ -286,7 +314,10 @@ def test_attention_kernels_are_counted_once(case, kernels, metrics, one_chip,
     would be counted twice. Each is named for what it is, and neither
     of the metric's patterns (nor the expert layer's) finds it. The
     same holds for the chunk transform's kernel inside the linear
-    mixers' block loops and ``linear_attn_s.hybridfit``'s patterns."""
+    mixers' block loops and ``linear_attn_s.hybridfit``'s patterns, and
+    for the grouped products inside the expert layers' token-block
+    loops and ``moe_s``'s (whose second pattern takes a ``moe_``
+    kernel)."""
     import json
     import os
     import re
@@ -345,20 +376,48 @@ def test_tree_predict_compiles_on_four_chips(family, topo, chip_compiler):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
-    """The training step of the benchmark's ``olmo-hybrid-7b``
-    configuration as the cell POSTs it (one period LLLF, rows of 8,192
-    tokens, 15 of 30 heads held, MLP 11,008 whole, 12,544 vocabulary
-    rows), compiled for the described v5e: its state and its temporaries
-    together are under the device's memory, and its loops are named as
-    the cell's device-trace metrics expect them (the linear mixers'
-    block loops by the carried state, three a linear layer: forward,
-    rematerialised forward, backward; the full layer's query-block
-    loops), none matched by the other's pattern."""
+def _cell(name):
+    """``(cfg, conf)``: the benchmark's configuration ``name`` as its
+    cell POSTs it."""
+    import json
+    import os
+
+    from learningorchestra_tpu.models import transformer as tx
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "perfbench", "configs", name + ".json")) as fh:
+        conf = json.load(fh)
+    hp, rows = conf["families"]["tx"], conf["data"]["seq_len"]
+    return tx.TxConfig(
+        vocab=hp["vocab"], d_model=hp["d_model"], n_heads=hp["n_heads"],
+        n_layers=hp["n_layers"], n_classes=conf["data"]["num_classes"],
+        max_len=rows, causal=hp["causal"], remat=hp["remat"],
+        **hp["arch"]), conf
+
+
+def _matcher(lines):
+    """``matched(metric)``: the lines a per-layer metric's patterns
+    find."""
     import json
     import os
     import re
 
+    def matched(metric):
+        with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                               "perfbench", "layer_metrics",
+                               metric + ".json")) as fh:
+            patterns = json.load(fh)["ops"]
+        return [ln for ln in lines if any(re.search(p, ln) for p in patterns)]
+
+    return matched
+
+
+def _compiled_step(topo, name):
+    """The training step of the benchmark's configuration ``name`` as
+    its cell POSTs it, compiled for one chip of the described v5e:
+    ``(cfg, conf, held parameters, compiled, loops, matched)``;
+    ``loops`` the compiled ``while`` lines, ``matched(metric)`` those a
+    per-layer metric's patterns find."""
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -366,16 +425,8 @@ def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
     from learningorchestra_tpu.models import transformer as tx
     from learningorchestra_tpu.parallel.mesh import local_mesh
 
-    bench = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench")
-    with open(os.path.join(bench, "configs", "olmo-hybrid-7b.json")) as fh:
-        conf = json.load(fh)
-    with open(os.path.join(bench, "peaks.json")) as fh:
-        hbm = json.load(fh)["devices"]["TPU v5 lite"]["hbm_bytes"]
+    cfg, conf = _cell(name)
     hp, rows = conf["families"]["tx"], conf["data"]["seq_len"]
-    cfg = tx.TxConfig(
-        vocab=hp["vocab"], d_model=hp["d_model"], n_heads=hp["n_heads"],
-        n_layers=hp["n_layers"], n_classes=conf["data"]["num_classes"],
-        max_len=rows, causal=hp["causal"], remat=hp["remat"], **hp["arch"])
     settings = Settings()
     settings.mesh_shape = "1,1,1"
     mesh = local_mesh(settings, devices=topo.devices[:1])
@@ -389,30 +440,93 @@ def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
 
     state = jax.eval_shape(init, jax.random.PRNGKey(0))
     held = sum(a.size for a in jax.tree.leaves(state[0]))
-    assert held == conf["state"]["parameters"]
     n = conf["data"]["n_train"]
     compiled = step.lower(
         placed(state), placed(jax.eval_shape(jax.random.PRNGKey, 0)),
         jax.ShapeDtypeStruct((n, rows), jnp.int32, sharding=rep),
         jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rep)).compile()
+    loops = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " while(" in ln]
+    return cfg, conf, held, compiled, loops, _matcher(loops)
+
+
+def _hbm():
+    import json
+    import os
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "perfbench", "peaks.json")) as fh:
+        return json.load(fh)["devices"]["TPU v5 lite"]["hbm_bytes"]
+
+
+def _loop_kernels(text: str, loop: str) -> str:
+    """The text of every computation a ``while`` line's body reaches
+    (its body, and what that calls or branches to)."""
+    import re
+
+    comps, name, body = {}, None, []
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[^ ]+) .*\{$", ln)
+        if head:
+            name, body = head.group(1), []
+        elif ln.startswith("}") and name:
+            comps[name], name = "\n".join(body), None
+        elif name:
+            body.append(ln)
+    seen, todo = set(), [re.search(r"body=(%[^ ,]+)", loop).group(1)]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=(%[^ ,}]+)",
+                           comps[c])
+        for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                comps[c]):
+            todo += [b.strip() for b in group.split(",")]
+    return "\n".join(comps[c] for c in seen)
+
+
+def _expert_loops_run_the_kernels(compiled, moe, backward=True):
+    """Every expert-layer loop ``moe_s`` matches reaches the grouped
+    products' custom calls (the routed pairs are computed there), and
+    with ``backward`` some reach the weight gradients' (``grouped_mm_t``)."""
+    import re
+
+    text = compiled.as_text()
+    inner = [_loop_kernels(text, loop) for loop in moe]
+    for loop, body in zip(moe, inner):
+        assert re.search(r"%grouped_mm[.0-9]* = [^\n]*tpu_custom_call",
+                         body), loop[:80]
+    assert backward == any(re.search(
+        r"%grouped_mm_t[.0-9]* = [^\n]*tpu_custom_call", b) for b in inner)
+
+
+def test_hybrid_step_compiles_and_fits_the_chip(topo, chip_compiler):
+    """The training step of the benchmark's ``olmo-hybrid-7b``
+    configuration as the cell POSTs it (one period LLLF, rows of 8,192
+    tokens, 15 of 30 heads held, MLP 11,008 whole, 12,544 vocabulary
+    rows), compiled for the described v5e: its state and its temporaries
+    together are under the device's memory, and its loops are named as
+    the cell's device-trace metrics expect them (the linear mixers'
+    block loops by the carried state, three a linear layer: forward,
+    rematerialised forward, backward; the full layer's query-block
+    loops), none matched by the other's pattern."""
+    import re
+
+    cfg, conf, held, compiled, loops, matched = _compiled_step(
+        topo, "olmo-hybrid-7b")
+    assert held == conf["state"]["parameters"]
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
-    assert total < hbm, (total, mem.temp_size_in_bytes)
+    assert total < _hbm(), (total, mem.temp_size_in_bytes)
     text = compiled.as_text()
     # PR 37: the chunk transform is built by blocks; XLA's triangular
     # solve (a serial ``InvertDiagBlocksLowerTriangular`` custom call a
     # block and pass) is in the program no more.
     assert not re.search(r"InvertDiagBlocks|triangular[-_]solve", text)
-    loops = [ln.strip() for ln in text.splitlines() if " while(" in ln]
-
-    def matched(metric):
-        with open(os.path.join(bench, "layer_metrics",
-                               metric + ".json")) as fh:
-            patterns = json.load(fh)["ops"]
-        return [ln for ln in loops if any(re.search(p, ln) for p in patterns)]
-
     core = matched("linear_attn_s.hybridfit")
     full = matched("full_attn_s.hybridfit")
     # The transform's kernel: once in each of a linear layer's loops
@@ -438,66 +552,127 @@ def test_ssm_step_compiles_and_fits_the_chip(topo, chip_compiler):
     are named as the cell's device-trace metrics expect them (the
     Mamba-2 mixers' block loops by the carried state, three an M layer:
     forward, rematerialised forward, backward; the expert layers'
-    token-block loops by the held experts' weights; the attention
+    window loops by the held experts' weights, forward and backward,
+    each of them running the grouped products; the attention
     layer's query-block loops by their stacked outputs), none matched by
     another's pattern."""
-    import json
-    import os
-    import re
-
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from learningorchestra_tpu.config import Settings
-    from learningorchestra_tpu.models import transformer as tx
-    from learningorchestra_tpu.parallel.mesh import local_mesh
-
-    bench = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench")
-    with open(os.path.join(bench, "configs",
-                           "nemotron-labs-twotower-30b-a3b.json")) as fh:
-        conf = json.load(fh)
-    with open(os.path.join(bench, "peaks.json")) as fh:
-        hbm = json.load(fh)["devices"]["TPU v5 lite"]["hbm_bytes"]
-    hp, rows = conf["families"]["tx"], conf["data"]["seq_len"]
-    cfg = tx.TxConfig(
-        vocab=hp["vocab"], d_model=hp["d_model"], n_heads=hp["n_heads"],
-        n_layers=hp["n_layers"], n_classes=conf["data"]["num_classes"],
-        max_len=rows, causal=hp["causal"], remat=hp["remat"], **hp["arch"])
-    settings = Settings()
-    settings.mesh_shape = "1,1,1"
-    mesh = local_mesh(settings, devices=topo.devices[:1])
-    init, step = tx.make_fit_programs(cfg, mesh, optax.adam(hp["lr"]),
-                                      hp["batch"])
-    rep = NamedSharding(mesh, P())
-
-    def placed(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=rep), tree)
-
-    state = jax.eval_shape(init, jax.random.PRNGKey(0))
-    held = sum(a.size for a in jax.tree.leaves(state[0]))
+    cfg, conf, held, compiled, loops, matched = _compiled_step(
+        topo, "nemotron-labs-twotower-30b-a3b")
     # the parameters, and the 4 x 128 correction bias (a buffer)
     assert held == conf["state"]["parameters"] + 4 * 128
-    n = conf["data"]["n_train"]
-    compiled = step.lower(
-        placed(state), placed(jax.eval_shape(jax.random.PRNGKey, 0)),
-        jax.ShapeDtypeStruct((n, rows), jnp.int32, sharding=rep),
-        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rep)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
-    assert mem.argument_size_in_bytes < mem.peak_memory_in_bytes < hbm, (
+    assert mem.argument_size_in_bytes < mem.peak_memory_in_bytes < _hbm(), (
         mem.peak_memory_in_bytes, mem.temp_size_in_bytes)
-    loops = [ln.strip() for ln in compiled.as_text().splitlines()
-             if " while(" in ln]
-
-    def matched(metric):
-        with open(os.path.join(bench, "layer_metrics",
-                               metric + ".json")) as fh:
-            patterns = json.load(fh)["ops"]
-        return [ln for ln in loops if any(re.search(p, ln) for p in patterns)]
-
     core, moe = matched("ssm_s.ssmfit"), matched("moe_s.ssmfit")
     attn = matched("full_attn_s.ssmfit")
     assert len(core) == 3 * cfg.pattern.count("M")
-    assert len(moe) >= cfg.pattern.count("E") and not set(core) & set(moe)
+    # the window loops of each E layer, forward and backward, and no
+    # loop inside them (the window turn is a conditional)
+    assert len(moe) == 2 * cfg.pattern.count("E")
+    assert not set(core) & set(moe)
     assert len(attn) >= 2 and not set(attn) & (set(core) | set(moe))
+    _expert_loops_run_the_kernels(compiled, moe)
+
+
+def test_keye_step_compiles_and_fits_the_chip(topo, chip_compiler):
+    """The training step of the benchmark's ``keye-vl-2.0-30b-a3b``
+    configuration as the cell POSTs it (6 layers, one row of 8,192
+    tokens a step, 16 of 128 experts held, 18,992 vocabulary rows),
+    compiled for the described v5e: its peak by the compiler's heap
+    simulation is under the device's memory, the expert layer's
+    window loops, forward and backward (the rematerialised forward's is
+    not kept: the layer's backward needs only its inputs), are the
+    only ones ``moe_s.txfit`` matches by the held experts' weights, each
+    runs the grouped products, and none is an attention loop."""
+    cfg, conf, held, compiled, loops, matched = _compiled_step(
+        topo, "keye-vl-2.0-30b-a3b")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0.75 * 16 * held   # w, m, v
+    assert mem.argument_size_in_bytes < mem.peak_memory_in_bytes < _hbm(), (
+        mem.peak_memory_in_bytes, mem.temp_size_in_bytes)
+    moe, attn = matched("moe_s.txfit"), matched("sparse_attn_s.txfit")
+    assert len(moe) == 2 and not set(moe) & set(attn)
+    _expert_loops_run_the_kernels(compiled, moe)
+
+
+@pytest.mark.parametrize("name,metric", [
+    ("keye-vl-2.0-30b-a3b", "moe_s.txfit"),
+    ("nemotron-labs-twotower-30b-a3b", "moe_s.ssmfit")])
+def test_expert_predict_loops_are_matched_once(name, metric, topo,
+                                               chip_compiler):
+    """The predict pass of the cell's 16 test rows, compiled for one
+    chip of the described v5e: ``moe_s`` finds each expert layer's
+    window loop once, each running the grouped products (no weight
+    gradient), and no loop inside one. Where the layers are not
+    stacked (Nemotron's period), the map over the rows carries every
+    parameter as it is, the held experts' weights among them, so the
+    pattern finds that loop too, as it did before the grouped path."""
+    from jax.sharding import SingleDeviceSharding
+
+    from learningorchestra_tpu.models import sequence
+    from learningorchestra_tpu.models import transformer as tx
+
+    cfg, conf = _cell(name)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda k: tx.init_params(k, cfg),
+                       jax.random.PRNGKey(0)))
+    X = jax.ShapeDtypeStruct((conf["data"]["n_test"], cfg.max_len),
+                             jnp.int32, sharding=one)
+    compiled = sequence._proba_program(cfg).lower(params, X).compile()
+    loops = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " while(" in ln]
+    moe = _matcher(loops)(metric)
+    windows = [ln for ln in moe if "pred[" in ln]     # their activity
+    if cfg.pattern:
+        assert len(windows) == cfg.pattern.count("E") == len(moe) - 1
+    else:
+        assert len(windows) == len(moe) == 1
+    _expert_loops_run_the_kernels(compiled, windows, backward=False)
+
+
+def test_grouped_experts_compile_split_over_four_chips(topo, chip_compiler):
+    """Keye-VL's expert layer with its 16 held experts split over a
+    model axis of four chips of the described v5e (four a chip), forward
+    and backward inside ``shard_map``: the grouped products, typed for
+    the mesh, compile there, and the shards' partial outputs and the
+    input's and router's cotangents are summed across the chips."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from learningorchestra_tpu.models import transformer as tx
+    from learningorchestra_tpu.parallel.mesh import (
+        DATA_AXIS, MODEL_AXIS, SEQ_AXIS)
+
+    cfg, _ = _cell("keye-vl-2.0-30b-a3b")
+    d, f, T = cfg.d_model, cfg.expert_width, cfg.max_len
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1),
+                (DATA_AXIS, MODEL_AXIS, SEQ_AXIS))
+    specs = {"router": P(), "we_gate": P(MODEL_AXIS),
+             "we_up": P(MODEL_AXIS), "we_down": P(MODEL_AXIS)}
+    ax = tx.Axes(model=MODEL_AXIS)
+
+    def loss(h, lyr):
+        out = tx._experts(cfg, ax, h, lyr)[0]
+        return jax.lax.psum((out.astype(jnp.float32) ** 2).sum(),
+                            MODEL_AXIS)
+
+    step = jax.jit(jax.grad(jax.shard_map(
+        loss, mesh=mesh, in_specs=(P(), specs), out_specs=P()), (0, 1)))
+    held = cfg.experts_held
+    shapes = {"router": (d, cfg.n_experts), "we_gate": (held, d, f),
+              "we_up": (held, d, f), "we_down": (held, f, d)}
+    lyr = {k: jax.ShapeDtypeStruct(v, jnp.float32,
+                                   sharding=NamedSharding(mesh, specs[k]))
+           for k, v in shapes.items()}
+    h = jax.ShapeDtypeStruct((1, T, d), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    text = step.lower(h, lyr).compile().as_text()
+    for kernel in ("grouped_mm", "grouped_mm_t"):
+        assert re.search(r"%" + kernel + r"[.0-9]* = [^\n]*tpu_custom_call",
+                         text), kernel
+    assert "all-reduce" in text

@@ -165,7 +165,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares):
         cfg = config(experts_first=i * per, experts_held=per)
         lyr = dict(lw, **{k: lw[k][i * per:(i + 1) * per]
                           for k in ("we_gate", "we_up", "we_down")})
-        out, counts, moe = tx._experts(cfg, tx.NO_AXES, h, lyr)
+        out, counts, moe, _ = tx._experts(cfg, tx.NO_AXES, h, lyr)
         total = total + out[0]
         assert moe[2] == 0 and counts.sum() == moe[0] - moe[1]
     np.testing.assert_allclose(np.asarray(total), np.asarray(after - mid),

@@ -192,7 +192,7 @@ def test_expert_layer_is_the_per_expert_form():
     z, prec = R.sizes(conf_of("E")), R._precision(None)
 
     def program(W, h):
-        out, counts, moe = tx._experts(cfg, tx.NO_AXES, h[None], W)
+        out, counts, moe, _ = tx._experts(cfg, tx.NO_AXES, h[None], W)
         return (out[0] * cot).sum(), (out[0], counts, moe)
 
     def reference(W, h):
@@ -239,7 +239,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(shares):
             cfg = config("E", experts_first=i * per, experts_held=per)
             mine = dict(W, **{k: W[k][i * per:(i + 1) * per]
                               for k in ("we_up", "we_down")})
-            out, counts, moe = tx._experts(cfg, tx.NO_AXES, h[None], mine)
+            out, counts, moe, _ = tx._experts(cfg, tx.NO_AXES, h[None], mine)
             total = total + out[0]
             assert moe[2] == 0 and counts.sum() == moe[0] - moe[1]
     assert close(total, whole, 1e-5)
